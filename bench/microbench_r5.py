@@ -19,17 +19,15 @@ one host fetch at the end), so successive deltas attribute time to:
 r6 variants (the levers that replaced D/E/F and part of A):
   2  A with the fingerprint folding dict columns directly (no stack)
   3  A with the PACKED-word fingerprint (datamodel/code.py plans)
-  p  C + fused Pallas suffix reduce: the kernel gathers rows THROUGH
-     the sort permutation (no standalone D gather pass at all)
-  q  C + standalone row-gather (D) + pre-gathered Pallas suffix reduce
-     (the r5 shipped shape) — q − p is the row-gather's residual cost
+  q  C + standalone row-gather (D) + the Pallas suffix reduce (the
+     shipped shape; the r6 in-kernel gather, stage p, was refused by
+     the chip's compiler and deleted in PR 22)
 
-G/H always time the CURRENT production graph, so after the r6 rebuild
-they include the packed fingerprint and (on TPU / forced pallas) the
-fused kernel; compare p vs q and 3 vs A on-chip to attribute the wins.
+G/H always time the CURRENT production graph (packed fingerprint and,
+on TPU / forced pallas, the Pallas reduce); compare 3 vs A on-chip.
 
 Usage: python bench/microbench_r5.py [--batch 2097152] [--capu 32768]
-                                     [--stages abcdefgh23pq]
+                                     [--stages abcdefgh23q]
 Copy results into PERF.md.
 """
 
@@ -171,22 +169,15 @@ def stage_v3(c, tags, meters, valid):
     return c ^ hi[0] ^ lo[0] ^ slot[0]
 
 
-def _stage_pallas(c, tags, meters, valid, capu, fused):
-    """C + the Pallas suffix reduce. fused=True: the kernel gathers
-    meter rows through the sort permutation (NO standalone row-gather
-    stage); fused=False: the r5 shape (D's take, then the kernel)."""
+def _stage_pallas(c, tags, meters, valid, capu):
+    """C + D's row gather + the Pallas suffix reduce."""
     from deepflow_tpu.ops.segreduce_pallas import sorted_segment_sum_max
 
     lanes, _ = _sorted(c, tags, valid)
     seg_id, num_seg = _segids(lanes)
     first_pos = jnp.searchsorted(seg_id, jnp.arange(capu, dtype=jnp.int32))
-    if fused:
-        ps, pm = sorted_segment_sum_max(
-            meters, seg_id, capu, first_pos, perm=lanes[3]
-        )
-    else:
-        rows = jnp.take(meters, lanes[3], axis=0)
-        ps, pm = sorted_segment_sum_max(rows, seg_id, capu, first_pos)
+    rows = jnp.take(meters, lanes[3], axis=0)
+    ps, pm = sorted_segment_sum_max(rows, seg_id, capu, first_pos)
     return (c ^ ps[0, 0].astype(jnp.uint32) ^ pm[0, 0].astype(jnp.uint32)
             ^ jnp.uint32(num_seg))
 
@@ -247,14 +238,12 @@ def main():
     jit_v1 = jax.jit(partial(stage_v1, capu=CAPU))
     jit_v2 = jax.jit(stage_v2)
     jit_v3 = jax.jit(stage_v3)
-    jit_p = jax.jit(partial(_stage_pallas, capu=CAPU, fused=True))
-    jit_q = jax.jit(partial(_stage_pallas, capu=CAPU, fused=False))
+    jit_q = jax.jit(partial(_stage_pallas, capu=CAPU))
     stages = {
         "1": ("V1 narrow segment_max", lambda c: jit_v1(c, tags, meters, valid)),
         "2": ("V2 destacked fingerprint", lambda c: jit_v2(c, tags, meters, valid)),
         "3": ("V3 packed-word fingerprint", lambda c: jit_v3(c, tags, meters, valid)),
-        "p": ("P fused-gather pallas reduce", lambda c: jit_p(c, tags, meters, valid)),
-        "q": ("Q pregather pallas reduce", lambda c: jit_q(c, tags, meters, valid)),
+        "q": ("Q row-gather + pallas reduce", lambda c: jit_q(c, tags, meters, valid)),
         "a": ("A stack+fingerprint", lambda c: jit_a(c, tags, meters, valid)),
         "b": ("B +sort4", lambda c: jit_b(c, tags, meters, valid)),
         "c": ("C +segids", lambda c: jit_c(c, tags, meters, valid)),

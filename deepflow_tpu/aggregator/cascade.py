@@ -184,6 +184,13 @@ tier_ring_fold = partial(
 )(_ring_fold_impl)
 
 
+def _varying_like(const, ref):
+    """`const` typed like `ref`: under shard_map state varies over the
+    mesh axes, and every `cond` branch must return that same type."""
+    vma = tuple(jax.typeof(ref).vma)
+    return lax.pcast(const, vma, to="varying") if vma else const
+
+
 def _tier_step_impl(tier: StashState, acc, fill, lanes, packed, total, hi,
                     *, ratio: int, num_tags: int, sum_cols_t, max_cols_t,
                     prefix: int, shared_sort: bool = False):
@@ -223,6 +230,7 @@ def _tier_step_impl(tier: StashState, acc, fill, lanes, packed, total, hi,
         packed[:prefix], hi, ratio=ratio, num_tags=num_tags
     )
     n_small = jnp.sum(pv).astype(jnp.uint32)
+    zero_fill = _varying_like(jnp.int32(0), fill)
 
     def append(tier, acc, fill, lanes):
         acc = _append_impl(acc, pp, ph, pl, pt, pm, pv, fill)
@@ -235,7 +243,7 @@ def _tier_step_impl(tier: StashState, acc, fill, lanes, packed, total, hi,
             tier, acc, lanes, sum_cols_t, max_cols_t,
             shared_sort=shared_sort,
         )
-        return append(tier, acc, jnp.int32(0), lanes)
+        return append(tier, acc, zero_fill, lanes)
 
     def direct_fold(tier, acc, fill, lanes):
         fp, fh, fl, ft, fm, fv = _parent_columns(
@@ -253,11 +261,13 @@ def _tier_step_impl(tier: StashState, acc, fill, lanes, packed, total, hi,
         )
         new_acc = dataclasses.replace(
             acc,
-            slot=jnp.full((A,), SENTINEL_SLOT, dtype=jnp.uint32),
+            slot=_varying_like(
+                jnp.full((A,), SENTINEL_SLOT, dtype=jnp.uint32), acc.slot
+            ),
         )
         shed = (new_tier.dropped_overflow - prev_dropped).astype(jnp.uint32)
         folded = jnp.sum(fv).astype(jnp.uint32)
-        return new_tier, new_acc, jnp.int32(0), lanes + jnp.stack(
+        return new_tier, new_acc, zero_fill, lanes + jnp.stack(
             [folded, shed]
         )
 

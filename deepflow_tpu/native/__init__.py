@@ -94,14 +94,14 @@ def _sources_newer_than_so() -> bool:
     )
 
 
-def _load():
+def _load(force_build: bool = False):
     global _lib, _build_error
     if _lib is not None or _build_error is not None:
         return
     try:
-        if _sources_newer_than_so():
+        if force_build or _sources_newer_than_so():
             subprocess.run(
-                ["make", "-s"],
+                ["make", "-s", "-B"] if force_build else ["make", "-s"],
                 cwd=_NATIVE_DIR,
                 check=True,
                 capture_output=True,
@@ -141,6 +141,17 @@ def _load():
 
 def native_available() -> bool:
     _load()
+    return _lib is not None
+
+
+def rebuild() -> bool:
+    """Build the shared object from native/src now, whatever is on disk
+    (`_load` trusts mtimes, which a copy of the tree does not keep), and
+    load only what this build produced. False — with `build_error()`
+    set and the Python codec in charge — when there is no toolchain."""
+    global _lib, _build_error
+    _lib = _build_error = None
+    _load(force_build=True)
     return _lib is not None
 
 

@@ -14,9 +14,7 @@ axis minor — it maps rows onto the 128-wide vector lanes and keeps
 column selection free); the METER payload is row-major [N, M] since r6,
 because the reduce consumes rows — one row-gather of [N, M] moves M
 contiguous elements per index (~17x better than M strided
-lane-gathers), and the fused Pallas path (segreduce_pallas.py) streams
-rows through the sort permutation by per-row DMA, which needs the
-original array row-contiguous. The batch pre-reduce hot path produces
+lane-gathers). The batch pre-reduce hot path produces
 [N, M] natively (FlowBatch.meters), so no transpose is ever
 materialized at 2M rows; the stash fold transposes its column-major
 state at the call site, where XLA folds it into the downstream
@@ -64,14 +62,6 @@ def _use_pallas_reduce() -> bool:
     if mode == "xla":
         return False
     return jax.default_backend() not in ("cpu",)
-
-
-def _use_fused_gather() -> bool:
-    """On the pallas path, gather meter rows INSIDE the kernel via
-    permutation-indexed DMA (PERF.md §9d) instead of a standalone
-    `take` pass. DEEPFLOW_FUSED_GATHER=0 re-enables the pre-gather
-    variant for on-chip A/B runs."""
-    return os.environ.get("DEEPFLOW_FUSED_GATHER", "1") != "0"
 
 
 def _use_merge_scatter() -> bool:
@@ -231,16 +221,9 @@ def groupby_reduce_sorted(
     if m and _use_pallas_reduce():
         from .segreduce_pallas import sorted_segment_sum_max
 
-        if _use_fused_gather():
-            # the kernel reads rows THROUGH the sort permutation — no
-            # standalone gather pass ever materializes the sorted payload
-            ps, pm = sorted_segment_sum_max(
-                meters_rows, seg_id, cap, first_pos, perm=perm
-            )
-        else:
-            ps, pm = sorted_segment_sum_max(
-                jnp.take(meters_rows, perm, axis=0), seg_id, cap, first_pos
-            )
+        ps, pm = sorted_segment_sum_max(
+            jnp.take(meters_rows, perm, axis=0), seg_id, cap, first_pos
+        )
         if not max_cols.size:
             out_meters = ps.T
         elif not sum_cols.size:

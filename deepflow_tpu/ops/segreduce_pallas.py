@@ -16,16 +16,12 @@ append. This kernel replaces both with one streaming pass:
     (n/B rows, three orders of magnitude smaller than n), then a
     [cap]-row gather at the segment head positions finishes the job.
 
-Fused gather (r6, PERF.md §9d): the [N, M] payload used to be
-pre-gathered into sorted order by a standalone `jnp.take` pass
-(~14.5 ms of HBM read+write at 2M rows). With `perm` supplied, the
-kernel instead streams rows THROUGH the sort permutation: the per-block
-slice of `perm` rides in SMEM, and a pipelined chain of row-sized
-async copies (permutation-indexed block DMA) lands each block's rows in
-VMEM scratch in sorted order — the payload is read exactly once, in
-its original layout, and the pre-gather pass disappears. Lanes past M
-in the scratch are never DMA'd and hold garbage; every lane is
-independent under sum/max, and callers slice [:, :m].
+The payload arrives already gathered into sorted order (one XLA
+`take` upstream) and lane-padded to the 128-wide f32 tile. An r6
+variant that gathered rows inside the kernel by per-row DMA of M lanes
+was refused by the v5e compiler (a VMEM slice of 62 lanes is not
+aligned to the 128-lane tiling) and was deleted in PR 22: made to
+compile by padding the payload first, it moves more bytes than this.
 
 No scatter touches the [N, M] payload; everything wide is sequential
 VMEM streaming (MXU-free, VPU + bandwidth bound).
@@ -37,8 +33,7 @@ fold, vectorized.
 Exactness: within-segment summation is tree-ordered instead of linear.
 For the integer-valued meter lanes this framework folds (packet/byte/
 count deltas well under 2^24), f32 tree sums are bit-exact; the
-conformance suite pins the pallas path against the XLA ops directly,
-with and without the fused gather.
+conformance suite pins the pallas path against the XLA ops directly.
 """
 
 from __future__ import annotations
@@ -54,10 +49,11 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128  # f32 lane tile; meter payloads are padded up to this
 _NEG = np.float32(-3.4e38)  # practical -inf that survives where()
 
-# Outstanding row DMAs in the fused-gather pipeline. Small enough to
-# stay within the DMA queue, deep enough to hide issue latency behind
-# the in-flight copies.
-_GATHER_LOOKAHEAD = 8
+
+def _interpret() -> bool:
+    """Interpret mode is for the CPU platform only; on a chip the
+    kernel compiles or the program fails."""
+    return jax.default_backend() == "cpu"
 
 
 def _suffix_scan(seg, x, block: int):
@@ -95,6 +91,10 @@ def _block_suffix(rows: jnp.ndarray, seg2d: jnp.ndarray, block: int):
     (suffix_sum, suffix_max), both [N, LANES]."""
     n = rows.shape[0]
     grid = (n // block,)
+    # under shard_map the outputs vary over the same mesh axes as the rows
+    out = jax.ShapeDtypeStruct(
+        (n, LANES), jnp.float32, vma=jax.typeof(rows).vma
+    )
     return pl.pallas_call(
         partial(_suffix_kernel, block=block),
         grid=grid,
@@ -106,83 +106,9 @@ def _block_suffix(rows: jnp.ndarray, seg2d: jnp.ndarray, block: int):
             pl.BlockSpec((block, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((block, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n, LANES), jnp.float32),
-        ],
-        interpret=jax.default_backend() == "cpu",
+        out_shape=[out, out],
+        interpret=_interpret(),
     )(seg2d, rows)
-
-
-def _gather_suffix_kernel(
-    perm_ref, seg_ref, rows_ref, sum_ref, max_ref, rows_vmem, sems,
-    *, block: int, m: int,
-):
-    """Fused variant: rows_ref is the FULL [N, m] payload in HBM
-    (original order); perm_ref holds this block's slice of the sort
-    permutation in SMEM. Rows land in VMEM scratch in sorted order via
-    a lookahead-pipelined chain of row DMAs, then the suffix scan runs
-    unchanged."""
-    la = min(_GATHER_LOOKAHEAD, block)
-
-    def row_copy(j):
-        return pltpu.make_async_copy(
-            rows_ref.at[perm_ref[j]],
-            rows_vmem.at[j, pl.ds(0, m)],
-            sems.at[j % la],
-        )
-
-    for j in range(la):  # warm-up: fill the pipeline
-        row_copy(j).start()
-
-    def body(j, carry):
-        @pl.when(j + la < block)
-        def _():
-            row_copy(j + la).start()
-
-        row_copy(j).wait()
-        return carry
-
-    jax.lax.fori_loop(0, block, body, 0)
-
-    s, mx = _suffix_scan(seg_ref[:], rows_vmem[:], block)
-    sum_ref[:] = s
-    max_ref[:] = mx
-
-
-def _block_suffix_gather(
-    rows: jnp.ndarray, perm: jnp.ndarray, seg2d: jnp.ndarray, block: int
-):
-    """rows [N, m] f32 in ORIGINAL order, perm [P] i32 (P % block == 0,
-    values < N), seg2d [P, 1] i32 → (suffix_sum, suffix_max) of
-    rows[perm], both [P, LANES] (lanes ≥ m are garbage — callers
-    slice)."""
-    n_sorted = perm.shape[0]
-    m = rows.shape[1]
-    grid = (n_sorted // block,)
-    la = min(_GATHER_LOOKAHEAD, block)
-    return pl.pallas_call(
-        partial(_gather_suffix_kernel, block=block, m=m),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((block, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # full payload, kernel-DMA'd
-        ],
-        out_specs=[
-            pl.BlockSpec((block, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((block, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_sorted, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n_sorted, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block, LANES), jnp.float32),
-            pltpu.SemaphoreType.DMA((la,)),
-        ],
-        interpret=jax.default_backend() == "cpu",
-    )(perm, seg2d, rows)
 
 
 def sorted_segment_sum_max(
@@ -191,7 +117,6 @@ def sorted_segment_sum_max(
     num_segments: int,
     first_pos: jnp.ndarray,
     *,
-    perm: jnp.ndarray | None = None,
     block: int = 2048,
 ):
     """Segment sum AND max of `rows` [N, M] f32 grouped by the ASCENDING
@@ -199,12 +124,6 @@ def sorted_segment_sum_max(
     last). `first_pos` [num_segments] are the first occurrence indices
     (searchsorted upstream). Returns (sums, maxs), both
     [num_segments, M].
-
-    With `perm` [N] i32 supplied, `rows` is in ORIGINAL (pre-sort)
-    order and row i of the reduction input is rows[perm[i]] — the
-    gather happens inside the kernel via permutation-indexed DMA, so no
-    pre-gathered copy of the payload is ever materialized. Without
-    `perm`, rows must already be sorted (legacy contract).
 
     CONTRACT: rows of ABSENT segments are garbage — searchsorted points
     an absent id at the next live segment's head, so its totals bleed
@@ -223,23 +142,13 @@ def sorted_segment_sum_max(
     pad_rows = (-n) % blk
     if pad_rows:
         seg_id = jnp.pad(seg_id, (0, pad_rows), constant_values=np.int32(2**31 - 1))
-        if perm is None:
-            rows = jnp.pad(rows, ((0, pad_rows), (0, 0)))
-        else:
-            # padded tail rows read a real row (index 0) but carry the
-            # sentinel segment id, so they never reach any live output
-            perm = jnp.pad(perm, (0, pad_rows))
+        rows = jnp.pad(rows, ((0, pad_rows), (0, 0)))
         n += pad_rows
     seg2d = seg_id.astype(jnp.int32)[:, None]
 
-    if perm is None:
-        if m < LANES:
-            rows = jnp.pad(rows, ((0, 0), (0, LANES - m)))
-        suf_sum, suf_max = _block_suffix(rows, seg2d, blk)
-    else:
-        suf_sum, suf_max = _block_suffix_gather(
-            rows, perm.astype(jnp.int32), seg2d, blk
-        )
+    if m < LANES:
+        rows = jnp.pad(rows, ((0, 0), (0, LANES - m)))
+    suf_sum, suf_max = _block_suffix(rows, seg2d, blk)
 
     # in-block totals at the segment heads
     fp = jnp.clip(first_pos, 0, n - 1)

@@ -71,13 +71,9 @@ _COST_KEYS = ("flops", "bytes accessed", "transcendentals",
               "optimal_seconds")
 
 
-def _flatten_cost(cost) -> dict:
-    """Normalize XLA cost_analysis output across jax versions: a dict
-    (new) or a one-element list of dicts (old); keys carry spaces
-    ('bytes accessed'). Only the headline totals are kept."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    cost = dict(cost)
+def _flatten_cost(cost: dict) -> dict:
+    """XLA cost_analysis → the headline totals only (keys carry spaces:
+    'bytes accessed')."""
     out = {}
     for k in _COST_KEYS:
         if k in cost:
@@ -109,7 +105,7 @@ def _count_sort_eqns(jaxpr) -> int:
 def _sub_jaxprs(v):
     """Yield every Jaxpr held by one eqn param value (handles Jaxpr,
     ClosedJaxpr, and lists/tuples of either)."""
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
 
     if isinstance(v, Jaxpr):
         yield v

@@ -85,6 +85,11 @@ class Server:
 
     def start(self) -> "Server":
         cfg = self.config
+        # the device programs (enrichment kernel, window managers the
+        # process hosts) compile once per checkout, not once per start
+        from ..utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         self.store = ColumnarStore(cfg.storage.root)
         # in-service schema upgrade before anything touches tables
         # (ckissu.go:51 boot ordering)

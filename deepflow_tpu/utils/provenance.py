@@ -57,3 +57,14 @@ def bench_provenance() -> dict:
     except Exception:
         pass
     return out
+
+
+def device_identity() -> dict:
+    """The device a result was measured on, as JAX reports it — every
+    bench record carries it, so a CPU count is never read as a chip
+    number."""
+    import jax
+
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}

@@ -45,7 +45,7 @@ def decorrelated_rng(tag: int) -> random.Random:
 
 # Substrings of runtime error text treated as transient. XLA runtime
 # errors carry their absl status code in the message; these are the
-# codes that mean "the device/tunnel may accept the same call shortly".
+# codes that mean "the device may accept the same call shortly".
 TRANSIENT_ERROR_MARKERS = (
     "RESOURCE_EXHAUSTED",
     "DEADLINE_EXCEEDED",
@@ -61,7 +61,7 @@ class TransientError(Exception):
 
 
 def is_transient(exc: BaseException) -> bool:
-    """The shared retry classification: our TransientError taxonomy,
+    """The shared retry classification: our TransientError classes,
     plus runtime errors whose status code says try-again. For
     donated-buffer DISPATCH calls use is_dispatch_transient instead."""
     if isinstance(exc, TransientError):
@@ -85,7 +85,7 @@ DISPATCH_TRANSIENT_MARKERS = (
 
 def is_dispatch_transient(exc: BaseException) -> bool:
     """Admission-time-only classification for the donated-buffer
-    dispatch paths: our TransientError taxonomy (the chaos seam fires
+    dispatch paths: our TransientError classes (the chaos seam fires
     before the real call) plus admission-time status codes. The fetch
     path keeps the broader is_transient — a blown fetch deadline
     leaves the device handle valid."""

@@ -143,7 +143,7 @@ def test_dispatch_retry_is_admission_time_only():
     assert is_dispatch_transient(RuntimeError("RESOURCE_EXHAUSTED: oom"))
     assert not is_dispatch_transient(RuntimeError("UNAVAILABLE: device lost"))
     assert not is_dispatch_transient(RuntimeError("ABORTED: replica failure"))
-    assert is_transient(RuntimeError("UNAVAILABLE: tunnel hiccup"))
+    assert is_transient(RuntimeError("UNAVAILABLE: device hiccup"))
 
     calls = {"n": 0}
 

@@ -141,30 +141,25 @@ def test_l4_both_inactive_record_dropped():
     assert oracle_l4_rollup([rec], cfg) == {}
 
 
-def test_conformance_forced_pallas_fused_gather(monkeypatch):
+def test_conformance_forced_pallas(monkeypatch):
     """The whole device pipeline stays oracle-exact with the Pallas
-    suffix-scan reduce forced on (CPU runs it in interpret mode) — both
-    with the in-kernel fused row gather and with the pre-gather
-    variant. Integer meters must be bit-exact; the suite's meters are
-    integral so _compare's equality check IS the bit-exactness check."""
+    suffix-scan reduce forced on (CPU runs it in interpret mode).
+    Integer meters must be bit-exact; the suite's meters are integral
+    so _compare's equality check IS the bit-exactness check."""
     import jax
 
-    for fused in ("1", "0"):
-        monkeypatch.setenv("DEEPFLOW_SEGREDUCE", "pallas")
-        monkeypatch.setenv("DEEPFLOW_FUSED_GATHER", fused)
-        jax.clear_caches()  # path selection happens at trace time
-        try:
-            emitted, oracle = _run_both(
-                {"num_tuples": 50, "seed": 1},
-                batches=[(1000, 100), (1000, 100), (1004, 1)],
-            )
-            assert len(oracle) > 0
-            _compare(emitted, oracle)
-        finally:
-            monkeypatch.setenv("DEEPFLOW_SEGREDUCE", "xla")
-            jax.clear_caches()
-    monkeypatch.delenv("DEEPFLOW_SEGREDUCE")
-    jax.clear_caches()
+    monkeypatch.setenv("DEEPFLOW_SEGREDUCE", "pallas")
+    jax.clear_caches()  # path selection happens at trace time
+    try:
+        emitted, oracle = _run_both(
+            {"num_tuples": 50, "seed": 1},
+            batches=[(1000, 100), (1000, 100), (1004, 1)],
+        )
+        assert len(oracle) > 0
+        _compare(emitted, oracle)
+    finally:
+        monkeypatch.delenv("DEEPFLOW_SEGREDUCE")
+        jax.clear_caches()
 
 
 def test_batch_unique_cap_prereduce_exact():

@@ -1,9 +1,8 @@
 """Pallas suffix-scan segmented reduce vs the XLA segment ops — the two
 paths of ops/segment.py must agree exactly on integer-valued meters and
-to 1 ulp on arbitrary floats (tree-order association). Since r6 the
-pallas path also gathers rows through the sort permutation INSIDE the
-kernel (fused gather, permutation-indexed DMA); fused and pre-gathered
-variants are pinned bit-equal here on both backend selections."""
+to 1 ulp on arbitrary floats (tree-order association). The CPU runs the
+kernel in interpret mode; tests/test_tpu_compile.py asks the chip's
+compiler for the same kernel at real widths."""
 
 from __future__ import annotations
 
@@ -15,7 +14,7 @@ import jax.numpy as jnp
 from deepflow_tpu.ops.segreduce_pallas import LANES, sorted_segment_sum_max
 
 
-def _case(n, cap, n_keys, m=7, seed=0, integral=True, block=256, fused=False):
+def _case(n, cap, n_keys, m=7, seed=0, integral=True, block=256):
     rng = np.random.default_rng(seed)
     seg = np.sort(rng.integers(0, n_keys, n)).astype(np.int32)
     n_live = n - n // 8  # tail of dead rows, ids past every live one
@@ -26,21 +25,10 @@ def _case(n, cap, n_keys, m=7, seed=0, integral=True, block=256, fused=False):
         rows = rng.standard_normal((n, m)).astype(np.float32) * 1e3
     first_pos = np.searchsorted(seg, np.arange(cap)).astype(np.int32)
 
-    if fused:
-        # hand the kernel the ORIGINAL (pre-sort) array + the sort
-        # permutation: rows == rows_orig[perm]
-        perm = rng.permutation(n).astype(np.int32)
-        rows_orig = np.empty_like(rows)
-        rows_orig[perm] = rows
-        got_s, got_m = sorted_segment_sum_max(
-            jnp.asarray(rows_orig), jnp.asarray(seg), cap,
-            jnp.asarray(first_pos), perm=jnp.asarray(perm), block=block,
-        )
-    else:
-        got_s, got_m = sorted_segment_sum_max(
-            jnp.asarray(rows), jnp.asarray(seg), cap, jnp.asarray(first_pos),
-            block=block,
-        )
+    got_s, got_m = sorted_segment_sum_max(
+        jnp.asarray(rows), jnp.asarray(seg), cap, jnp.asarray(first_pos),
+        block=block,
+    )
     import jax
 
     want_s = jax.ops.segment_sum(jnp.asarray(rows), jnp.asarray(seg),
@@ -63,34 +51,21 @@ CASES = [
 
 
 @pytest.mark.parametrize("n,cap,n_keys,block", CASES)
-@pytest.mark.parametrize("fused", [False, True], ids=["pregather", "fused"])
-def test_matches_xla_integral(n, cap, n_keys, block, fused):
-    gs, gm, ws, wm = _case(n, cap, n_keys, block=block, fused=fused)
+def test_matches_xla_integral(n, cap, n_keys, block):
+    gs, gm, ws, wm = _case(n, cap, n_keys, block=block)
     np.testing.assert_array_equal(gs, ws)
     np.testing.assert_array_equal(gm, wm)
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["pregather", "fused"])
-def test_matches_xla_float_tolerance(fused):
-    gs, gm, ws, wm = _case(1024, 256, 50, integral=False, seed=3, fused=fused)
+def test_matches_xla_float_tolerance():
+    gs, gm, ws, wm = _case(1024, 256, 50, integral=False, seed=3)
     np.testing.assert_allclose(gs, ws, rtol=1e-5)
     np.testing.assert_array_equal(gm, wm)  # max is order-free → exact
 
 
-def test_fused_matches_pregather_bitexact_floats():
-    """Fused gather reorders only the DMA, not the reduction tree —
-    arbitrary floats must agree BIT-exactly between the two pallas
-    variants (tolerance is only vs the XLA linear-order sum)."""
-    a = _case(1024, 256, 50, integral=False, seed=9, fused=False)
-    b = _case(1024, 256, 50, integral=False, seed=9, fused=True)
-    np.testing.assert_array_equal(a[0], b[0])
-    np.testing.assert_array_equal(a[1], b[1])
-
-
 def test_full_lane_width():
-    """m == LANES leaves no garbage lanes; the fused DMA copies whole
-    rows."""
-    gs, gm, ws, wm = _case(512, 64, 20, m=LANES, block=128, fused=True)
+    """m == LANES: no lane padding at all."""
+    gs, gm, ws, wm = _case(512, 64, 20, m=LANES, block=128)
     np.testing.assert_array_equal(gs, ws)
     np.testing.assert_array_equal(gm, wm)
 
@@ -120,9 +95,8 @@ def _groupby_inputs(seed=7, n=512, t=5, m=6):
     return slot, hi, lo, tags, meters, valid, sum_cols, max_cols
 
 
-def _run_groupby(monkeypatch, segreduce: str, fused: str):
+def _run_groupby(monkeypatch, segreduce: str):
     monkeypatch.setenv("DEEPFLOW_SEGREDUCE", segreduce)
-    monkeypatch.setenv("DEEPFLOW_FUSED_GATHER", fused)
     from deepflow_tpu.ops.segment import groupby_reduce
 
     slot, hi, lo, tags, meters, valid, sum_cols, max_cols = _groupby_inputs()
@@ -132,13 +106,11 @@ def _run_groupby(monkeypatch, segreduce: str, fused: str):
                           out_capacity=128)
 
 
-@pytest.mark.parametrize("fused", ["0", "1"], ids=["pregather", "fused"])
-def test_groupby_reduce_pallas_path_matches(monkeypatch, fused):
-    """Force the pallas path (both gather variants) through the full
-    groupby_reduce and pin it against the XLA path on the same
-    inputs."""
-    g1 = _run_groupby(monkeypatch, "pallas", fused)
-    g2 = _run_groupby(monkeypatch, "xla", fused)
+def test_groupby_reduce_pallas_path_matches(monkeypatch):
+    """Force the pallas path through the full groupby_reduce and pin it
+    against the XLA path on the same inputs."""
+    g1 = _run_groupby(monkeypatch, "pallas")
+    g2 = _run_groupby(monkeypatch, "xla")
     np.testing.assert_array_equal(np.asarray(g1.meters), np.asarray(g2.meters))
     np.testing.assert_array_equal(np.asarray(g1.slot), np.asarray(g2.slot))
     np.testing.assert_array_equal(np.asarray(g1.seg_valid), np.asarray(g2.seg_valid))
