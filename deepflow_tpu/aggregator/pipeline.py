@@ -31,7 +31,7 @@ from ..datamodel.batch import DocBatch, FlowBatch
 from ..datamodel.code import DOC_KEY_PACK, RAW_TAG_PACK, DocumentFlag, pack_tag_words
 from ..datamodel.schema import APP_METER, FLOW_METER, TAG_SCHEMA, MeterSchema
 from ..ops.hashing import fingerprint64_words
-from ..utils.spans import JitCacheMonitor
+from ..utils.spans import SPAN_INGEST_STAGE, JitCacheMonitor
 from ..utils.stats import register_countable
 from .fanout import FANOUT_LANES, FanoutConfig, fanout_l4, fanout_l7
 from .stash import _append_impl
@@ -405,34 +405,42 @@ class RollupPipeline:
         def step(acc, offset, start_window, stash_valid, stash_evict,
                  feeder_shed, fold_rows, casc_lanes, snap_lanes, sk,
                  tag_mat, meters, valid):
+            # the stages carry names (jax.named_scope: metadata only) so
+            # a device profile can say which one an op belongs to
             tags = {k: tag_mat[i] for i, k in enumerate(names)}
             aux = None
             if cap_u is not None:
-                tags, meters, valid, aux = batch_prereduce(
-                    tags, meters, valid, interval, cap_u, sum_cols, max_cols
-                )
+                with jax.named_scope("step.prereduce"):
+                    tags, meters, valid, aux = batch_prereduce(
+                        tags, meters, valid, interval, cap_u, sum_cols, max_cols
+                    )
             if sk is not None:
-                sk = _sketch(sk, tags, meters, valid, start_window)
-            doc_tags, doc_meters, ts, doc_valid = fanout_fn(
-                tags, meters, valid, fanout_cfg
-            )
-            hi, lo, excess = _doc_fingerprint(doc_tags, with_excess=True)
-            # packing-guard hits: doc rows whose tag values overflow the
-            # declared DOC_KEY_WIDTHS contract (datamodel/code.py)
-            excess_hits = jnp.sum((excess != 0) & doc_valid)
-            gated, window, block = batch_counter_block(
-                ts, doc_valid, start_window, interval, aux=aux,
-                excess_hits=excess_hits, stash_valid=stash_valid,
-                stash_evictions=stash_evict, ring_fill=offset,
-                feeder_shed=feeder_shed, fold_rows=fold_rows,
-                sketch_rows=None if sk is None else sk.rows,
-                sketch_shed=None if sk is None else sk.shed,
-                cascade_rows=casc_lanes[0], cascade_shed=casc_lanes[1],
-                snapshot_reads=snap_lanes[0], snapshot_bytes=snap_lanes[1],
-            )
-            acc = _append_impl(
-                acc, window, hi, lo, doc_tags, doc_meters, gated, offset
-            )
+                with jax.named_scope("step.sketch"):
+                    sk = _sketch(sk, tags, meters, valid, start_window)
+            with jax.named_scope("step.fanout"):
+                doc_tags, doc_meters, ts, doc_valid = fanout_fn(
+                    tags, meters, valid, fanout_cfg
+                )
+            with jax.named_scope("step.fingerprint"):
+                hi, lo, excess = _doc_fingerprint(doc_tags, with_excess=True)
+                # packing-guard hits: doc rows whose tag values overflow the
+                # declared DOC_KEY_WIDTHS contract (datamodel/code.py)
+                excess_hits = jnp.sum((excess != 0) & doc_valid)
+            with jax.named_scope("step.counter_block"):
+                gated, window, block = batch_counter_block(
+                    ts, doc_valid, start_window, interval, aux=aux,
+                    excess_hits=excess_hits, stash_valid=stash_valid,
+                    stash_evictions=stash_evict, ring_fill=offset,
+                    feeder_shed=feeder_shed, fold_rows=fold_rows,
+                    sketch_rows=None if sk is None else sk.rows,
+                    sketch_shed=None if sk is None else sk.shed,
+                    cascade_rows=casc_lanes[0], cascade_shed=casc_lanes[1],
+                    snapshot_reads=snap_lanes[0], snapshot_bytes=snap_lanes[1],
+                )
+            with jax.named_scope("step.append"):
+                acc = _append_impl(
+                    acc, window, hi, lo, doc_tags, doc_meters, gated, offset
+                )
             if sk is None:
                 return acc, block
             return acc, block, sk
@@ -472,6 +480,10 @@ class RollupPipeline:
         stages batch i+1 while batch i's dispatch is still in flight —
         the upload overlaps compute, mirroring async_drain on the
         output side. Returns None for an all-padding batch."""
+        with self.tracer.span(SPAN_INGEST_STAGE):
+            return self._stage(batch)
+
+    def _stage(self, batch: FlowBatch) -> "StagedBatch | None":
         batch = batch.pad_to(self._pad_target(batch.size))
         if not np.any(batch.valid):
             return None

@@ -80,19 +80,24 @@ def _merge_impl(state: StashState, slot, key_hi, key_lo, tags_t, meters_t, valid
     sum_cols = np.asarray(sum_cols_t, dtype=np.int32)
     max_cols = np.asarray(max_cols_t, dtype=np.int32)
 
-    all_slot = jnp.concatenate([state.slot, slot])
-    all_hi = jnp.concatenate([state.key_hi, key_hi])
-    all_lo = jnp.concatenate([state.key_lo, key_lo])
-    all_tags = jnp.concatenate([state.tags, tags_t], axis=1)
-    all_meters = jnp.concatenate([state.meters, meters_t], axis=1)
-    all_valid = jnp.concatenate([state.valid, valid])
-
     # groupby_reduce consumes row-major meters; the stash keeps its
     # column-major layout (free column selection at flush), so the fold
     # transposes here — at fold scale this replaces the row-gather the
     # reduce no longer performs, and XLA folds it into that copy.
+    with jax.named_scope("fold.concat"):
+        all_slot = jnp.concatenate([state.slot, slot])
+        all_hi = jnp.concatenate([state.key_hi, key_hi])
+        all_lo = jnp.concatenate([state.key_lo, key_lo])
+        all_tags = jnp.concatenate([state.tags, tags_t], axis=1)
+        all_meters = jnp.transpose(
+            jnp.concatenate([state.meters, meters_t], axis=1)
+        )
+        all_valid = jnp.concatenate([state.valid, valid])
+
+    # the group-by names its own stages (fold.sort / .segments /
+    # .reduce / .compact, ops/segment.py)
     g = groupby_reduce(
-        all_slot, all_hi, all_lo, all_tags, jnp.transpose(all_meters), all_valid,
+        all_slot, all_hi, all_lo, all_tags, all_meters, all_valid,
         sum_cols, max_cols, out_capacity=s,
     )
 
@@ -322,27 +327,32 @@ def _sorted_merge_reduce(state: StashState, na_sl, na_hi, na_lo,
     ns_hi = jnp.where(state.valid, state.key_hi, jnp.uint32(_U32_MAX))
     ns_lo = jnp.where(state.valid, state.key_lo, jnp.uint32(_U32_MAX))
 
-    rank_s, rank_a = merge_ranks((ns_sl, ns_hi, ns_lo), (a_sl, a_hi, a_lo))
+    with jax.named_scope("fold.merge_ranks"):
+        rank_s, rank_a = merge_ranks((ns_sl, ns_hi, ns_lo), (a_sl, a_hi, a_lo))
     # order maps merged position → concat([stash, acc]) row; the acc
     # payload routes through a_perm so downstream gathers hit original
     # ring rows (the reduce's tag/meter payloads are never pre-sorted)
-    order = merge_order(
-        rank_s, rank_a, jnp.arange(s, dtype=jnp.int32), s + a_perm
-    )
+    with jax.named_scope("fold.merge_order"):
+        order = merge_order(
+            rank_s, rank_a, jnp.arange(s, dtype=jnp.int32), s + a_perm
+        )
 
-    cat_sl = jnp.concatenate([ns_sl, na_sl])
-    cat_hi = jnp.concatenate([ns_hi, na_hi])
-    cat_lo = jnp.concatenate([ns_lo, na_lo])
-    cat_tags = jnp.concatenate([state.tags, acc_tags], axis=1)
-    # same transpose-at-fold stance as _merge_impl (module layout note)
-    cat_meters = jnp.transpose(
-        jnp.concatenate([state.meters, acc_meters], axis=1)
-    )
+    with jax.named_scope("fold.concat"):
+        cat_sl = jnp.concatenate([ns_sl, na_sl])
+        cat_hi = jnp.concatenate([ns_hi, na_hi])
+        cat_lo = jnp.concatenate([ns_lo, na_lo])
+        cat_tags = jnp.concatenate([state.tags, acc_tags], axis=1)
+        # same transpose-at-fold stance as _merge_impl (module layout note)
+        cat_meters = jnp.transpose(
+            jnp.concatenate([state.meters, acc_meters], axis=1)
+        )
 
+    with jax.named_scope("fold.merge_order"):
+        m_sl, m_hi, m_lo = (jnp.take(c, order) for c in (cat_sl, cat_hi, cat_lo))
     g = groupby_reduce_sorted(
-        jnp.take(cat_sl, order),
-        jnp.take(cat_hi, order),
-        jnp.take(cat_lo, order),
+        m_sl,
+        m_hi,
+        m_lo,
         order,
         cat_tags,
         cat_meters,
@@ -395,7 +405,10 @@ def _merge_fold_impl(state: StashState, acc: AccumState, hi_window, sum_cols_t, 
     na_hi = jnp.where(fold_mask, acc.key_hi, jnp.uint32(_U32_MAX))
     na_lo = jnp.where(fold_mask, acc.key_lo, jnp.uint32(_U32_MAX))
     a_iota = jnp.arange(a, dtype=jnp.int32)
-    a_sl, a_hi, a_lo, a_perm = lax.sort((na_sl, na_hi, na_lo, a_iota), num_keys=3)
+    with jax.named_scope("fold.sort"):
+        a_sl, a_hi, a_lo, a_perm = lax.sort(
+            (na_sl, na_hi, na_lo, a_iota), num_keys=3
+        )
 
     new_state = _sorted_merge_reduce(
         state, na_sl, na_hi, na_lo, a_sl, a_hi, a_lo, a_perm,
@@ -509,13 +522,15 @@ def _pack_window_range(state: StashState, lo, hi):
     # Stable (window, position) compaction: selected rows first,
     # ascending window, original stash order within a window. Other rows
     # rank as SENTINEL (> any real window — slots are < hi ≤ SENTINEL).
-    rank = jnp.where(mask, state.slot, jnp.uint32(SENTINEL_SLOT))
-    iota = jnp.arange(state.capacity, dtype=jnp.int32)
-    _, order = jax.lax.sort((rank, iota), num_keys=1)
-    cols = pack_u32_columns(
-        state.slot, state.key_hi, state.key_lo, state.tags, state.meters
-    )  # [3+T+M, S]
-    packed = jnp.take(cols, order, axis=1).T  # row-major [S, 3+T+M]
+    with jax.named_scope("flush.order"):
+        rank = jnp.where(mask, state.slot, jnp.uint32(SENTINEL_SLOT))
+        iota = jnp.arange(state.capacity, dtype=jnp.int32)
+        _, order = jax.lax.sort((rank, iota), num_keys=1)
+    with jax.named_scope("flush.pack"):
+        cols = pack_u32_columns(
+            state.slot, state.key_hi, state.key_lo, state.tags, state.meters
+        )  # [3+T+M, S]
+        packed = jnp.take(cols, order, axis=1).T  # row-major [S, 3+T+M]
     total = jnp.sum(mask.astype(jnp.int32))
     return mask, packed, total
 
@@ -545,16 +560,17 @@ def _flush_range_impl(state: StashState, lo_window, hi_window, *, compact: bool 
     new_slot = jnp.where(mask, jnp.uint32(SENTINEL_SLOT), state.slot)
     new_valid = state.valid & ~mask
     if compact:
-        idx = (iota + total) % state.capacity
-        new_state = StashState(
-            slot=jnp.take(new_slot, idx),
-            key_hi=jnp.take(state.key_hi, idx),
-            key_lo=jnp.take(state.key_lo, idx),
-            tags=jnp.take(state.tags, idx, axis=1),
-            meters=jnp.take(state.meters, idx, axis=1),
-            valid=jnp.take(new_valid, idx),
-            dropped_overflow=state.dropped_overflow,
-        )
+        with jax.named_scope("flush.compact"):
+            idx = (iota + total) % state.capacity
+            new_state = StashState(
+                slot=jnp.take(new_slot, idx),
+                key_hi=jnp.take(state.key_hi, idx),
+                key_lo=jnp.take(state.key_lo, idx),
+                tags=jnp.take(state.tags, idx, axis=1),
+                meters=jnp.take(state.meters, idx, axis=1),
+                valid=jnp.take(new_valid, idx),
+                dropped_overflow=state.dropped_overflow,
+            )
     else:
         new_state = dataclasses.replace(state, slot=new_slot, valid=new_valid)
     return new_state, packed, total

@@ -68,7 +68,11 @@ from ..utils.retry import (
     retry_call,
 )
 from ..utils.spans import (
+    FLUSH_SPAN_NAMES,
     SPAN_FLUSH_DRAIN,
+    SPAN_FLUSH_ROWS,
+    SPAN_FLUSH_SPLIT,
+    SPAN_FLUSH_WAIT,
     SPAN_INGEST_DISPATCH,
     SPAN_QUERY_SNAPSHOT,
     SPAN_STATS_FETCH,
@@ -851,54 +855,70 @@ class WindowManager:
         # Pw is a handful of rows, the filter is cheaper than a device
         # compaction.
         has_wide = entry.wide_rows is not None and entry.wide_rows.size > 0
-        scalars = [jnp.asarray(entry.total, jnp.int32)]
-        if has_sketch:
-            scalars.append(jnp.asarray(entry.pend_n, jnp.int32))
-        if has_wide:
-            scalars.append(
-                jnp.sum(entry.wide_wins != jnp.uint32(SENTINEL_WIN)).astype(
-                    jnp.int32
-                )
-            )
-        scalars += [jnp.asarray(tf.total, jnp.int32) for tf in entry.tiers]
-        n_wide = 0
-        if len(scalars) == 1:
-            total, n_blocks, tier_totals = int(self._fetch(scalars[0])), 0, []
-        else:
-            vec = self._fetch(jnp.stack(scalars))
-            o = 1 + int(has_sketch) + int(has_wide)
-            total = int(vec[0])
-            n_blocks = int(vec[1]) if has_sketch else 0
+        # the first fetch of a drain blocks until the fold and the range
+        # flush dispatched ahead of it have run on the device
+        with self.tracer.span(SPAN_FLUSH_WAIT):
+            scalars = [jnp.asarray(entry.total, jnp.int32)]
+            if has_sketch:
+                scalars.append(jnp.asarray(entry.pend_n, jnp.int32))
             if has_wide:
-                n_wide = int(vec[1 + int(has_sketch)])
-            tier_totals = [int(v) for v in vec[o:]]
-        if not has_sketch and not entry.tiers and total == 0:
-            # pure exact-only drain with nothing flushed. The sketch and
-            # cascade paths must NOT return here even with every count
-            # zero: previously-held blocks may still marry this drain's
-            # [lo, hi) range, and a tier window whose exact rows were
-            # all shed (sketch-only coverage) still closes below.
-            return []
+                scalars.append(
+                    jnp.sum(entry.wide_wins != jnp.uint32(SENTINEL_WIN)).astype(
+                        jnp.int32
+                    )
+                )
+            scalars += [jnp.asarray(tf.total, jnp.int32) for tf in entry.tiers]
+            n_wide = 0
+            if len(scalars) == 1:
+                total, n_blocks, tier_totals = int(self._fetch(scalars[0])), 0, []
+            else:
+                vec = self._fetch(jnp.stack(scalars))
+                o = 1 + int(has_sketch) + int(has_wide)
+                total = int(vec[0])
+                n_blocks = int(vec[1]) if has_sketch else 0
+                if has_wide:
+                    n_wide = int(vec[1 + int(has_sketch)])
+                tier_totals = [int(v) for v in vec[o:]]
+        # an empty drain still runs the (empty) rows and split phases:
+        # previously-held sketch blocks may marry this drain's [lo, hi)
+        # range, and a tier window whose exact rows were all shed
+        # (sketch-only coverage) still closes there
+        with self.tracer.span(SPAN_FLUSH_ROWS):
+            if total == 0 and n_blocks == 0 and n_wide == 0 and not any(tier_totals):
+                flat = np.zeros((0,), np.uint32)  # nothing to transfer
+            else:
+                # each distinct `total` compiles a slice and a reshape
+                # here: the tracer's compile lanes on this span count them
+                parts = [entry.packed[:total].reshape(-1)]
+                if has_sketch:
+                    parts += [entry.pend[:n_blocks].reshape(-1),
+                              entry.pend_win[:n_blocks]]
+                if n_wide:
+                    parts += [entry.wide_rows.reshape(-1), entry.wide_wins]
+                for tf, t in zip(entry.tiers, tier_totals):
+                    parts.append(tf.packed[:t].reshape(-1))
+                if len(parts) == 1:
+                    # nothing rode along — fetch the 2D rows directly (the
+                    # reshape+concatenate would compile a kernel per
+                    # distinct `total`, a real tax at one advance/second)
+                    flat = self._fetch(entry.packed[:total]).reshape(-1)
+                else:
+                    flat = self._fetch(jnp.concatenate(parts))
+        with self.tracer.span(SPAN_FLUSH_SPLIT):
+            return self._split_drained(
+                entry, flat, total, n_blocks, n_wide, tier_totals
+            )
+
+    def _split_drained(
+        self, entry: "_FlushEntry", flat: np.ndarray, total: int,
+        n_blocks: int, n_wide: int, tier_totals: list[int],
+    ) -> list[FlushedWindow]:
+        """The host half of a drain: cut the fetched u32 run back into
+        exact rows, sketch blocks and tier rows, split the rows into
+        windows and marry the blocks to them."""
+        has_sketch = entry.pend is not None
         row_cols = entry.packed.shape[1]
         wide = entry.pend.shape[1] if has_sketch else 0
-        if total == 0 and n_blocks == 0 and n_wide == 0 and not any(tier_totals):
-            flat = np.zeros((0,), np.uint32)  # nothing to transfer
-        else:
-            parts = [entry.packed[:total].reshape(-1)]
-            if has_sketch:
-                parts += [entry.pend[:n_blocks].reshape(-1),
-                          entry.pend_win[:n_blocks]]
-            if n_wide:
-                parts += [entry.wide_rows.reshape(-1), entry.wide_wins]
-            for tf, t in zip(entry.tiers, tier_totals):
-                parts.append(tf.packed[:t].reshape(-1))
-            if len(parts) == 1:
-                # nothing rode along — fetch the 2D rows directly (the
-                # reshape+concatenate would compile a kernel per
-                # distinct `total`, a real tax at one advance/second)
-                flat = self._fetch(entry.packed[:total]).reshape(-1)
-            else:
-                flat = self._fetch(jnp.concatenate(parts))
         o = 0
 
         def take(n: int) -> np.ndarray:
@@ -1640,7 +1660,18 @@ class WindowManager:
         or burning a host sync. `stash_occupancy`/`stash_evictions` are
         as of the last fused append dispatch; the `counters` property
         below fetches the live values when a probe wants them."""
+        xla_compiles, xla_compile_us = self.tracer.compile_lanes()
+        flush_compiles, flush_compile_us = self.tracer.compile_lanes(FLUSH_SPAN_NAMES)
         return {
+            # backend compiles charged to this manager's spans by the
+            # process-wide listener (utils/spans), persistent-cache reads
+            # included: all of them, and those under flush.drain — a
+            # close that compiles per document count shows here, where
+            # jit_compiles / jit_retraces watch the fused step alone
+            "xla_compiles": xla_compiles,
+            "xla_compile_us": xla_compile_us,
+            "flush_compiles": flush_compiles,
+            "flush_compile_us": flush_compile_us,
             "doc_in": self.total_docs_in,
             "flushed_doc": self.total_flushed,
             "drop_before_window": self.drop_before_window,

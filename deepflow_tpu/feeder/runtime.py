@@ -66,6 +66,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import threading
+import time
 from collections import deque
 from pathlib import Path
 
@@ -75,9 +76,12 @@ from .. import chaos
 from ..datamodel.batch import FlowBatch
 from ..ingest.framing import HEADER_LEN, FlowHeader, MessageType, split_message_spans
 from ..utils.spans import (
+    SPAN_FEEDER_ASSEMBLE,
     SPAN_FEEDER_COALESCE,
+    SPAN_FEEDER_DECODE,
     SPAN_FEEDER_DISPATCH,
     SPAN_FEEDER_DRAIN,
+    SPAN_FEEDER_PUMP,
     SpanTracer,
 )
 from ..utils.retry import RetryPolicy, decorrelated_rng
@@ -142,6 +146,10 @@ class FrameCodecBase:
     def __init__(self):
         self.decode_errors = 0
         self.quarantine: deque = deque(maxlen=QUARANTINE_KEEP)
+        # where the sink's feeder-side spans go (feeder.assemble): its
+        # own tracer until a FeederRuntime adopts the sink and hands it
+        # the runtime's, so a feeder.* name lives on one tracer
+        self.tracer = SpanTracer(service="deepflow_tpu.feeder")
 
     def _decode_frame(self, raw: bytes):
         raise NotImplementedError
@@ -178,6 +186,12 @@ class _FlowFrameCodec(FrameCodecBase):
         if not parts:
             return None
         return FlowChunk(FlowBatch.concat(parts))
+
+    def _assemble(self, chunks: list[FlowChunk]) -> FlowBatch:
+        """One batch's chunks → one FlowBatch (37 tag columns and the
+        meter matrix concatenated: ~50 MB at a full 131072-row bucket)."""
+        with self.tracer.span(SPAN_FEEDER_ASSEMBLE):
+            return FlowBatch.concat([c.fb for c in chunks])
 
 
 class PipelineFeedSink(_FlowFrameCodec):
@@ -225,7 +239,7 @@ class PipelineFeedSink(_FlowFrameCodec):
         }
 
     def emit(self, chunks: list[FlowChunk], rows: int, bucket: int, shed: int) -> list:
-        fb = FlowBatch.concat([c.fb for c in chunks])
+        fb = self._assemble(chunks)
         assert fb.size == rows
         carried = self._shed_carry
         shed += carried
@@ -313,7 +327,7 @@ class ShardedFeedSink(_FlowFrameCodec):
         self.feeder_shed = 0  # sharded path has no device counter block
 
     def emit(self, chunks: list[FlowChunk], rows: int, bucket: int, shed: int) -> list:
-        fb = FlowBatch.concat([c.fb for c in chunks]).pad_to(bucket)
+        fb = self._assemble(chunks).pad_to(bucket)
         out = self.swm.ingest(fb.tags, fb.meters, fb.valid)
         # only account the shed once the batch actually landed — on a
         # failed dispatch the runtime re-owns it
@@ -475,6 +489,8 @@ class FeederRuntime:
         self.tracer = tracer if tracer is not None else SpanTracer(
             service="deepflow_tpu.feeder"
         )
+        if hasattr(sink, "tracer"):
+            sink.tracer = self.tracer  # feeder.assemble joins the runtime's spans
         self._journal = journal
         # push query plane (ISSUE 11): flushed outputs become
         # WindowClosed/TierClosed events AFTER the pump's last emit —
@@ -542,7 +558,15 @@ class FeederRuntime:
             "snapshot_errors": 0,
             # push query plane (ISSUE 11)
             "events_published": 0,
+            # pumps that drained nothing and emitted nothing: they
+            # record no span (a starved feeder pumps ~2,000 times a
+            # second and would turn the span ring over in one)
+            "idle_pumps": 0,
         }
+        # decode_frame time of the round under way (feeder.decode is ONE
+        # record a round, not one a frame: see _record_decode)
+        self._decode_us = 0
+        self._decode_frames = 0
         self._pump_count = 0
         self.last_snapshot = None  # most recent scheduled OpenSnapshot
         self._snapshot_err_logged = False
@@ -758,6 +782,7 @@ class FeederRuntime:
         it — the single path pump() and replay_journal() share, so
         recovery exercises no special-case decode code."""
         errs0 = int(getattr(self.sink, "decode_errors", 0))
+        t0 = time.perf_counter()
         try:
             chunk = self.sink.decode_frame(raw)
         except Exception:
@@ -766,6 +791,9 @@ class FeederRuntime:
             self._count("bad_frames")
             self._drop_admit_stamp()
             return
+        finally:
+            self._decode_us += int((time.perf_counter() - t0) * 1e6)
+            self._decode_frames += 1
         if int(getattr(self.sink, "decode_errors", 0)) > errs0:
             self._count("bad_frames")  # quarantined by the codec
             self._drop_admit_stamp()
@@ -782,6 +810,16 @@ class FeederRuntime:
         self._count("records_in", chunk.rows)
         self._admit(chunk, out)
 
+    def _record_decode(self, start_s: float) -> None:
+        """The decode_frame calls since the last record, as ONE
+        feeder.decode span: they interleave with the dispatches that a
+        full bucket triggers, and a record a frame (107–128 a second)
+        would push the spans a profile needs out of the ring. `start_s`
+        is where the first of them began."""
+        if self._decode_frames:
+            self.tracer.record(SPAN_FEEDER_DECODE, self._decode_us, start_s=start_s)
+            self._decode_us = self._decode_frames = 0
+
     # -- the pump --------------------------------------------------------
     def pump(self) -> list:
         """One fan-in cycle: drain every queue (rounds_per_pump visits
@@ -793,7 +831,18 @@ class FeederRuntime:
             return self._pump_locked()
 
     def _pump_locked(self) -> list:
+        with self.tracer.span(SPAN_FEEDER_PUMP) as pump_span:
+            out, busy = self._pump_spanned()
+            if not busy:
+                pump_span.discard()
+                self._count("idle_pumps")
+            return out
+
+    def _pump_spanned(self) -> tuple[list, bool]:
+        """The pump under its feeder.pump span → (outputs, whether it
+        took a frame off a queue or dispatched a batch)."""
         out: list = []
+        drained_total = 0
         if self._lineage is not None:
             self._lineage.begin_pump()
             # frames lost to queue OVERWRITE never reach _process_frame
@@ -808,14 +857,17 @@ class FeederRuntime:
         nq = len(self.queues)
         for _ in range(self.config.rounds_per_pump):
             admit: list = []
-            with self.tracer.span(SPAN_FEEDER_DRAIN):
+            with self.tracer.span(SPAN_FEEDER_DRAIN) as drain_span:
                 drained = 0
                 for j in range(nq):
                     drained += self._visit((self._rr + j) % nq, admit)
+                if not drained:
+                    drain_span.discard()  # empty queues: nothing to account
             self._rr = (self._rr + 1) % nq
             if not admit and not drained:
                 break
-            with self.tracer.span(SPAN_FEEDER_COALESCE):
+            drained_total += drained
+            with self.tracer.span(SPAN_FEEDER_COALESCE) as coalesce_span:
                 # one shed decision per round: frames the live run
                 # sheds-and-counts are NOT journaled — replay would
                 # resurrect rows the counters already declared shed,
@@ -837,6 +889,7 @@ class FeederRuntime:
                         self._shed_frame(raw)
                         continue
                     self._process_frame(raw, out)
+                self._record_decode(coalesce_span.wall)
         if (
             self.config.emit_partial
             and self._rows > 0
@@ -845,12 +898,11 @@ class FeederRuntime:
             out.extend(self._emit(self._rows, self._bucket_for(self._rows)))
         if self._journal is not None:
             self._journal.mark()
-        if (
-            self.degraded
-            and self._probe_now
-            and self.counters["batches_out"] + self.counters["emit_failures"]
-            == dispatch0
-        ):
+        dispatched = (
+            self.counters["batches_out"] + self.counters["emit_failures"]
+            != dispatch0
+        )
+        if self.degraded and self._probe_now and not dispatched:
             # the probe pump had no data to send, so nothing was tested:
             # keep the probe armed instead of re-arming the countdown —
             # otherwise a feeder that goes idle while degraded sheds the
@@ -881,7 +933,7 @@ class FeederRuntime:
                 else:
                     self._publish_snapshot_event()
         self._publish_events(out)
-        return out
+        return out, bool(drained_total) or dispatched
 
     # -- push events (ISSUE 11) ------------------------------------------
     def _publish_events(self, out: list) -> None:
@@ -1105,6 +1157,7 @@ class FeederRuntime:
 
         with self._pump_mutex:
             out: list = []
+            t_replay = time.time()
             epoch, entries, truncated = read_journal(path)
             if self._journal is not None:
                 try:
@@ -1134,6 +1187,7 @@ class FeederRuntime:
                         out.extend(self._emit(self._rows, self._bucket_for(self._rows)))
                     if self._journal is not None:
                         self._journal.mark()
+            self._record_decode(t_replay)
             return out
 
     # -- thread ----------------------------------------------------------

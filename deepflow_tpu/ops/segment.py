@@ -144,16 +144,82 @@ def groupby_reduce(
     """
     n = slot.shape[0]
 
-    slot = jnp.where(valid, slot, jnp.uint32(SENTINEL_SLOT))
-    key_hi = jnp.where(valid, key_hi, jnp.uint32(_U32_MAX))
-    key_lo = jnp.where(valid, key_lo, jnp.uint32(_U32_MAX))
+    # stage names for device profiles (metadata only). The fused step's
+    # pre-reduce is this same group-by: there they read
+    # step.prereduce/fold.sort, … under the step's own scope.
+    with jax.named_scope("fold.sort"):
+        slot = jnp.where(valid, slot, jnp.uint32(SENTINEL_SLOT))
+        key_hi = jnp.where(valid, key_hi, jnp.uint32(_U32_MAX))
+        key_lo = jnp.where(valid, key_lo, jnp.uint32(_U32_MAX))
 
-    iota = jnp.arange(n, dtype=jnp.int32)
-    s_slot, s_hi, s_lo, perm = lax.sort((slot, key_hi, key_lo, iota), num_keys=3)
+        iota = jnp.arange(n, dtype=jnp.int32)
+        s_slot, s_hi, s_lo, perm = lax.sort(
+            (slot, key_hi, key_lo, iota), num_keys=3
+        )
     return groupby_reduce_sorted(
         s_slot, s_hi, s_lo, perm, tags_t, meters_rows,
         sum_cols, max_cols, out_capacity=out_capacity,
     )
+
+
+def _reduce_meters(meters_rows, perm, seg_id, cap: int, first_pos,
+                   sum_cols: np.ndarray, max_cols: np.ndarray):
+    """Per-segment SUM / MAX of the meter rows in sorted order →
+    [M, cap]; columns of absent segments are unspecified (callers mask
+    by their live-segment prefix)."""
+    m = meters_rows.shape[1]
+    # Full-width segment ops + per-column select, NOT subset-indexed
+    # ops: `meters_rows[:, sum_cols]` materializes a strided copy of
+    # [N, |subset|] before each op, which costs more than running the
+    # op over all M lanes and discarding the unwanted half (measured
+    # ~16% off the whole fold at 588k rows — PERF.md §7b follow-up).
+    # On TPU both ops fuse into ONE scatter-free Pallas suffix-scan
+    # pass (segreduce_pallas.py, PERF.md §9).
+    if m and _use_pallas_reduce():
+        from .segreduce_pallas import sorted_segment_sum_max
+
+        ps, pm = sorted_segment_sum_max(
+            jnp.take(meters_rows, perm, axis=0), seg_id, cap, first_pos
+        )
+        if not max_cols.size:
+            out_meters = ps.T
+        elif not sum_cols.size:
+            out_meters = pm.T
+        else:
+            is_sum = np.zeros((m,), bool)
+            is_sum[sum_cols] = True
+            out_meters = jnp.where(jnp.asarray(is_sum)[None, :], ps, pm).T
+    elif m:
+        # One row-gather moves all M meter lanes of a row at once.
+        sorted_rows = jnp.take(meters_rows, perm, axis=0)  # [N, M]
+        # (segment_max yields -inf for empty segments; the caller's
+        # seg_valid mask zeroes those columns, so no isfinite rewrite — it
+        # would also mask NaNs from genuinely corrupt meters.)
+        ps = (
+            jax.ops.segment_sum(
+                sorted_rows, seg_id, num_segments=cap, indices_are_sorted=True
+            )
+            if sum_cols.size
+            else None
+        )
+        pm = (
+            jax.ops.segment_max(
+                sorted_rows, seg_id, num_segments=cap, indices_are_sorted=True
+            )
+            if max_cols.size
+            else None
+        )
+        if pm is None:
+            out_meters = ps.T
+        elif ps is None:
+            out_meters = pm.T
+        else:
+            is_sum = np.zeros((m,), bool)
+            is_sum[sum_cols] = True
+            out_meters = jnp.where(jnp.asarray(is_sum)[None, :], ps, pm).T  # [M, cap]
+    else:
+        out_meters = jnp.zeros((0, cap), meters_rows.dtype)
+    return out_meters
 
 
 def groupby_reduce_sorted(
@@ -182,97 +248,51 @@ def groupby_reduce_sorted(
         with an iota payload produces.
     """
     n = s_slot.shape[0]
-    m = meters_rows.shape[1]
     cap = int(out_capacity) if out_capacity is not None else n
     sum_cols = np.asarray(sum_cols, np.int32)
     max_cols = np.asarray(max_cols, np.int32)
 
-    head = jnp.concatenate(
-        [
-            jnp.ones((1,), dtype=bool),
-            (s_slot[1:] != s_slot[:-1]) | (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1]),
-        ]
-    )
-    # Sentinel rows sort after every live row, so live rows are a prefix
-    # and live segments are exactly segment ids [0, num_seg).
-    live_row = s_slot != jnp.uint32(SENTINEL_SLOT)
-    live_head = head & live_row
-    num_seg = jnp.sum(live_head.astype(jnp.int32))
-    seg_id = jnp.cumsum(head.astype(jnp.int32)) - 1  # [N] ascending
-    # Dead rows get an out-of-range id so every segment op drops them.
-    # It must be `n`, not `cap`: live overflow segments carry ids in
-    # [cap, num_seg) and the id sequence must stay ascending for the
-    # indices_are_sorted hint below to be honest.
-    seg_id = jnp.where(live_row, seg_id, n)
-
-    # First sorted position of each kept segment: seg_id is ascending by
-    # construction, so first occurrence = binary search. A segment_min
-    # here measured ~24 ms at 2M rows (r5 bisect, stage G−F) because
-    # TPU scatter reductions cost per ROW; searchsorted is O(cap·log N).
-    first_pos = jnp.searchsorted(seg_id, jnp.arange(cap, dtype=jnp.int32))
-
-    # Full-width segment ops + per-column select, NOT subset-indexed
-    # ops: `meters_rows[:, sum_cols]` materializes a strided copy of
-    # [N, |subset|] before each op, which costs more than running the
-    # op over all M lanes and discarding the unwanted half (measured
-    # ~16% off the whole fold at 588k rows — PERF.md §7b follow-up).
-    # On TPU both ops fuse into ONE scatter-free Pallas suffix-scan
-    # pass (segreduce_pallas.py, PERF.md §9).
-    if m and _use_pallas_reduce():
-        from .segreduce_pallas import sorted_segment_sum_max
-
-        ps, pm = sorted_segment_sum_max(
-            jnp.take(meters_rows, perm, axis=0), seg_id, cap, first_pos
+    with jax.named_scope("fold.segments"):
+        head = jnp.concatenate(
+            [
+                jnp.ones((1,), dtype=bool),
+                (s_slot[1:] != s_slot[:-1]) | (s_hi[1:] != s_hi[:-1]) | (s_lo[1:] != s_lo[:-1]),
+            ]
         )
-        if not max_cols.size:
-            out_meters = ps.T
-        elif not sum_cols.size:
-            out_meters = pm.T
-        else:
-            is_sum = np.zeros((m,), bool)
-            is_sum[sum_cols] = True
-            out_meters = jnp.where(jnp.asarray(is_sum)[None, :], ps, pm).T
-    elif m:
-        # One row-gather moves all M meter lanes of a row at once.
-        sorted_rows = jnp.take(meters_rows, perm, axis=0)  # [N, M]
-        # (segment_max yields -inf for empty segments; the seg_valid mask
-        # below zeroes those columns, so no isfinite rewrite — it would
-        # also mask NaNs from genuinely corrupt meters.)
-        ps = (
-            jax.ops.segment_sum(
-                sorted_rows, seg_id, num_segments=cap, indices_are_sorted=True
-            )
-            if sum_cols.size
-            else None
-        )
-        pm = (
-            jax.ops.segment_max(
-                sorted_rows, seg_id, num_segments=cap, indices_are_sorted=True
-            )
-            if max_cols.size
-            else None
-        )
-        if pm is None:
-            out_meters = ps.T
-        elif ps is None:
-            out_meters = pm.T
-        else:
-            is_sum = np.zeros((m,), bool)
-            is_sum[sum_cols] = True
-            out_meters = jnp.where(jnp.asarray(is_sum)[None, :], ps, pm).T  # [M, cap]
-    else:
-        out_meters = jnp.zeros((0, cap), meters_rows.dtype)
+        # Sentinel rows sort after every live row, so live rows are a prefix
+        # and live segments are exactly segment ids [0, num_seg).
+        live_row = s_slot != jnp.uint32(SENTINEL_SLOT)
+        live_head = head & live_row
+        num_seg = jnp.sum(live_head.astype(jnp.int32))
+        seg_id = jnp.cumsum(head.astype(jnp.int32)) - 1  # [N] ascending
+        # Dead rows get an out-of-range id so every segment op drops them.
+        # It must be `n`, not `cap`: live overflow segments carry ids in
+        # [cap, num_seg) and the id sequence must stay ascending for the
+        # indices_are_sorted hint below to be honest.
+        seg_id = jnp.where(live_row, seg_id, n)
 
-    k = jnp.arange(cap, dtype=jnp.int32)
-    seg_valid = k < jnp.minimum(num_seg, cap)
-    fp = jnp.where(seg_valid, first_pos, 0).astype(jnp.int32)
+        # First sorted position of each kept segment: seg_id is ascending by
+        # construction, so first occurrence = binary search. A segment_min
+        # here measured ~24 ms at 2M rows (r5 bisect, stage G−F) because
+        # TPU scatter reductions cost per ROW; searchsorted is O(cap·log N).
+        first_pos = jnp.searchsorted(seg_id, jnp.arange(cap, dtype=jnp.int32))
 
-    out_slot = jnp.where(seg_valid, jnp.take(s_slot, fp), jnp.uint32(SENTINEL_SLOT))
-    out_hi = jnp.where(seg_valid, jnp.take(s_hi, fp), 0)
-    out_lo = jnp.where(seg_valid, jnp.take(s_lo, fp), 0)
-    rep_orig = jnp.take(perm, fp)
-    out_tags = jnp.where(seg_valid[None, :], jnp.take(tags_t, rep_orig, axis=1), 0)
-    out_meters = jnp.where(seg_valid[None, :], out_meters, 0)
+    with jax.named_scope("fold.reduce"):
+        out_meters = _reduce_meters(
+            meters_rows, perm, seg_id, cap, first_pos, sum_cols, max_cols
+        )
+
+    with jax.named_scope("fold.compact"):
+        k = jnp.arange(cap, dtype=jnp.int32)
+        seg_valid = k < jnp.minimum(num_seg, cap)
+        fp = jnp.where(seg_valid, first_pos, 0).astype(jnp.int32)
+
+        out_slot = jnp.where(seg_valid, jnp.take(s_slot, fp), jnp.uint32(SENTINEL_SLOT))
+        out_hi = jnp.where(seg_valid, jnp.take(s_hi, fp), 0)
+        out_lo = jnp.where(seg_valid, jnp.take(s_lo, fp), 0)
+        rep_orig = jnp.take(perm, fp)
+        out_tags = jnp.where(seg_valid[None, :], jnp.take(tags_t, rep_orig, axis=1), 0)
+        out_meters = jnp.where(seg_valid[None, :], out_meters, 0)
 
     return Grouped(
         slot=out_slot,

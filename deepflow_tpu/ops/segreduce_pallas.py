@@ -108,6 +108,7 @@ def _block_suffix(rows: jnp.ndarray, seg2d: jnp.ndarray, block: int):
         ],
         out_shape=[out, out],
         interpret=_interpret(),
+        name="segreduce_suffix_scan",  # the kernel's name in a device profile
     )(seg2d, rows)
 
 

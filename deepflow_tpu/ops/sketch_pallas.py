@@ -233,6 +233,7 @@ def sketch_update_fused(
         # 0/1 (positions count pallas_call operands, kernel order)
         input_output_aliases={13: 0, 14: 1},
         interpret=jax.default_backend() == "cpu",
+        name="sketch_fused_update",  # the kernel's name in a device profile
     )(
         i32(s_slot), i32(s_gid), i32(s_reg), i32(s_rho),
         i32(s_mask), i32(w_head), i32(rw), cms_col, tk_col,

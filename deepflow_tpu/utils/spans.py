@@ -3,23 +3,77 @@
 The reference attributes latency per pipeline stage by shipping every
 component's counters through its own stats pipeline (stats.go:89-202);
 what it cannot see — and what the TPU build critically needs — is where
-a *host-driven* batch spends its wall time: dispatching the fused jit
-step, blocking on the stats fetch, advancing the window (fold + flush
-dispatch), draining packed flush rows, saving checkpoints. This module
-is that seam: a monotonic-clock span recorder with a fixed vocabulary
-of stage names, cheap enough to stay always-on (two perf_counter calls
-per span), exposing three faces:
+a *host-driven* batch spends its wall time. This module is that seam: a
+monotonic-clock span recorder with a fixed vocabulary of stage names,
+cheap enough to stay always-on, exposing three faces:
 
-  * `summary()` — per-stage count/total/max/last aggregates for bench
-    JSON snapshots (BENCH files carry stage attribution);
+  * `summary()` — per-stage count / total / self / max / last
+    aggregates and the compile lanes, for bench JSON snapshots;
   * `get_counters()` — a flat Countable field map so the tracer
     registers on `utils/stats.StatsCollector` like any component and
     its aggregates dogfood into the `deepflow_system` table;
   * `export_otlp(exporter)` — drains the recent-span ring through the
     EXISTING OTLP exporter path (server/exporters.OtlpExporter's
-    l7_flow_log traces lane), so pipeline stages show up as spans in
-    whatever trace backend the exporter points at — including our own
-    IntegrationCollector round-trip.
+    l7_flow_log traces lane), parent ids included.
+
+**Nesting.** Every tracer in the process pushes its open spans onto ONE
+per-thread stack, so the feeder's spans and the pipeline's nest across
+the two tracers. A span's record carries its own `span_id` and the
+`parent_span_id` of the span open beneath it on that thread (a root
+span opens a trace; its descendants share the `trace_id`); on exit it
+adds its duration to its parent's child time, and the aggregate keeps
+`self_us` = duration less what child records covered. `record()` (work
+measured by the caller, split over non-contiguous sections) nests the
+same way under whatever span is open on the calling thread.
+
+The served path's vocabulary, as a tree (`f` = the FeederRuntime's
+tracer, `p` = the pipeline's / WindowManager's; a name lives on one):
+
+    feeder.pump                  f  one pump that drained or emitted
+      feeder.drain               f  a round's queue gets
+      feeder.coalesce            f  a round's journal + decode + admit
+        feeder.decode            f  the round's sink.decode_frame calls,
+                                    summed, ONE record a round
+        feeder.dispatch          f  sink.emit of one bucket batch
+          feeder.assemble        f  FlowBatch.concat of the chunks
+          ingest.stage           p  pad, np.stack, three uploads
+          window.fold            p  fold dispatch when the ring is full
+          ingest.dispatch        p  the fused step's dispatch
+          stats.fetch            p  the per-batch counter-block sync
+          window.advance         p  a close: fold + range-flush dispatch
+            window.fold          p
+          flush.drain            p  every ready flush entry
+            flush.wait           p  scalar fetch: waits for the fold
+                                    and range flush queued ahead
+            flush.rows           p  slice / reshape / concatenate and
+                                    the row fetch
+            flush.split          p  unpack, per-window split, sketch
+                                    and tier marrying
+      feeder.dispatch            f  the pump's sub-bucket tail emit
+    checkpoint.save, query.snapshot, query.cache   p  roots
+    xla.compile                  a ring record (no aggregate of its
+                                    own) under whichever span compiled
+
+A pump that drains nothing and emits nothing records no span (it counts
+`idle_pumps` on the feeder): a starved feeder pumps ~2,000 times a
+second, which would turn the 4,096-record ring over in two.
+
+**Compiles.** One process-wide listener on JAX's
+`/jax/core/compile/backend_compile_duration` event (registered the
+first time a span opens with `jax` imported) charges every backend
+compile, persistent-cache reads included, to the innermost span open on
+the compiling thread: an `xla.compile` record in that span's tracer's
+ring (parent = that span, start = arrival less the duration), the
+`compiles` / `compile_us` lanes of that span name's aggregate, and the
+span's child time (so `self_us` excludes it). A compile with no span
+open lands in the module's `unspanned_compiles()` lanes.
+
+**Profiles.** While a span is open it holds a
+`jax.profiler.TraceAnnotation(name)` (only where `jax` is already in
+`sys.modules`: the tracer itself imports no JAX), so a profile an
+operator takes (`jax.profiler.start_trace(dir)` … `stop_trace()`, or
+`chipbench/tests/dump_trace.py`) shows these spans on the trace's own
+clock, on the host thread that ran them, beside the device's lines.
 
 `JitCacheMonitor` rides along: retrace/compile counters for one jitted
 callable, read from the pjit cache size — the CI gate asserts ZERO
@@ -30,16 +84,19 @@ silent compile-per-batch failure mode) trips loudly.
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import math
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 
 import numpy as np
 
 # The pipeline stage vocabulary (explicit names, ISSUE 3). Everything
 # the window managers emit uses these; ad-hoc names are allowed but the
 # docs/tests pin this set.
+SPAN_INGEST_STAGE = "ingest.stage"  # pad to bucket + np.stack + the three uploads
 SPAN_INGEST_DISPATCH = "ingest.dispatch"  # fused jit step dispatch (async — host-side cost)
 SPAN_STATS_FETCH = "stats.fetch"  # the ONE per-batch device→host stats sync
 SPAN_WINDOW_ADVANCE = "window.advance"  # fold + flush_range dispatch on window close
@@ -49,19 +106,42 @@ SPAN_WINDOW_ADVANCE = "window.advance"  # fold + flush_range dispatch on window 
 # this is the lane the merge-fold exists to shrink)
 SPAN_WINDOW_FOLD = "window.fold"
 SPAN_FLUSH_DRAIN = "flush.drain"  # packed flush fetch + per-window split
+# flush.drain's three phases per flush entry (ISSUE 26): the scalar
+# fetch blocks until the fold and range flush queued ahead have run;
+# the rows phase is where a per-document-count slice/reshape compiles
+SPAN_FLUSH_WAIT = "flush.wait"
+SPAN_FLUSH_ROWS = "flush.rows"
+SPAN_FLUSH_SPLIT = "flush.split"
+FLUSH_SPAN_NAMES = (
+    SPAN_FLUSH_DRAIN, SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT
+)
 SPAN_CHECKPOINT_SAVE = "checkpoint.save"  # window-state snapshot to .npz
 # live read plane (ISSUE 10): pull-only open-window snapshot reads and
 # result-cache lookups — separate names so a live dashboard's read
 # latency is attributable on its own instead of hiding in flush.drain
 SPAN_QUERY_SNAPSHOT = "query.snapshot"  # snapshot_open: fold + 2-fetch read
 SPAN_QUERY_CACHE = "query.cache"  # result-cache lookup (hit or miss)
+# a backend compile seen by the process-wide listener: a ring record
+# under the span that compiled, never an aggregate of its own
+SPAN_XLA_COMPILE = "xla.compile"
 
 # Feeder-runtime stages (ISSUE 4) — emitted by feeder/runtime.py on its
 # own tracer; NOT in PIPELINE_SPAN_NAMES (a pipeline can run feederless,
 # and the pinned vocabulary must stay satisfiable by a bare pipeline).
-SPAN_FEEDER_DRAIN = "feeder.drain"  # queue gets + frame decode
-SPAN_FEEDER_COALESCE = "feeder.coalesce"  # bucket assembly + pad
+SPAN_FEEDER_PUMP = "feeder.pump"  # one pump that drained or emitted (root)
+SPAN_FEEDER_DRAIN = "feeder.drain"  # a round's queue gets
+SPAN_FEEDER_COALESCE = "feeder.coalesce"  # journal + decode + bucket assembly
+SPAN_FEEDER_DECODE = "feeder.decode"  # a round's decode_frame calls, summed
 SPAN_FEEDER_DISPATCH = "feeder.dispatch"  # staged batch → sink ingest
+SPAN_FEEDER_ASSEMBLE = "feeder.assemble"  # FlowBatch.concat of a batch's chunks
+FEEDER_SPAN_NAMES = (
+    SPAN_FEEDER_PUMP,
+    SPAN_FEEDER_DRAIN,
+    SPAN_FEEDER_COALESCE,
+    SPAN_FEEDER_DECODE,
+    SPAN_FEEDER_DISPATCH,
+    SPAN_FEEDER_ASSEMBLE,
+)
 
 # Push query plane (ISSUE 11) — emitted by querier/subscribe.py and
 # querier/alerts.py on their own tracers; also not pipeline vocabulary
@@ -73,15 +153,21 @@ SPAN_SUBSCRIPTION_EVAL = "subscribe.eval"  # one shared eval serving N watchers
 SPAN_ALERT_EVAL = "alert.eval"  # rule query + state-machine step
 
 PIPELINE_SPAN_NAMES = (
+    SPAN_INGEST_STAGE,
     SPAN_INGEST_DISPATCH,
     SPAN_STATS_FETCH,
     SPAN_WINDOW_ADVANCE,
     SPAN_WINDOW_FOLD,
     SPAN_FLUSH_DRAIN,
+    SPAN_FLUSH_WAIT,
+    SPAN_FLUSH_ROWS,
+    SPAN_FLUSH_SPLIT,
     SPAN_CHECKPOINT_SAVE,
     SPAN_QUERY_SNAPSHOT,
     SPAN_QUERY_CACHE,
 )
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,13 +199,20 @@ class SpanHistSpec:
     bins: int = 1024
     vmin: float = 1.0  # µs; durations at/below land in bin 0
     gamma: float = 1.02
+    # ln(gamma), computed once: bin() runs on every record
+    _log_gamma: float = dataclasses.field(
+        init=False, repr=False, compare=False, default=0.0
+    )
+
+    def __post_init__(self):
+        object.__setattr__(self, "_log_gamma", math.log(self.gamma))
 
     def bin(self, duration_us: float) -> int:
-        import math
-
-        v = max(float(duration_us), self.vmin)
-        b = int(math.floor(math.log(v / self.vmin) / math.log(self.gamma)))
-        return min(max(b, 0), self.bins - 1)
+        v = float(duration_us)
+        if v <= self.vmin:
+            return 0
+        return min(int(math.log(v / self.vmin) / self._log_gamma),
+                   self.bins - 1)
 
     def centers(self) -> np.ndarray:
         return self.vmin * np.power(
@@ -153,33 +246,171 @@ SPAN_QUANTILES = (0.5, 0.95, 0.99)
 
 
 class _Agg:
-    __slots__ = ("count", "total_us", "max_us", "last_us", "hist")
+    __slots__ = ("count", "total_us", "self_us", "max_us", "last_us",
+                 "compiles", "compile_us", "hist")
 
     def __init__(self, bins: int):
         self.count = 0
         self.total_us = 0
+        # duration less what child records covered (nested spans,
+        # record()s made while the span was open, compiles)
+        self.self_us = 0
         self.max_us = 0
         self.last_us = 0
+        # backend compiles charged to this span name (innermost rule)
+        self.compiles = 0
+        self.compile_us = 0
         # per-stage log-histogram (ISSUE 12): updated together with the
         # scalar aggregates — callers hold the tracer lock, so the
         # read-modify-write on the bin counter cannot lose updates under
         # concurrent feeder-pump + query threads
         self.hist = np.zeros(bins, np.int64)
 
-    def add(self, dur_us: int, bin_idx: int) -> None:
+    def add(self, dur_us: int, self_us: int, bin_idx: int) -> None:
         self.count += 1
         self.total_us += dur_us
+        self.self_us += self_us
         self.last_us = dur_us
         if dur_us > self.max_us:
             self.max_us = dur_us
         self.hist[bin_idx] += 1
 
 
+# -- the process-wide per-thread span stack, and what hangs off it ------
+
+_tls = threading.local()
+_ids = itertools.count(1)  # span ids: one sequence for every tracer
+_hooks_lock = threading.Lock()
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
+_unspanned = {"compiles": 0, "compile_us": 0}
+
+
+def _stack() -> list:
+    try:
+        return _tls.stack
+    except AttributeError:
+        _tls.stack = []
+        return _tls.stack
+
+
+def _new_ids(stack: list) -> tuple[str, str, str]:
+    """(span_id, parent_span_id, trace_id) for a span opening above
+    `stack`: a child joins its parent's trace, a root opens one."""
+    n = next(_ids)
+    if stack:
+        parent = stack[-1]
+        return f"{n:016x}", parent.span_id, parent.trace_id
+    return f"{n:016x}", "", f"{n:032x}"
+
+
+def _jax_hooks():
+    """TraceAnnotation, or None while nothing has imported jax. The
+    first call that finds jax also registers the compile listener:
+    once for the process, whatever the number of tracers."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:  # not imported, or still importing
+            return None
+        with _hooks_lock:
+            if _annotation is None:
+                import jax.monitoring
+
+                jax.monitoring.register_event_duration_secs_listener(_on_duration)
+                _annotation = profiler.TraceAnnotation
+    return _annotation
+
+
+def _on_duration(event: str, secs: float, **_kw) -> None:
+    """JAX's duration events arrive on the thread that did the work: a
+    backend compile is charged to the innermost span open there."""
+    if event != COMPILE_EVENT:
+        return
+    us = int(secs * 1e6)
+    stack = _stack()
+    if stack:
+        top = stack[-1]
+        top.child_us += us
+        top.tracer._charge_compile(top, us, time.time() - secs)
+    else:
+        with _hooks_lock:
+            _unspanned["compiles"] += 1
+            _unspanned["compile_us"] += us
+
+
+def unspanned_compiles() -> dict[str, int]:
+    """Backend compiles (count, µs) that ran with no span open on their
+    thread, since the process started."""
+    with _hooks_lock:
+        return dict(_unspanned)
+
+
+class _Span:
+    """One open span: the context manager `SpanTracer.span` returns and
+    the entry on its thread's stack."""
+
+    __slots__ = ("tracer", "name", "window", "span_id", "trace_id",
+                 "parent_id", "child_us", "wall", "t0", "ann", "discarded")
+
+    def __init__(self, tracer: "SpanTracer", name: str, window: str):
+        self.tracer = tracer
+        self.name = name
+        self.window = window
+        self.child_us = 0
+        self.discarded = False
+
+    def discard(self) -> None:
+        """Leave no record and no aggregate of this span when it closes
+        (an idle pump). Call it only where nothing was recorded under
+        the span: a child would keep a parent id that names nothing."""
+        self.discarded = True
+
+    def __enter__(self) -> "_Span":
+        stack = _stack()
+        self.span_id, self.parent_id, self.trace_id = _new_ids(stack)
+        stack.append(self)
+        ann = _jax_hooks()
+        if ann is None:
+            self.ann = None
+        else:
+            self.ann = ann(self.name)
+            self.ann.__enter__()
+        self.wall = time.time()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dur = int((time.perf_counter() - self.t0) * 1e6)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        stack = _stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:  # closed out of order (a generator's span)
+            stack.remove(self)
+        if self.discarded:
+            return
+        if stack:
+            stack[-1].child_us += dur
+        self.tracer._commit(
+            SpanRecord(self.name, self.wall, dur, trace_id=self.trace_id,
+                       span_id=self.span_id, parent_span_id=self.parent_id,
+                       window=self.window),
+            max(dur - self.child_us, 0),
+        )
+
+
 class SpanTracer:
     """Monotonic-clock stage spans: aggregates + per-stage log-histograms
-    always, ring for export."""
+    always, ring for export. Spans nest across tracers (module
+    docstring)."""
 
-    def __init__(self, service: str = "deepflow_tpu.pipeline", ring_size: int = 2048,
+    # 4,096 records: the served path at capacity records ~25 spans a
+    # second on the feeder's tracer and ~10 on the pipeline's (PERF.md
+    # §6, PR 26), and whoever lays the ring over a device profile reads it
+    # a minute or more after the profiled slice closed
+    def __init__(self, service: str = "deepflow_tpu.pipeline", ring_size: int = 4096,
                  hist_spec: SpanHistSpec = SpanHistSpec()):
         self.service = service
         self.hist_spec = hist_spec
@@ -188,40 +419,75 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._seq = 0
 
-    @contextmanager
-    def span(self, name: str):
-        wall = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, int((time.perf_counter() - t0) * 1e6), start_s=wall)
+    def span(self, name: str, *, window: str = "") -> _Span:
+        """`with tracer.span(name) [as s]:` — times the block, nested
+        under whatever span is open on this thread; `s.discard()` drops
+        it. `window` is the per-window correlation key, as in record()."""
+        return _Span(self, name, window)
 
     def record(self, name: str, duration_us: int, start_s: float | None = None,
                *, trace_id: str = "", span_id: str = "",
                parent_span_id: str = "", window: str = ""):
         """Record a pre-measured span — for stages whose work is split
         across non-contiguous host sections (e.g. the sharded advance:
-        sketch close before the append, fold after) that must count as
-        ONE logical span so cross-path stage attribution compares.
-        Optional trace/parent ids + the per-window correlation key ride
-        into the export ring (ISSUE 13: lineage-context stage spans)."""
-        rec = SpanRecord(name, time.time() if start_s is None else start_s,
-                         int(duration_us), trace_id=trace_id, span_id=span_id,
-                         parent_span_id=parent_span_id, window=window)
+        sketch close before the append, fold after; the feeder's
+        per-frame decode) that must count as ONE logical span so
+        cross-path stage attribution compares. With no ids given it
+        nests like span(): child of the span open on this thread, whose
+        child time it joins. Explicit trace/parent ids + the per-window
+        correlation key ride into the export ring untouched (ISSUE 13:
+        lineage-context stage spans)."""
+        duration_us = int(duration_us)
+        if not (trace_id or span_id or parent_span_id):
+            stack = _stack()
+            span_id, parent_span_id, trace_id = _new_ids(stack)
+            if stack:
+                stack[-1].child_us += duration_us
+        self._commit(
+            SpanRecord(name, time.time() if start_s is None else start_s,
+                       duration_us, trace_id=trace_id, span_id=span_id,
+                       parent_span_id=parent_span_id, window=window),
+            duration_us,
+        )
+
+    def _agg_of(self, name: str) -> _Agg:
+        agg = self._agg.get(name)
+        if agg is None:
+            agg = self._agg[name] = _Agg(self.hist_spec.bins)
+        return agg
+
+    def _commit(self, rec: SpanRecord, self_us: int) -> None:
         # the bin is computed outside the lock (pure math), but EVERY
         # aggregate mutation — scalar lanes and the histogram counter —
-        # happens under the tracer lock: record() runs concurrently from
+        # happens under the tracer lock: spans close concurrently on
         # feeder-pump and query threads, and an unlocked += on the
         # histogram would silently lose samples (ISSUE 12 satellite,
         # pinned by tests/test_profiling.py::test_span_tracer_threaded).
         bin_idx = self.hist_spec.bin(rec.duration_us)
         with self._lock:
             self._ring.append(rec)
-            agg = self._agg.get(name)
-            if agg is None:
-                agg = self._agg[name] = _Agg(self.hist_spec.bins)
-            agg.add(rec.duration_us, bin_idx)
+            self._agg_of(rec.name).add(rec.duration_us, self_us, bin_idx)
+
+    def _charge_compile(self, span: _Span, us: int, start_s: float) -> None:
+        """One backend compile under `span` (still open): the ring gets
+        an xla.compile record, so whoever lays the ring over a device
+        trace finds the gap under it (innermost = shortest open span),
+        and the span name's aggregate its compile lanes."""
+        rec = SpanRecord(SPAN_XLA_COMPILE, start_s, us, trace_id=span.trace_id,
+                         span_id=f"{next(_ids):016x}",
+                         parent_span_id=span.span_id, window=span.window)
+        with self._lock:
+            self._ring.append(rec)
+            agg = self._agg_of(span.name)
+            agg.compiles += 1
+            agg.compile_us += us
+
+    def compile_lanes(self, names: tuple[str, ...] | None = None) -> tuple[int, int]:
+        """(compiles, compile_us) charged to spans of this tracer: all
+        of them, or those named."""
+        with self._lock:
+            aggs = [a for n, a in self._agg.items() if names is None or n in names]
+            return sum(a.compiles for a in aggs), sum(a.compile_us for a in aggs)
 
     # -- read faces -----------------------------------------------------
     def summary(self) -> dict[str, dict]:
@@ -235,9 +501,12 @@ class SpanTracer:
                 out[name] = {
                     "count": a.count,
                     "total_us": a.total_us,
+                    "self_us": a.self_us,
                     "avg_us": round(a.total_us / a.count, 1) if a.count else 0.0,
                     "max_us": a.max_us,
                     "last_us": a.last_us,
+                    "compiles": a.compiles,
+                    "compile_us": a.compile_us,
                     **{
                         f"p{int(q * 100)}_us": round(float(v), 1)
                         for q, v in zip(SPAN_QUANTILES, qv)
@@ -296,21 +565,26 @@ class SpanTracer:
         return np.asarray(m[0]), np.asarray(w[0])
 
     def get_counters(self) -> dict[str, int | float]:
-        """Countable face: flat `<stage>.count/.total_us/.max_us` fields
-        plus the log-histogram p50/p95/p99 lanes (ISSUE 12) — dogfooded
+        """Countable face: flat `<stage>.count/.total_us/.self_us/.max_us`
+        fields, the compile lanes `<stage>.compiles/.compile_us`, plus
+        the log-histogram p50/p95/p99 lanes (ISSUE 12) — dogfooded
         via integration/dfstats into deepflow_system, where
         `ingest.dispatch.p99_us` becomes the
         `tpu_pipeline_spans_ingest_dispatch_p99_us` metric a span-latency
         alert rule keys on. Pure numpy, fetch-free, safe from a ticking
         collector thread."""
         with self._lock:
-            aggs = [(name, a.count, a.total_us, a.max_us, a.hist.copy())
+            aggs = [(name, a.count, a.total_us, a.self_us, a.max_us,
+                     a.compiles, a.compile_us, a.hist.copy())
                     for name, a in sorted(self._agg.items())]
         out: dict[str, int | float] = {}
-        for name, count, total_us, max_us, hist in aggs:
+        for name, count, total_us, self_us, max_us, compiles, compile_us, hist in aggs:
             out[f"{name}.count"] = count
             out[f"{name}.total_us"] = total_us
+            out[f"{name}.self_us"] = self_us
             out[f"{name}.max_us"] = max_us
+            out[f"{name}.compiles"] = compiles
+            out[f"{name}.compile_us"] = compile_us
             qv = loghist_quantiles_np(hist, self.hist_spec, SPAN_QUANTILES)
             for q, v in zip(SPAN_QUANTILES, qv):
                 out[f"{name}.p{int(q * 100)}_us"] = round(float(v), 1)

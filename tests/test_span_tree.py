@@ -1,0 +1,492 @@
+"""ISSUE 26: the span tree (nesting and self time on one per-thread stack
+shared by every tracer), the served path's leaf spans, the idle pump, the
+compile lanes, the stage names on the device programs, and the per-layer
+metric files that read the new spans and counters."""
+
+import glob
+import json
+import os
+import socket
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepflow_tpu.utils import spans
+from deepflow_tpu.utils.spans import (
+    FEEDER_SPAN_NAMES,
+    PIPELINE_SPAN_NAMES,
+    SPAN_FEEDER_ASSEMBLE,
+    SPAN_FEEDER_COALESCE,
+    SPAN_FEEDER_DECODE,
+    SPAN_FEEDER_DISPATCH,
+    SPAN_FEEDER_DRAIN,
+    SPAN_FEEDER_PUMP,
+    SPAN_FLUSH_DRAIN,
+    SPAN_FLUSH_ROWS,
+    SPAN_FLUSH_SPLIT,
+    SPAN_FLUSH_WAIT,
+    SPAN_INGEST_DISPATCH,
+    SPAN_INGEST_STAGE,
+    SPAN_XLA_COMPILE,
+    SpanHistSpec,
+    SpanTracer,
+)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHIPBENCH = os.path.join(ROOT, "chipbench")
+NEW_LAYERS = (
+    "feeder.pump_ms_per_mrec", "feeder.decode_ms_per_mrec",
+    "feeder.assemble_ms_per_mrec", "step.stage_ms_per_mrec",
+    "flush.wait_ms_per_window", "flush.rows_ms_per_window",
+    "flush.split_ms_per_window", "flush.compile_ms_per_window",
+)
+
+
+# ---------------------------------------------------------------------------
+# (1) nesting
+
+
+def test_spans_nest_across_two_tracers_with_self_time():
+    feeder, pipe = SpanTracer(service="f"), SpanTracer(service="p")
+    with feeder.span("outer"):
+        time.sleep(0.002)
+        with pipe.span("inner", window="7@1s"):
+            time.sleep(0.004)
+            pipe.record("leaf", 1500)  # pre-measured work joins the tree too
+        feeder.record("sibling", 300)
+    outer, = feeder.recent("outer")
+    inner, = pipe.recent("inner")
+    leaf, = pipe.recent("leaf")
+    sibling, = feeder.recent("sibling")
+    assert outer.parent_span_id == "" and len(outer.span_id) == 16
+    assert inner.parent_span_id == outer.span_id and inner.window == "7@1s"
+    assert leaf.parent_span_id == inner.span_id
+    assert sibling.parent_span_id == outer.span_id
+    assert {r.trace_id for r in (outer, inner, leaf, sibling)} == {outer.trace_id}
+    assert len({r.span_id for r in (outer, inner, leaf, sibling)}) == 4
+    f, p = feeder.summary(), pipe.summary()
+    assert f["outer"]["self_us"] == (
+        f["outer"]["total_us"] - p["inner"]["total_us"] - 300)
+    assert p["inner"]["self_us"] == p["inner"]["total_us"] - 1500
+    assert p["leaf"]["self_us"] == p["leaf"]["total_us"] == 1500
+    assert f["outer"]["self_us"] >= 1500  # its own 2 ms sleep
+    assert feeder.get_counters()["outer.self_us"] == f["outer"]["self_us"]
+
+
+def test_span_stacks_of_two_threads_are_independent():
+    tr = SpanTracer()
+    inside, release = threading.Event(), threading.Event()
+
+    def other():
+        with tr.span("other.root"):
+            inside.set()
+            assert release.wait(10)
+
+    t = threading.Thread(target=other)
+    with tr.span("main.root"):
+        t.start()
+        assert inside.wait(10)
+        with tr.span("main.child"):  # opened while other.root is open over there
+            pass
+        release.set()
+        t.join(10)
+    assert not t.is_alive()
+    main_root, = tr.recent("main.root")
+    main_child, = tr.recent("main.child")
+    other_root, = tr.recent("other.root")
+    assert main_child.parent_span_id == main_root.span_id
+    assert other_root.parent_span_id == "" and main_root.parent_span_id == ""
+    assert other_root.trace_id != main_root.trace_id
+    s = tr.summary()
+    # the other thread's span took no self time from this thread's root
+    assert s["main.root"]["self_us"] == (
+        s["main.root"]["total_us"] - s["main.child"]["total_us"])
+    assert s["other.root"]["self_us"] == s["other.root"]["total_us"]
+
+
+def test_discarded_span_leaves_nothing_and_explicit_ids_pass_through():
+    tr = SpanTracer()
+    with tr.span("parent"):
+        with tr.span("idle") as s:
+            s.discard()
+        tr.record("hop", 40, trace_id="t" * 32, span_id="s" * 16,
+                  parent_span_id="p" * 16)  # lineage context: not re-parented
+    assert tr.recent("idle") == [] and "idle" not in tr.summary()
+    hop, = tr.recent("hop")
+    assert (hop.trace_id, hop.span_id, hop.parent_span_id) == (
+        "t" * 32, "s" * 16, "p" * 16)
+    parent = tr.summary()["parent"]
+    assert parent["self_us"] == parent["total_us"]
+
+
+def test_hist_bin_matches_the_closed_form():
+    import math
+
+    spec = SpanHistSpec(bins=512, vmin=1.0, gamma=1.02)
+    for v in (0, 0.5, 1, 2, 17, 999, 123456, 10**9, 10**30):
+        want = 0 if v <= 1 else min(
+            int(math.floor(math.log(v / 1.0) / math.log(1.02))), 511)
+        assert spec.bin(v) == want, v
+    assert SpanHistSpec() == SpanHistSpec()  # the cached log is no part of it
+
+
+# ---------------------------------------------------------------------------
+# (2) the served path, as chipbench/tests/tiny.py builds it
+
+
+@pytest.fixture(scope="module")
+def chipbench_modules():
+    """chipbench's own modules (it is no package: its files import each
+    other by bare name)."""
+    added = [p for p in (CHIPBENCH, os.path.join(CHIPBENCH, "tests"))
+             if p not in sys.path]
+    sys.path[:0] = added
+    import gen
+    import layers
+    import sut
+    import tiny
+    import wire
+
+    yield {"gen": gen, "layers": layers, "sut": sut, "tiny": tiny, "wire": wire}
+    for p in added:
+        sys.path.remove(p)
+
+
+def _pump_until_taken(feeder, want: int, base_in: int, flushed: list) -> None:
+    deadline = time.monotonic() + 120
+    while feeder.get_counters()["records_in"] - base_in < want:
+        assert time.monotonic() < deadline, "the feeder took too few records"
+        out = feeder.pump()
+        flushed.extend(out)
+        if not out:
+            time.sleep(0.001)
+
+
+@pytest.fixture(scope="module")
+def tiny_run(chipbench_modules):
+    """Receiver -> queues -> FeederRuntime -> PipelineFeedSink ->
+    L4Pipeline at tiny.py's sizes: five event-seconds over TCP, pumped
+    until taken, then planes in the harness's shape (run.py)."""
+    m = chipbench_modules
+    schema = m["gen"].load_schema()
+    served = m["sut"].Served(m["tiny"].CONFIG)
+    try:
+        source = m["gen"].FlowSource(schema, m["tiny"].CONFIG["population"], 5)
+        c0, s0 = served.counters(), served.spans()
+        ring0 = {id(r) for tr in (served.feeder.tracer, served.pipe.tracer)
+                 for r in tr.recent()}
+        flushed, sent = [], 0
+        with socket.create_connection(("127.0.0.1", served.port), timeout=30) as sock:
+            for k in range(1, 6):
+                tags, meters = source.second(k, 6000)
+                for frame in m["wire"].encode_frames(tags, meters, schema["wire"]):
+                    sock.sendall(frame)
+                sent += 6000
+                # a second at a time, so that rounds and closes interleave
+                _pump_until_taken(served.feeder, sent, c0["feeder.records_in"], flushed)
+        flushed += served.feeder.flush()
+        served.block()
+        c1, s1 = served.counters(), served.spans()
+        records = [r for tr in (served.feeder.tracer, served.pipe.tracer)
+                   for r in tr.recent() if id(r) not in ring0]
+        spans_plane = {n: {f: s1[n][f] - s0.get(n, {}).get(f, 0) for f in s1[n]}
+                       for n in s1}
+        counters = {k: c1[k] - c0.get(k, 0) for k in c1}
+        yield {
+            "records": records, "sent": sent,
+            "feeder": served.feeder.tracer.summary(),
+            "pipe": served.pipe.tracer.summary(),
+            "planes": {"spans": spans_plane, "counters": counters,
+                       "run": {"windows_closed": len(
+                           {int(db.timestamp[0]) for db in flushed})}},
+        }
+    finally:
+        served.close()
+
+
+def test_served_path_emits_every_leaf_span_with_its_count(tiny_run):
+    f, p, c = tiny_run["feeder"], tiny_run["pipe"], tiny_run["planes"]["counters"]
+    assert c["feeder.records_in"] == tiny_run["sent"]
+    assert tiny_run["planes"]["run"]["windows_closed"] >= 2
+    # a name lives on one tracer: feeder.* on the feeder's, the rest on the pipeline's
+    assert set(FEEDER_SPAN_NAMES) <= set(f) and not set(FEEDER_SPAN_NAMES) & set(p)
+    for name in (SPAN_INGEST_STAGE, SPAN_INGEST_DISPATCH, SPAN_FLUSH_DRAIN,
+                 SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT):
+        assert name in p and name not in f and name in PIPELINE_SPAN_NAMES
+    # one feeder.decode a round that decoded a frame = one a coalesce here
+    # (nothing is shed); one ingest.stage and one feeder.assemble a batch;
+    # the three flush phases once a drained entry
+    assert f[SPAN_FEEDER_DECODE]["count"] == f[SPAN_FEEDER_COALESCE]["count"] > 0
+    assert f[SPAN_FEEDER_DRAIN]["count"] >= f[SPAN_FEEDER_COALESCE]["count"]
+    assert f[SPAN_FEEDER_ASSEMBLE]["count"] == c["feeder.batches_out"] > 0
+    assert p[SPAN_INGEST_STAGE]["count"] == c["feeder.batches_out"]
+    # the double buffer holds the last staged batch back until flush()
+    assert p[SPAN_INGEST_DISPATCH]["count"] == c["feeder.batches_out"]
+    assert (p[SPAN_FLUSH_WAIT]["count"] == p[SPAN_FLUSH_ROWS]["count"]
+            == p[SPAN_FLUSH_SPLIT]["count"] == c["pipeline.window_advances"] > 0)
+    assert p[SPAN_FLUSH_DRAIN]["count"] <= p[SPAN_FLUSH_WAIT]["count"]
+
+
+def test_children_fit_inside_their_parents(tiny_run):
+    f, p = tiny_run["feeder"], tiny_run["pipe"]
+    total = lambda s, *names: sum(s[n]["total_us"] for n in names)
+    assert total(p, SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT) \
+        <= p[SPAN_FLUSH_DRAIN]["total_us"]
+    assert f[SPAN_FEEDER_ASSEMBLE]["total_us"] + p[SPAN_INGEST_STAGE]["total_us"] \
+        <= f[SPAN_FEEDER_DISPATCH]["total_us"]
+    assert f[SPAN_FEEDER_DECODE]["total_us"] <= f[SPAN_FEEDER_COALESCE]["total_us"]
+    assert total(f, SPAN_FEEDER_DRAIN, SPAN_FEEDER_COALESCE) \
+        <= f[SPAN_FEEDER_PUMP]["total_us"]
+    for s in (f, p):
+        for name, agg in s.items():
+            assert 0 <= agg["self_us"] <= agg["total_us"], name
+
+
+def test_ring_records_name_their_parents(tiny_run):
+    by_id = {r.span_id: r for r in tiny_run["records"]}
+    assert len(by_id) == len(tiny_run["records"])  # ids are unique across tracers
+    parents = {
+        SPAN_FEEDER_PUMP: {""},
+        SPAN_FEEDER_DRAIN: {SPAN_FEEDER_PUMP},
+        SPAN_FEEDER_COALESCE: {SPAN_FEEDER_PUMP},
+        SPAN_FEEDER_DECODE: {SPAN_FEEDER_COALESCE},
+        # a full bucket dispatches inside the round; the tail after it
+        SPAN_FEEDER_DISPATCH: {SPAN_FEEDER_COALESCE, SPAN_FEEDER_PUMP},
+        SPAN_FEEDER_ASSEMBLE: {SPAN_FEEDER_DISPATCH},
+        SPAN_INGEST_STAGE: {SPAN_FEEDER_DISPATCH},
+        SPAN_INGEST_DISPATCH: {SPAN_FEEDER_DISPATCH},
+        SPAN_FLUSH_DRAIN: {SPAN_FEEDER_DISPATCH},
+        SPAN_FLUSH_WAIT: {SPAN_FLUSH_DRAIN},
+        SPAN_FLUSH_ROWS: {SPAN_FLUSH_DRAIN},
+        SPAN_FLUSH_SPLIT: {SPAN_FLUSH_DRAIN},
+    }
+    seen = set()
+    for r in tiny_run["records"]:
+        if r.name not in parents:
+            continue
+        # feeder.flush() at the end dispatches outside any pump
+        if r.name == SPAN_FEEDER_DISPATCH and r.parent_span_id == "":
+            continue
+        parent = by_id.get(r.parent_span_id)
+        assert (parent.name if parent else "") in parents[r.name], r
+        seen.add(r.name)
+    assert seen == set(parents)
+    roots = {by_id[r.trace_id[-16:]].name for r in tiny_run["records"]
+             if r.trace_id[-16:] in by_id}
+    assert SPAN_FEEDER_PUMP in roots
+
+
+@pytest.mark.parametrize("name", NEW_LAYERS)
+def test_new_layer_file_reads_a_number_from_a_tiny_run(name, tiny_run, chipbench_modules):
+    layers = chipbench_modules["layers"]
+    spec = layers.load_layer(name)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert {k: spec[k] for k in entry} == entry  # the file and its entry agree
+    value = layers.read_metric(spec, tiny_run["planes"])
+    assert isinstance(value, float) and value >= 0.0
+    if name != "flush.compile_ms_per_window":  # 0.0 where no close compiled
+        assert value > 0.0
+    # a program without the span or counter (the parent commit) reads nothing
+    assert layers.read_metric(spec, {"spans": {}, "counters": {
+        "feeder.records_in": 1}, "run": {"windows_closed": 1}}) is None
+
+
+def test_layer_files_and_benchmark_entries_pair_up():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        names = [m["name"] for m in json.load(f)["per_layer"]]
+    files = {os.path.basename(p)[:-5]
+             for p in glob.glob(os.path.join(CHIPBENCH, "layers", "*.json"))}
+    assert set(names) == files and names[-len(NEW_LAYERS):] == list(NEW_LAYERS)
+
+
+# ---------------------------------------------------------------------------
+# (3) the idle pump
+
+
+def test_idle_pump_records_no_span_and_counts_itself():
+    from deepflow_tpu.feeder import FeederConfig, FeederRuntime
+    from deepflow_tpu.ingest.queues import new_queue
+
+    class Sink:
+        bucket_sizes = (8,)
+
+    feeder = FeederRuntime([new_queue(16), new_queue(16)], Sink(), FeederConfig(),
+                           name="idle")
+    for _ in range(3):
+        assert feeder.pump() == []
+    assert feeder.tracer.recent() == [] and feeder.tracer.summary() == {}
+    assert feeder.get_counters()["idle_pumps"] == 3
+
+
+# ---------------------------------------------------------------------------
+# (4) compiles, seen by the program
+
+
+def test_compile_inside_a_span_is_charged_to_it_once():
+    tr = SpanTracer()
+    fn = jax.jit(lambda x: x * 3 + 1)
+    x = jnp.arange(7)  # made out here: building it compiles too
+    with tr.span("outer"):
+        with tr.span("compiles.here"):
+            fn(x).block_until_ready()
+        with tr.span("compiles.here"):
+            fn(x).block_until_ready()  # cached: no event
+    first, second = tr.recent("compiles.here")
+    compile_rec, = tr.recent(SPAN_XLA_COMPILE)
+    assert compile_rec.parent_span_id == first.span_id != second.span_id
+    assert compile_rec.trace_id == first.trace_id
+    assert first.start_s <= compile_rec.start_s
+    assert compile_rec.duration_us <= first.duration_us
+    s = tr.summary()
+    assert SPAN_XLA_COMPILE not in s  # a ring record, no aggregate
+    here = s["compiles.here"]
+    assert (here["compiles"], here["compile_us"]) == (1, compile_rec.duration_us)
+    assert here["self_us"] <= here["total_us"] - compile_rec.duration_us
+    assert s["outer"]["compiles"] == 0  # the innermost span takes it
+    assert tr.compile_lanes() == (1, compile_rec.duration_us)
+    assert tr.compile_lanes(("outer",)) == (0, 0)
+    assert tr.get_counters()["compiles.here.compiles"] == 1
+
+
+def test_compile_outside_any_span_goes_to_the_unspanned_lanes():
+    tr = SpanTracer()
+    with tr.span("registers.the.listener"):
+        pass
+    x = jnp.arange(11)
+    before = spans.unspanned_compiles()
+    jax.jit(lambda x: x - 5)(x).block_until_ready()
+    after = spans.unspanned_compiles()
+    assert after["compiles"] == before["compiles"] + 1
+    assert after["compile_us"] > before["compile_us"]
+    assert tr.recent(SPAN_XLA_COMPILE) == [] and tr.compile_lanes() == (0, 0)
+
+
+def test_window_manager_counts_compiles_under_its_spans():
+    from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
+    from deepflow_tpu.aggregator.window import WindowConfig
+    from deepflow_tpu.datamodel.batch import FlowBatch
+    from deepflow_tpu.ingest.replay import SyntheticFlowGen
+
+    pipe = L4Pipeline(PipelineConfig(window=WindowConfig(capacity=1 << 10),
+                                     batch_size=64))
+    try:
+        c = pipe.get_counters()
+        assert (c["xla_compiles"], c["xla_compile_us"],
+                c["flush_compiles"], c["flush_compile_us"]) == (0, 0, 0, 0)
+        gen = SyntheticFlowGen(num_tuples=23, seed=3)  # a document count of its own
+        for t in (5000, 5001, 5004, 5008):
+            pipe.ingest(FlowBatch.from_records(gen.records(57, t)))
+        c = pipe.get_counters()
+        assert c["xla_compiles"] >= c["flush_compiles"] >= 1
+        assert c["xla_compile_us"] >= c["flush_compile_us"] > 0
+        rows = pipe.tracer.summary()[SPAN_FLUSH_ROWS]
+        assert rows["compiles"] >= 1  # the per-count slice / reshape
+        assert c["jit_compiles"] == 1  # the fused step's own monitor stays
+    finally:
+        pipe.close()
+
+
+# ---------------------------------------------------------------------------
+# (5) stage names on the device programs
+
+
+def _lowered_texts() -> dict:
+    from deepflow_tpu.aggregator import stash
+    from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
+    from deepflow_tpu.aggregator.sketchplane import SketchConfig
+    from deepflow_tpu.aggregator.window import WindowConfig
+    from deepflow_tpu.datamodel.batch import FlowBatch
+    from deepflow_tpu.datamodel.schema import FLOW_METER, TAG_SCHEMA
+    from deepflow_tpu.ingest.replay import SyntheticFlowGen
+
+    text = lambda lowered: lowered.as_text(debug_info=True)
+    st = stash.stash_init(256, TAG_SCHEMA, FLOW_METER)
+    acc = stash.accum_init(128, TAG_SCHEMA, FLOW_METER)
+    cols = lambda mask: tuple(int(i) for i in np.nonzero(mask)[0])
+    sums, maxs = cols(FLOW_METER.sum_mask), cols(FLOW_METER.max_mask)
+    out = {
+        "fold": text(stash.collector_fold_counted.lower(st, acc, sums, maxs)),
+        "merge_fold": text(stash.collector_merge_fold.lower(
+            st, acc, jnp.uint32(9), sums, maxs)),
+        "flush_range": text(stash.stash_flush_range.lower(
+            st, np.uint32(0), np.uint32(9), compact=True)),
+    }
+    # the fused step with the pre-reduce and the sketch plane on: the
+    # arguments of its first dispatch, recorded by the step census
+    pipe = L4Pipeline(PipelineConfig(
+        window=WindowConfig(capacity=1 << 10, sketch=SketchConfig()),
+        batch_size=64, batch_unique_cap=64))
+    try:
+        gen = SyntheticFlowGen(num_tuples=9, seed=1)
+        captured = {}
+        observe = pipe._census.observe
+
+        def capture(service, kind, rows, fn, args):
+            captured["text"] = text(fn.lower(*args))
+            return observe(service, kind, rows, fn, args)
+
+        pipe._census.observe = capture
+        try:
+            pipe.ingest(FlowBatch.from_records(gen.records(40, 7000)))
+        finally:
+            pipe._census.observe = observe
+        out["step"] = captured["text"]
+    finally:
+        pipe.close()
+    return out
+
+
+@pytest.fixture(scope="module")
+def lowered_texts():
+    return _lowered_texts()
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("step", "step.prereduce"), ("step", "step.sketch"), ("step", "step.fanout"),
+    ("step", "step.fingerprint"), ("step", "step.counter_block"),
+    ("step", "step.append"),
+    # the pre-reduce is the fold's group-by under the step's own scope
+    ("step", "step.prereduce/fold.sort"),
+    ("fold", "fold.concat"), ("fold", "fold.sort"), ("fold", "fold.segments"),
+    ("fold", "fold.reduce"), ("fold", "fold.compact"),
+    ("merge_fold", "fold.sort"), ("merge_fold", "fold.merge_ranks"),
+    ("merge_fold", "fold.merge_order"), ("merge_fold", "fold.concat"),
+    ("merge_fold", "fold.reduce"),
+    ("flush_range", "flush.order"), ("flush_range", "flush.pack"),
+    ("flush_range", "flush.compact"),
+])
+def test_lowered_program_names_its_stages(program, scope, lowered_texts):
+    assert scope in lowered_texts[program]
+
+
+def test_jitted_programs_keep_the_names_the_trace_groups_match(lowered_texts):
+    with open(os.path.join(CHIPBENCH, "trace_groups.json")) as f:
+        groups = json.load(f)["modules"]
+    for program, group in (("step", "fused_step"), ("fold", "fold"),
+                           ("flush_range", "flush_range")):
+        module = lowered_texts[program].split("module @", 1)[1].split()[0]
+        assert any(module.startswith(p) for p in groups[group]), (module, groups[group])
+
+
+def test_pallas_kernels_carry_names():
+    from deepflow_tpu.ops.segreduce_pallas import sorted_segment_sum_max
+
+    n, cap = 256, 16
+    seg = jnp.sort(jnp.arange(n, dtype=jnp.int32) % cap)
+    rows = jnp.ones((n, 8), jnp.float32)
+    first = jnp.searchsorted(seg, jnp.arange(cap, dtype=jnp.int32))
+    jaxpr = str(jax.make_jaxpr(
+        lambda r, s, f: sorted_segment_sum_max(r, s, cap, f))(rows, seg, first))
+    assert "segreduce_suffix_scan" in jaxpr
+    import inspect
+
+    from deepflow_tpu.ops import sketch_pallas
+
+    assert 'name="sketch_fused_update"' in inspect.getsource(sketch_pallas)
