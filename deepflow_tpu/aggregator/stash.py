@@ -543,8 +543,10 @@ def _flush_range_impl(state: StashState, lo_window, hi_window, *, compact: bool 
     Rows are ordered by (window, stash position) — exactly the order the
     sequential ascending per-window `stash_flush` loop emits, so the two
     paths are bit-identical (pinned by tests/test_flush_range.py). The
-    host fetches the row count, then only `packed[:total]` — two
-    transfers per window advance, independent of how many windows closed.
+    host fetches the row count, then the fixed-size pages of `packed`
+    that cover [0, total) and cuts them to `total` itself — two transfers
+    per window advance, independent of how many windows closed, and no
+    program whose shape depends on `total` (window.py `_PagedRows`).
 
     `compact` (static) re-establishes the CANONICAL layout the
     merge-fold requires (live rows = sorted positional prefix): on a

@@ -22,10 +22,11 @@ AT MOST one tiny fetch per batch — the versioned on-device COUNTER BLOCK
 the jitted append step computes (late/valid/shed plus stash occupancy &
 evictions, packed-key excess-word hits, ring fill and feeder shed; see
 COUNTER_BLOCK_VERSION / CB_* below) — plus two fetches per *window
-advance* (row count + the packed flush matrix), independent of batch
-size and of how many windows closed. With `WindowConfig.stats_ring = K`
-the blocks accumulate in a device-resident [K, CB_LEN] ring fetched
-once per K dispatches, dropping steady-state syncs to 1/K per batch
+advance* (row count + the packed flush matrix's pages, one list),
+independent of batch size and of how many windows closed. With
+`WindowConfig.stats_ring = K` the blocks accumulate in a
+device-resident [K, CB_LEN] ring fetched once per K dispatches,
+dropping steady-state syncs to 1/K per batch
 (ISSUE 4; late gating moves to device state so flushed rows stay
 bit-exact vs per-batch fetching). All transfers route through
 `host_fetch` so the CI gate (tests/test_perf_gate.py) can count them and
@@ -98,13 +99,69 @@ from .stash import (
 _U32_MAX = np.uint32(0xFFFFFFFF)
 
 
-def host_fetch(x) -> np.ndarray:
+def host_fetch(x):
     """THE device→host fetch boundary for the windowed path.
 
     Every transfer WindowManager performs goes through here so the
     perf gate can shim it and assert the per-batch budget; keep new
-    fetches behind this seam."""
+    fetches behind this seam. A list of device arrays is ONE fetch
+    (`jax.device_get`: every copy is started, then all are waited for)
+    and comes back as a list of host arrays."""
+    if isinstance(x, list):
+        return jax.device_get(x)
     return np.asarray(x)
+
+
+# Rows of one page of a drain's row fetch. A close reads the device
+# matrices in pages of this many rows at a traced offset and cuts to the
+# live count on the host, so no program on the close path has a shape
+# that depends on a document count: one `_take_page` program per matrix
+# shape for the life of the process, an over-fetch of under one page per
+# part. A constant: chosen on the chip (PERF.md §6, PR 27).
+PAGE_ROWS = 16384
+
+
+@partial(jax.jit, static_argnames=("rows", "axis"))
+def _take_page(x, start, *, rows: int, axis: int = 0):
+    """`rows` rows of `x` along `axis` from the traced offset `start`
+    (clamped by XLA so the page stays inside `x`)."""
+    return jax.lax.dynamic_slice_in_dim(x, start, rows, axis=axis)
+
+
+class _PagedRows:
+    """The first `n` rows of device array `x` along `axis`, as pages of
+    min(PAGE_ROWS, rows of x): `pages` are the device handles to fetch
+    (none when n == 0; `x` itself when it is under one page) and `join`
+    cuts the fetched pages back to exactly those `n` rows."""
+
+    def __init__(self, x, n: int, axis: int = 0):
+        size = x.shape[axis]
+        self.n, self.axis = int(n), axis
+        self.page = min(PAGE_ROWS, size)
+        # dynamic_slice moves a start past this back to it
+        self.last_start = size - self.page
+        self._no_rows = (x.shape[:axis] + (0,) + x.shape[axis + 1:], x.dtype)
+        if self.page == size:
+            self.pages = [x] if self.n else []
+        else:
+            self.pages = [
+                _take_page(x, np.int32(s), rows=self.page, axis=axis)
+                for s in range(0, self.n, self.page)
+            ]
+
+    @property
+    def rows_fetched(self) -> int:
+        return len(self.pages) * self.page
+
+    def join(self, fetched: list) -> np.ndarray:
+        cut = []
+        lead = (slice(None),) * self.axis
+        for s, page in zip(range(0, self.n, self.page), fetched):
+            at = min(s, self.last_start)  # where the page really starts
+            cut.append(page[lead + (slice(s - at, min(self.n, s + self.page) - at),)])
+        if not cut:
+            return np.zeros(*self._no_rows)
+        return cut[0] if len(cut) == 1 else np.concatenate(cut, axis=self.axis)
 
 
 # ---------------------------------------------------------------------------
@@ -606,7 +663,7 @@ class _FlushEntry:
     exact flush handles plus (optionally) the sketch plane's pending
     blocks and the cascade's closed tier flushes. `_drain_flush` fetches
     the whole entry in the same two transfers regardless of what rode
-    along."""
+    along: the counts, then every part's fixed-size pages as one list."""
 
     packed: jnp.ndarray  # [S, 3+T+M] u32 device handle
     total: jnp.ndarray  # scalar i32 device handle
@@ -756,6 +813,11 @@ class WindowManager:
         self.host_fetches = 0
         self.bytes_fetched = 0
         self.bytes_uploaded = 0  # callers add their packed upload sizes
+        # how the drains' paged row fetch engages (every part of every
+        # drain): pages fetched, the rows they hold, the rows wanted
+        self.flush_pages = 0
+        self.flush_rows_fetched = 0
+        self.flush_rows_live = 0
         self.feeder_shed = 0  # CB_FEEDER_SHED lane mirror
         # live read plane (ISSUE 10): host-authoritative snapshot
         # counters + the cached [reads, bytes] device vector riding into
@@ -815,8 +877,9 @@ class WindowManager:
             jnp.zeros((2,), jnp.uint32) if config.stats_ring > 1 else None
         )
 
-    def _fetch(self, x) -> np.ndarray:
-        """host_fetch + per-manager transfer accounting (count + bytes).
+    def _fetch(self, x):
+        """host_fetch + per-manager transfer accounting (count + bytes;
+        a list of device arrays is one fetch and returns a list).
         Transient fetch failures (transfer timeouts, injected
         chaos faults) retry with backoff — the device handle stays
         valid across a blown fetch deadline."""
@@ -831,8 +894,17 @@ class WindowManager:
         arr = retry_call(once, self.retry_policy, on_retry=on_retry,
                          rng=self._retry_rng)
         self.host_fetches += 1
-        self.bytes_fetched += arr.nbytes
+        self.bytes_fetched += (
+            sum(a.nbytes for a in arr) if isinstance(arr, list) else arr.nbytes
+        )
         return arr
+
+    def _fetch_parts(self, parts: "list[_PagedRows]") -> list[np.ndarray]:
+        """Every page of every part of a drain in ONE fetch; returns each
+        part cut back to its live rows. No pages, no fetch."""
+        pages = [pg for part in parts for pg in part.pages]
+        got = iter(self._fetch(pages) if pages else ())
+        return [part.join([next(got) for _ in part.pages]) for part in parts]
 
     # -- device→host drains ---------------------------------------------
     def _drain_flush(self, entry: "_FlushEntry") -> list[FlushedWindow]:
@@ -842,17 +914,19 @@ class WindowManager:
         plane and/or the rollup cascade enabled the SAME two transfers
         also carry the closed sketch blocks and the closed TIER windows'
         rows: the scalar fetch widens to [row count, pending block
-        count, tier row counts…] and the row fetch becomes one
-        concatenated u32 transfer (flush rows ‖ packed blocks ‖ block
-        window ids ‖ tier rows per tier), so the ≤3-fetch budget is
-        untouched (tests/test_perf_gate.py)."""
+        count, tier row counts…] and the row fetch is one list of
+        fixed-size pages (`_PagedRows`: flush rows, packed blocks, block
+        window ids, tier rows per tier — each part paged at its own
+        size and cut to its live rows on the host), so the ≤3-fetch
+        budget is untouched (tests/test_perf_gate.py) and nothing
+        dispatched here has a shape that depends on a count."""
         has_sketch = entry.pend is not None
         # pooled sketch memory (ISSUE 20): closed WIDE slots ride the
         # same two transfers. The scalar vector widens by one lane
         # (closed-wide count, so a drain with none skips the wide bytes
         # entirely); when any closed, all Pw rows + window ids join the
-        # concatenated fetch and the host filters on SENTINEL_WIN —
-        # Pw is a handful of rows, the filter is cheaper than a device
+        # row fetch and the host filters on SENTINEL_WIN — Pw is a
+        # handful of rows, the filter is cheaper than a device
         # compaction.
         has_wide = entry.wide_rows is not None and entry.wide_rows.size > 0
         # the first fetch of a drain blocks until the fold and the range
@@ -884,72 +958,53 @@ class WindowManager:
         # range, and a tier window whose exact rows were all shed
         # (sketch-only coverage) still closes there
         with self.tracer.span(SPAN_FLUSH_ROWS):
-            if total == 0 and n_blocks == 0 and n_wide == 0 and not any(tier_totals):
-                flat = np.zeros((0,), np.uint32)  # nothing to transfer
-            else:
-                # each distinct `total` compiles a slice and a reshape
-                # here: the tracer's compile lanes on this span count them
-                parts = [entry.packed[:total].reshape(-1)]
-                if has_sketch:
-                    parts += [entry.pend[:n_blocks].reshape(-1),
-                              entry.pend_win[:n_blocks]]
-                if n_wide:
-                    parts += [entry.wide_rows.reshape(-1), entry.wide_wins]
-                for tf, t in zip(entry.tiers, tier_totals):
-                    parts.append(tf.packed[:t].reshape(-1))
-                if len(parts) == 1:
-                    # nothing rode along — fetch the 2D rows directly (the
-                    # reshape+concatenate would compile a kernel per
-                    # distinct `total`, a real tax at one advance/second)
-                    flat = self._fetch(entry.packed[:total]).reshape(-1)
-                else:
-                    flat = self._fetch(jnp.concatenate(parts))
+            parts = [_PagedRows(entry.packed, total)]
+            if has_sketch:
+                parts += [_PagedRows(entry.pend, n_blocks),
+                          _PagedRows(entry.pend_win, n_blocks)]
+            if n_wide:
+                pw = entry.wide_rows.shape[0]  # every slot: the host filters
+                parts += [_PagedRows(entry.wide_rows, pw),
+                          _PagedRows(entry.wide_wins, pw)]
+            parts += [_PagedRows(tf.packed, t)
+                      for tf, t in zip(entry.tiers, tier_totals)]
+            self.flush_pages += sum(len(p.pages) for p in parts)
+            self.flush_rows_fetched += sum(p.rows_fetched for p in parts)
+            self.flush_rows_live += sum(p.n for p in parts)
+            got = iter(self._fetch_parts(parts))
+            rows = next(got)
+            blocks = (next(got), next(got)) if has_sketch else None
+            wide = (next(got), next(got)) if n_wide else None
+            tier_rows = list(got)
         with self.tracer.span(SPAN_FLUSH_SPLIT):
-            return self._split_drained(
-                entry, flat, total, n_blocks, n_wide, tier_totals
+            return self._split_drained(entry, rows, blocks, wide, tier_rows)
+
+    def _hold_sketch_blocks(self, block_rows: np.ndarray, wins: np.ndarray) -> None:
+        for blk in unpack_drained(block_rows, wins, self.config.sketch):
+            have = self._sketch_blocks.get(blk.window)
+            self._sketch_blocks[blk.window] = (
+                blk if have is None else have.merge(blk)
             )
 
     def _split_drained(
-        self, entry: "_FlushEntry", flat: np.ndarray, total: int,
-        n_blocks: int, n_wide: int, tier_totals: list[int],
+        self, entry: "_FlushEntry", rows: np.ndarray,
+        blocks: tuple[np.ndarray, np.ndarray] | None,
+        wide: tuple[np.ndarray, np.ndarray] | None,
+        tier_rows: list[np.ndarray],
     ) -> list[FlushedWindow]:
-        """The host half of a drain: cut the fetched u32 run back into
-        exact rows, sketch blocks and tier rows, split the rows into
-        windows and marry the blocks to them."""
-        has_sketch = entry.pend is not None
-        row_cols = entry.packed.shape[1]
-        wide = entry.pend.shape[1] if has_sketch else 0
-        o = 0
-
-        def take(n: int) -> np.ndarray:
-            nonlocal o
-            out = flat[o : o + n]
-            o += n
-            return out
-
-        rows = take(total * row_cols).reshape(total, row_cols)
+        """The host half of a drain: split the fetched exact rows into
+        windows, unpack the sketch blocks (pending `blocks` and the wide
+        arena's slots, each as (rows, window ids)) and marry them to the
+        windows, then build the tier windows from `tier_rows`."""
         flushed = []
-        if has_sketch:
-            block_rows = take(n_blocks * wide).reshape(n_blocks, wide)
-            wins = take(n_blocks)
-            for blk in unpack_drained(block_rows, wins, self.config.sketch):
-                have = self._sketch_blocks.get(blk.window)
-                self._sketch_blocks[blk.window] = (
-                    blk if have is None else have.merge(blk)
-                )
-        if n_wide:
-            pw, wide_w = entry.wide_rows.shape
-            w_rows = take(pw * wide_w).reshape(pw, wide_w)
-            w_wins = take(pw)
+        if blocks is not None:
+            self._hold_sketch_blocks(*blocks)
+        if wide is not None:
+            w_rows, w_wins = wide
             keep = w_wins != np.uint32(SENTINEL_WIN)
-            for blk in unpack_drained(w_rows[keep], w_wins[keep],
-                                      self.config.sketch):
-                have = self._sketch_blocks.get(blk.window)
-                self._sketch_blocks[blk.window] = (
-                    blk if have is None else have.merge(blk)
-                )
-        if total:
-            flushed = self._split_flushed(rows, total)
+            self._hold_sketch_blocks(w_rows[keep], w_wins[keep])
+        if rows.shape[0]:
+            flushed = self._split_flushed(rows, rows.shape[0])
         # marry blocks to this drain's window range; blocks whose exact
         # rows were all shed become sketch-only windows (count == 0)
         for f in flushed:
@@ -986,9 +1041,10 @@ class WindowManager:
                 if f.sketches is not None:
                     self.cascade.feed_block(0, f.window_idx, f.sketches)
             tier_wins: list[FlushedWindow] = []
-            for tf, t in zip(entry.tiers, tier_totals):
-                t_rows = take(t * row_cols).reshape(t, row_cols)
-                tier_wins.extend(self.cascade.take_tier_windows(tf, t_rows, t))
+            for tf, t_rows in zip(entry.tiers, tier_rows):
+                tier_wins.extend(
+                    self.cascade.take_tier_windows(tf, t_rows, t_rows.shape[0])
+                )
             if lin is not None and tier_wins:
                 lin.note_tier_windows(
                     [(f.interval, f.window_idx, f.count) for f in tier_wins]
@@ -1128,7 +1184,7 @@ class WindowManager:
         folded in first — a pure device dispatch, zero fetches, the
         same fold the next advance would run) plus the open sketch
         slots, fetched in the flush drain's 2-transfer shape (one
-        scalar, one concatenated row block). The stash is untouched
+        scalar, one list of fixed-size pages). The stash is untouched
         (stash_snapshot_range does not donate), so the later real flush
         of these windows emits the same rows plus whatever arrived
         after the snapshot — the overlay contract the querier relies
@@ -1180,25 +1236,15 @@ class WindowManager:
         if self.sk is not None:
             blocks, wins = _sketch_open_snapshot(self.sk)
         total_i = int(self._fetch(jnp.asarray(total, jnp.int32)))
-        row_cols = packed.shape[1]
-        if blocks is None:
-            if total_i:
-                rows = self._fetch(packed[:total_i])
-            else:
-                rows = np.zeros((0, row_cols), np.uint32)
-            windows = self._split_rows(rows, total_i, partial=True)
-        else:
-            r, wide = blocks.shape
-            flat = self._fetch(
-                jnp.concatenate(
-                    [packed[:total_i].reshape(-1), blocks.reshape(-1), wins]
-                )
-            )
-            nb = total_i * row_cols
-            rows = flat[:nb].reshape(total_i, row_cols)
-            block_rows = flat[nb : nb + r * wide].reshape(r, wide)
-            win_np = flat[nb + r * wide :]
-            windows = self._split_rows(rows, total_i, partial=True)
+        parts = [_PagedRows(packed, total_i)]
+        if blocks is not None:
+            # every open slot: the host filters on SENTINEL_WIN
+            r = blocks.shape[0]
+            parts += [_PagedRows(blocks, r), _PagedRows(wins, r)]
+        rows, *open_slots = self._fetch_parts(parts)
+        windows = self._split_rows(rows, total_i, partial=True)
+        if open_slots:
+            block_rows, win_np = open_slots
             live = win_np != np.uint32(SENTINEL_WIN)
             open_blocks = {
                 blk.window: blk
@@ -1693,6 +1739,11 @@ class WindowManager:
             "host_fetches": self.host_fetches,
             "bytes_fetched": self.bytes_fetched,
             "bytes_uploaded": self.bytes_uploaded,
+            # the drains' paged row fetch: fetched − live is the
+            # over-fetch, under one page per part per drain
+            "flush_pages": self.flush_pages,
+            "flush_rows_fetched": self.flush_rows_fetched,
+            "flush_rows_live": self.flush_rows_live,
             # transient-failure lanes (ISSUE 6): non-zero means the
             # retry policy absorbed device hiccups
             "dispatch_retries": self.dispatch_retries,

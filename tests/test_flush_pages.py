@@ -1,0 +1,279 @@
+"""The window close fetches fixed-size pages (ISSUE 27): whatever the
+document count of a close, the drained windows equal the sequential
+per-window `stash_flush` oracle bit for bit, nothing compiles after the
+first closes, the over-fetch stays under one page per part, and a close
+is still two fetches. The same for the live read plane's snapshot."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepflow_tpu.aggregator.window as window_mod
+from deepflow_tpu.aggregator.cascade import CascadeConfig
+from deepflow_tpu.aggregator.sketchplane import SketchConfig
+from deepflow_tpu.aggregator.stash import (
+    stash_flush,
+    stash_snapshot_range,
+    unpack_flush_rows,
+)
+from deepflow_tpu.aggregator.window import WindowConfig, WindowManager, _PagedRows
+from deepflow_tpu.datamodel.schema import FLOW_METER, TAG_SCHEMA
+from deepflow_tpu.ops.histogram import LogHistSpec
+from deepflow_tpu.utils.spans import FLUSH_SPAN_NAMES, SPAN_QUERY_SNAPSHOT
+
+PAGE = 12  # does not divide the 128-row stash: the last page is clamped
+CAPACITY = 128
+WIDTH = 128  # every batch has this many rows; `valid` picks the live ones
+# a minute starts 45 s after T0, so the cascade's tier closes mid-stream
+T0 = 1_700_000_000 - 1_700_000_000 % 60 + 15
+SK = SketchConfig(
+    num_groups=4, hll_precision=6, cms_depth=2, cms_width=128,
+    hist=LogHistSpec(bins=32, vmin=1.0, gamma=1.3),
+    topk_rows=2, topk_cols=64, pending=16,
+)
+# the tier's stash has another row count than tier 0's: a page program of
+# its own, compiled at the first tier close
+CASCADE = CascadeConfig(intervals=(60,), capacity=256)
+CONFIGS = {
+    "plain": {},
+    "sketch": {"sketch": SK},
+    "cascade": {"sketch": SK, "cascade": CASCADE},
+}
+# (second after T0, live rows). Every batch closes what came before it: the
+# second warm-up batch 4 rows and their minute, then 7, 11, 12, 13, 1, 124
+# (its last page starts past row 116 and is clamped), 3, 0 (an empty span),
+# 19 (two windows), 6 rows, and `flush_all` the last 25. A window and the
+# next together stay under the stash's rows: nothing is shed.
+WARM_UP = ((-130, 4), (-60, 7))
+STREAM = ((0, 11), (10, 12), (20, 13), (30, 1), (40, 124), (50, 3), (60, 17),
+          (61, 2), (70, 6), (80, 25))
+CLOSED = [4, 7, 11, 12, 13, 1, 124, 3, 0, 19, 6, 25]
+TIER_CLOSED = [4, 7, 11 + 12 + 13 + 1 + 124, 3 + 17 + 2 + 6 + 25]
+
+def _batch(second: int, live: int):
+    """WIDTH doc rows stamped T0 + second with keys of that second's own,
+    the first `live` of them valid."""
+    keys = (np.uint32(1000) * np.uint32(second + 200)
+            + np.arange(WIDTH, dtype=np.uint32))
+    tags = np.zeros((TAG_SCHEMA.num_fields, WIDTH), np.uint32)
+    tags[TAG_SCHEMA.index("ip0_w3")] = keys
+    tags[TAG_SCHEMA.index("server_port")] = 443
+    tags[TAG_SCHEMA.index("protocol")] = 6
+    tags[TAG_SCHEMA.index("l3_epc_id1")] = keys % 5
+    meters = np.zeros((FLOW_METER.num_fields, WIDTH), np.float32)
+    meters[FLOW_METER.index("byte_tx")] = 0.1 + keys % 7  # uneven bit patterns
+    meters[FLOW_METER.index("rtt_sum")] = 10.5
+    meters[FLOW_METER.index("rtt_count")] = 1.0
+    return (np.full(WIDTH, T0 + second, np.uint32),
+            jnp.asarray(keys * np.uint32(2654435761) + np.uint32(1)),
+            jnp.asarray(keys ^ np.uint32(0x9E3779B9)),
+            jnp.asarray(tags), jnp.asarray(meters),
+            jnp.asarray(np.arange(WIDTH) < live))
+
+
+def _oracle_windows(state, lo: int, hi: int) -> dict:
+    """window -> (key_hi, key_lo, tags [n, T], meters [n, M]) from the
+    sequential ascending per-window `stash_flush` loop over [lo, hi)."""
+    slots, valid = np.asarray(state.slot), np.asarray(state.valid)
+    out = {}
+    for w in sorted({int(w) for w in slots[valid] if lo <= int(w) < hi}):
+        state, got = stash_flush(state, np.uint32(w))
+        mask = np.asarray(got["mask"])
+        out[w] = (np.asarray(got["key_hi"])[mask], np.asarray(got["key_lo"])[mask],
+                  np.asarray(got["tags"]).T[mask], np.asarray(got["meters"]).T[mask])
+    return out
+
+
+def _bits(a: np.ndarray) -> np.ndarray:
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+def _assert_windows_equal(flushed, oracle: dict) -> None:
+    assert [f.window_idx for f in flushed if f.count] == sorted(oracle)
+    for f in flushed:
+        if f.count:
+            for got, want in zip((f.key_hi, f.key_lo, f.tags, f.meters),
+                                 oracle[f.window_idx]):
+                np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def _block_lanes(blk) -> dict:
+    return {k: v for k, v in vars(blk).items() if isinstance(v, np.ndarray)}
+
+
+def _run_stream(monkeypatch, page_rows: int, config: dict) -> dict:
+    """WARM_UP, STREAM and `flush_all` through a WindowManager with pages
+    of `page_rows`; every range flush it dispatches is first run through
+    the sequential oracle on a copy of the stash."""
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", page_rows)
+    oracle: list[dict] = []
+    real_flush_range = window_mod.stash_flush_range
+
+    def recording_flush_range(state, lo, hi, **kw):
+        oracle.append(_oracle_windows(jax.tree.map(jnp.array, state), int(lo), int(hi)))
+        return real_flush_range(state, lo, hi, **kw)
+
+    monkeypatch.setattr(window_mod, "stash_flush_range", recording_flush_range)
+    wm = WindowManager(WindowConfig(capacity=CAPACITY, **config))
+    closes, flushed, tiers = [], [], []
+
+    def close(call):
+        c0, n0 = wm.get_counters(), len(oracle)
+        out = call()
+        c1 = wm.get_counters()
+        assert len(oracle) - n0 <= 1  # one range flush, one drain
+        if len(oracle) > n0:
+            _assert_windows_equal(out, oracle[-1])
+            closes.append({
+                "total": sum(f.count for f in out),
+                "compiles": wm.tracer.compile_lanes(FLUSH_SPAN_NAMES)[0],
+                **{k: c1[k] - c0[k] for k in (
+                    "host_fetches", "flush_pages", "flush_rows_fetched",
+                    "flush_rows_live")},
+            })
+        flushed.extend(out)
+        tiers.extend(wm.pop_tier_windows())
+
+    for second, live in WARM_UP + STREAM:
+        close(lambda: wm.ingest(*_batch(second, live)))
+    close(wm.flush_all)
+    wm.close()
+    return {"closes": closes, "flushed": flushed, "tiers": tiers,
+            "counters": wm.get_counters()}
+
+
+@pytest.mark.parametrize("name", list(CONFIGS))
+def test_paged_close_equals_oracle_and_compiles_nothing_per_count(monkeypatch, name):
+    config = CONFIGS[name]
+    run = _run_stream(monkeypatch, PAGE, config)
+    closes = run["closes"]
+    totals = [c["total"] for c in closes]
+    assert totals == CLOSED and len(set(totals)) == len(totals)
+    assert {0, 1, PAGE - 1, PAGE, PAGE + 1} <= set(totals) and max(totals) > 3 * PAGE
+
+    # (b) nothing compiles under flush.* after the warm-up's closes (the
+    # first close, and for the cascade the first tier close)
+    assert [c["compiles"] for c in closes[1:]] == [closes[0]["compiles"]] * (len(closes) - 1)
+
+    # (c) the over-fetch is under one page per part; (d) a close is the
+    # counter block's fetch (ingest only), the counts and one row fetch
+    parts = 1 + 2 * ("sketch" in config) + ("cascade" in config)
+    for i, c in enumerate(closes):
+        over = c["flush_rows_fetched"] - c["flush_rows_live"]
+        assert 0 <= over < PAGE * parts, c
+        assert c["flush_rows_live"] >= c["total"]
+        is_ingest = i < len(closes) - 1
+        assert c["host_fetches"] <= 2 + is_ingest
+        if c["total"]:
+            assert c["host_fetches"] == 2 + is_ingest
+        if name == "plain":
+            assert c["flush_rows_live"] == c["total"] and over < PAGE
+            assert c["flush_pages"] == -(-c["total"] // PAGE)
+            assert c["flush_rows_fetched"] == c["flush_pages"] * PAGE
+    assert run["counters"]["flushed_doc"] == sum(CLOSED)
+
+    # the ride-alongs: blocks and tier windows equal those of a manager
+    # that fetches every matrix whole (a page over every matrix's rows)
+    whole = _run_stream(monkeypatch, 1 << 20, config)
+    assert [c["total"] for c in whole["closes"]] == CLOSED
+    for kind in ("flushed", "tiers"):
+        assert len(run[kind]) == len(whole[kind])
+        for f, g in zip(run[kind], whole[kind]):
+            assert (f.window_idx, f.count, f.tier, f.interval) == \
+                   (g.window_idx, g.count, g.tier, g.interval)
+            for got, want in zip((f.key_hi, f.key_lo, f.tags, f.meters),
+                                 (g.key_hi, g.key_lo, g.tags, g.meters)):
+                np.testing.assert_array_equal(_bits(got), _bits(want))
+            assert (f.sketches is None) == (g.sketches is None) == ("sketch" not in config)
+            if f.sketches is not None:
+                fb, gb = _block_lanes(f.sketches), _block_lanes(g.sketches)
+                assert fb.keys() == gb.keys() and fb
+                for k in fb:
+                    np.testing.assert_array_equal(fb[k], gb[k])
+    if "cascade" in config:
+        # the two minutes of the warm-up, the stream's first, its second
+        assert [t.count for t in run["tiers"]] == TIER_CLOSED
+
+
+@pytest.mark.parametrize("name", ["plain", "sketch"])
+def test_paged_snapshot_equals_snapshot_range_and_compiles_once(monkeypatch, name):
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", PAGE)
+    wm = WindowManager(WindowConfig(capacity=CAPACITY, delay=4, **CONFIGS[name]))
+    compiles = []
+    for second, live in ((0, 5), (1, 20), (2, 30)):  # 5, 25, 55 open rows
+        assert wm.ingest(*_batch(second, live)) == []
+        f0 = wm.host_fetches
+        snap = wm.snapshot_open(force=True)
+        assert wm.host_fetches - f0 == 2
+        compiles.append(wm.tracer.compile_lanes((SPAN_QUERY_SNAPSHOT,))[0])
+        packed, total = stash_snapshot_range(  # the snapshot folded the ring in
+            wm.state, np.uint32(wm.start_window), np.uint32(0xFFFFFFFF))
+        win, key_hi, key_lo, tags, meters = unpack_flush_rows(
+            np.asarray(packed)[: int(total)], TAG_SCHEMA.num_fields)
+        rows = [w for w in snap.windows if w.count]
+        assert sum(w.count for w in rows) == int(total) == sum(
+            live for s, live in ((0, 5), (1, 20), (2, 30)) if s <= second)
+        assert all(w.partial for w in snap.windows)
+        np.testing.assert_array_equal(
+            np.concatenate([np.full(w.count, w.window_idx, np.uint32) for w in rows]), win)
+        for got, want in ((np.concatenate([w.key_hi for w in rows]), key_hi),
+                          (np.concatenate([w.key_lo for w in rows]), key_lo),
+                          (np.concatenate([w.tags for w in rows]), tags),
+                          (np.concatenate([w.meters for w in rows]), meters)):
+            np.testing.assert_array_equal(_bits(got), _bits(want))
+        assert all((w.sketches is not None) == (name == "sketch") for w in snap.windows)
+    assert compiles[1:] == compiles[:1] * 2  # nothing after the first snapshot
+    # a snapshot is no drain: the drain's counters stand still
+    assert wm.get_counters()["flush_pages"] == 0
+    _assert_windows_equal(
+        wm.flush_all(),
+        {f.window_idx: (f.key_hi, f.key_lo, f.tags, f.meters) for f in snap.windows})
+    wm.close()
+
+
+@pytest.mark.parametrize("size,page,axis", [
+    (64, 12, 0), (64, 16, 0), (10, 12, 0), (64, 12, 1), (7, 3, 1)])
+def test_paged_rows_cut_back_to_the_live_rows(monkeypatch, size, page, axis):
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", page)
+    shape = (size, 5) if axis == 0 else (3, size)
+    host = np.arange(np.prod(shape), dtype=np.uint32).reshape(shape)
+    x = jnp.asarray(host)
+    for n in sorted({0, 1, page - 1, page, page + 1, 3 * page + 1, size - 1, size}):
+        if not 0 <= n <= size:
+            continue
+        part = _PagedRows(x, n, axis=axis)
+        assert len(part.pages) == (-(-n // min(page, size)) if n else 0)
+        assert part.rows_fetched - n < page and part.n == n
+        assert all(p.shape[axis] == min(page, size) for p in part.pages)
+        got = part.join(window_mod.host_fetch(part.pages)) if part.pages else part.join([])
+        want = host[:n] if axis == 0 else host[:, :n]
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+    vec = _PagedRows(jnp.arange(size, dtype=jnp.uint32), min(size, page + 1))
+    np.testing.assert_array_equal(
+        vec.join(window_mod.host_fetch(vec.pages)),
+        np.arange(min(size, page + 1), dtype=np.uint32))
+
+
+def test_get_counters_carries_the_page_counters_fetch_free(monkeypatch):
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", PAGE)
+    wm = WindowManager(WindowConfig(capacity=CAPACITY))
+    c = wm.get_counters()
+    assert (c["flush_pages"], c["flush_rows_fetched"], c["flush_rows_live"]) == (0, 0, 0)
+    wm.ingest(*_batch(0, 30))
+    wm.ingest(*_batch(10, 1))  # closes the 30 documents: 3 pages of 12
+
+    def no_fetch(_x):
+        raise AssertionError("get_counters must not touch the device")
+
+    monkeypatch.setattr(window_mod, "host_fetch", no_fetch)
+    f0 = wm.host_fetches
+    c = wm.get_counters()
+    assert wm.host_fetches == f0
+    assert (c["flush_pages"], c["flush_rows_fetched"], c["flush_rows_live"]) == (3, 36, 30)
+    assert all(isinstance(c[k], int) for k in (
+        "flush_pages", "flush_rows_fetched", "flush_rows_live"))
+    wm.close()

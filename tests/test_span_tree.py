@@ -45,6 +45,7 @@ NEW_LAYERS = (
     "feeder.assemble_ms_per_mrec", "step.stage_ms_per_mrec",
     "flush.wait_ms_per_window", "flush.rows_ms_per_window",
     "flush.split_ms_per_window", "flush.compile_ms_per_window",
+    "flush.fetched_rows_per_live_row",  # PR 27
 )
 
 
@@ -368,27 +369,36 @@ def test_compile_outside_any_span_goes_to_the_unspanned_lanes():
     assert tr.recent(SPAN_XLA_COMPILE) == [] and tr.compile_lanes() == (0, 0)
 
 
-def test_window_manager_counts_compiles_under_its_spans():
+def test_window_manager_counts_compiles_under_its_spans(monkeypatch):
+    from deepflow_tpu.aggregator import window as window_mod
     from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
     from deepflow_tpu.aggregator.window import WindowConfig
     from deepflow_tpu.datamodel.batch import FlowBatch
     from deepflow_tpu.ingest.replay import SyntheticFlowGen
 
+    # a page size of this test's own, under the stash's rows: the first
+    # close compiles the page program, whatever ran in the process before
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", 257)
     pipe = L4Pipeline(PipelineConfig(window=WindowConfig(capacity=1 << 10),
                                      batch_size=64))
     try:
         c = pipe.get_counters()
         assert (c["xla_compiles"], c["xla_compile_us"],
                 c["flush_compiles"], c["flush_compile_us"]) == (0, 0, 0, 0)
-        gen = SyntheticFlowGen(num_tuples=23, seed=3)  # a document count of its own
+        gen = SyntheticFlowGen(num_tuples=23, seed=3)
         for t in (5000, 5001, 5004, 5008):
             pipe.ingest(FlowBatch.from_records(gen.records(57, t)))
         c = pipe.get_counters()
         assert c["xla_compiles"] >= c["flush_compiles"] >= 1
         assert c["xla_compile_us"] >= c["flush_compile_us"] > 0
         rows = pipe.tracer.summary()[SPAN_FLUSH_ROWS]
-        assert rows["compiles"] >= 1  # the per-count slice / reshape
+        assert rows["compiles"] == 1  # the page program, once
         assert c["jit_compiles"] == 1  # the fused step's own monitor stays
+        # closes with other document counts compile nothing more
+        for i, t in enumerate((5012, 5016, 5020)):
+            pipe.ingest(FlowBatch.from_records(gen.records(11 + 17 * i, t)))
+        assert pipe.get_counters()["flush_compiles"] == c["flush_compiles"]
+        assert pipe.get_counters()["flush_rows_live"] > c["flush_rows_live"] > 0
     finally:
         pipe.close()
 
