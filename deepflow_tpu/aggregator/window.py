@@ -71,6 +71,8 @@ from ..utils.retry import (
 from ..utils.spans import (
     FLUSH_SPAN_NAMES,
     SPAN_FLUSH_DRAIN,
+    SPAN_FLUSH_FETCH,
+    SPAN_FLUSH_JOIN,
     SPAN_FLUSH_ROWS,
     SPAN_FLUSH_SPLIT,
     SPAN_FLUSH_WAIT,
@@ -765,6 +767,11 @@ class WindowManager:
         # post-fold, pre-flush stash of that batch)
         self.excess_word_hits = 0
         self.stash_occupancy = 0
+        # the gauge above summed over every processed counter block, and
+        # the capacity as often: monotone, so a delta of the two gives
+        # the mean live share of the stash over any stretch of blocks
+        self.stash_live_rows_sum = 0
+        self.stash_capacity_rows_sum = 0
         self.stash_evictions = 0
         self.device_ring_fill = 0
         self.fold_rows = 0  # CB_FOLD_ROWS mirror: last fold's sorted rows
@@ -903,8 +910,12 @@ class WindowManager:
         """Every page of every part of a drain in ONE fetch; returns each
         part cut back to its live rows. No pages, no fetch."""
         pages = [pg for part in parts for pg in part.pages]
-        got = iter(self._fetch(pages) if pages else ())
-        return [part.join([next(got) for _ in part.pages]) for part in parts]
+        got = iter(())
+        if pages:
+            with self.tracer.span(SPAN_FLUSH_FETCH):
+                got = iter(self._fetch(pages))
+        with self.tracer.span(SPAN_FLUSH_JOIN):
+            return [part.join([next(got) for _ in part.pages]) for part in parts]
 
     # -- device→host drains ---------------------------------------------
     def _drain_flush(self, entry: "_FlushEntry") -> list[FlushedWindow]:
@@ -1321,6 +1332,8 @@ class WindowManager:
             t_max, t_min, n_valid, n_late, aux = vec[CB_T_MAX:CB_PREREDUCE_SHED + 1]
             self.excess_word_hits += vec[CB_EXCESS_HITS]
             self.stash_occupancy = vec[CB_STASH_OCCUPANCY]
+            self.stash_live_rows_sum += vec[CB_STASH_OCCUPANCY]
+            self.stash_capacity_rows_sum += self.config.capacity
             self.stash_evictions = vec[CB_STASH_EVICTIONS]
             self.device_ring_fill = vec[CB_RING_FILL]
             self.feeder_shed += vec[CB_FEEDER_SHED]
@@ -1724,6 +1737,8 @@ class WindowManager:
             "prereduce_shed": self.aux_count,
             "excess_word_hits": self.excess_word_hits,
             "stash_occupancy": self.stash_occupancy,
+            "stash_live_rows_sum": self.stash_live_rows_sum,
+            "stash_capacity_rows_sum": self.stash_capacity_rows_sum,
             "stash_evictions": self.stash_evictions,
             "acc_fill": self.fill,  # rows awaiting the next fold
             # device-reported ring fill at last dispatch — must track

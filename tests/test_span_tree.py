@@ -46,6 +46,8 @@ NEW_LAYERS = (
     "flush.wait_ms_per_window", "flush.rows_ms_per_window",
     "flush.split_ms_per_window", "flush.compile_ms_per_window",
     "flush.fetched_rows_per_live_row",  # PR 27
+    "stash.live_share", "step.doc_rows_per_record",  # PR 28
+    "flush.docs_per_window", "flush.fetch_ms_per_window",
 )
 
 
