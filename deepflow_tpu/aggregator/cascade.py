@@ -58,7 +58,7 @@ import numpy as np
 from jax import lax
 
 from ..datamodel.schema import MeterSchema, TagSchema
-from ..ops.segment import SENTINEL_SLOT, _use_shared_sort
+from ..ops.segment import SENTINEL_SLOT, _use_shared_sort, _varying_like
 from .sketchplane import WindowSketchBlock
 from .stash import (
     AccumState,
@@ -182,13 +182,6 @@ tier_ring_fold = partial(
     static_argnames=("sum_cols_t", "max_cols_t", "shared_sort"),
     donate_argnums=(0, 1, 2),
 )(_ring_fold_impl)
-
-
-def _varying_like(const, ref):
-    """`const` typed like `ref`: under shard_map state varies over the
-    mesh axes, and every `cond` branch must return that same type."""
-    vma = tuple(jax.typeof(ref).vma)
-    return lax.pcast(const, vma, to="varying") if vma else const
 
 
 def _tier_step_impl(tier: StashState, acc, fill, lanes, packed, total, hi,

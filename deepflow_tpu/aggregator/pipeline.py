@@ -213,7 +213,7 @@ def make_ingest_step(fanout_config: FanoutConfig, interval: int = 1, app: bool =
 
     if fold_mode == "merge":
         def fold(stash, acc):
-            new_stash, new_acc, _fold_rows = _merge_fold_impl(
+            new_stash, new_acc, _fold_lanes = _merge_fold_impl(
                 stash, acc, jnp.uint32(SENTINEL_SLOT), sum_cols, max_cols
             )
             return new_stash, new_acc
@@ -403,7 +403,7 @@ class RollupPipeline:
             )
 
         def step(acc, offset, start_window, stash_valid, stash_evict,
-                 feeder_shed, fold_rows, casc_lanes, snap_lanes, sk,
+                 feeder_shed, fold_lanes, casc_lanes, snap_lanes, sk,
                  tag_mat, meters, valid):
             # the stages carry names (jax.named_scope: metadata only) so
             # a device profile can say which one an op belongs to
@@ -431,7 +431,8 @@ class RollupPipeline:
                     ts, doc_valid, start_window, interval, aux=aux,
                     excess_hits=excess_hits, stash_valid=stash_valid,
                     stash_evictions=stash_evict, ring_fill=offset,
-                    feeder_shed=feeder_shed, fold_rows=fold_rows,
+                    feeder_shed=feeder_shed, fold_rows=fold_lanes[0],
+                    fold_blocks=fold_lanes[1],
                     sketch_rows=None if sk is None else sk.rows,
                     sketch_shed=None if sk is None else sk.shed,
                     cascade_rows=casc_lanes[0], cascade_shed=casc_lanes[1],
@@ -450,10 +451,10 @@ class RollupPipeline:
             # to the pre-ISSUE-8 step: None is not a pytree leaf we want
             # in the dispatch path
             def step_plain(acc, offset, start_window, stash_valid, stash_evict,
-                           feeder_shed, fold_rows, casc_lanes, snap_lanes,
+                           feeder_shed, fold_lanes, casc_lanes, snap_lanes,
                            tag_mat, meters, valid):
                 return step(acc, offset, start_window, stash_valid,
-                            stash_evict, feeder_shed, fold_rows, casc_lanes,
+                            stash_evict, feeder_shed, fold_lanes, casc_lanes,
                             snap_lanes, None, tag_mat, meters, valid)
 
             return jax.jit(step_plain, donate_argnums=(0,))
@@ -545,13 +546,13 @@ class RollupPipeline:
         def dispatch(acc, offset, start_window):
             # stash lanes read at dispatch time (post any fold) — device
             # handles, no transfer; they fill the counter block's
-            # occupancy/eviction/fold_rows/cascade lanes inside the same
+            # occupancy/eviction/fold/cascade lanes inside the same
             # fused call. The sketch plane rides the same dispatch when on.
             st = self.wm.state
             casc = self.wm._cascade_lanes()
             snap = self.wm._snapshot_lanes()
             args = (acc, offset, start_window, st.valid, st.dropped_overflow,
-                    shed, self.wm._fold_rows_dev, casc, snap)
+                    shed, self.wm._fold_lanes_dev, casc, snap)
             if self.wm.sk is not None:
                 args = args + (self.wm.sk,)
             args = args + (staged.tag_mat, staged.meters, staged.valid)

@@ -42,6 +42,7 @@ from ..ops.segment import (
     groupby_reduce_sorted,
     merge_order,
     merge_ranks,
+    out_blocks_run,
 )
 
 _U32_MAX = np.uint32(0xFFFFFFFF)
@@ -255,16 +256,24 @@ def check_fold_mode(mode: str) -> str:
     return mode
 
 
+def _fold_lanes(fold_rows, new_state: StashState):
+    """The fold's two telemetry scalars as one [2] u32 vector:
+    [fold_rows, fold_blocks]. fold_blocks is the trip count of the
+    group-by's output loop (ops/segment.py): the stash keeps the first
+    `capacity` segments, so its live rows are min(num_seg, capacity).
+    They ride the device counter block's CB_FOLD_ROWS / CB_FOLD_BLOCKS
+    lanes, zero extra host syncs."""
+    blocks = out_blocks_run(jnp.sum(new_state.valid), new_state.capacity)
+    return jnp.stack([fold_rows.astype(jnp.uint32), blocks.astype(jnp.uint32)])
+
+
 def _fold_counted_impl(state: StashState, acc: AccumState, sum_cols_t, max_cols_t):
-    """`_fold_impl` + the fold_rows telemetry scalar: live rows the
-    fold's keyed sort touched (whole stash + whole accumulator — the
-    full-sort fold re-sorts everything). Rides the device counter
-    block's CB_FOLD_ROWS lane, zero extra host syncs."""
-    fold_rows = (
-        jnp.sum(state.valid) + jnp.sum(acc.slot != jnp.uint32(SENTINEL_SLOT))
-    ).astype(jnp.uint32)
+    """`_fold_impl` + the fold's telemetry lanes (`_fold_lanes`);
+    fold_rows: live rows the fold's keyed sort touched (whole stash +
+    whole accumulator — the full-sort fold re-sorts everything)."""
+    fold_rows = jnp.sum(state.valid) + jnp.sum(acc.slot != jnp.uint32(SENTINEL_SLOT))
     new_state, new_acc = _fold_impl(state, acc, sum_cols_t, max_cols_t)
-    return new_state, new_acc, fold_rows
+    return new_state, new_acc, _fold_lanes(fold_rows, new_state)
 
 
 collector_fold_counted = partial(
@@ -275,7 +284,8 @@ collector_fold_counted = partial(
 def stash_fold_counted(
     state: StashState, acc: AccumState, meter_schema: MeterSchema
 ) -> tuple[StashState, AccumState, jnp.ndarray]:
-    """Schema-keyed `collector_fold_counted` → (state, acc, fold_rows)."""
+    """Schema-keyed `collector_fold_counted` → (state, acc, fold_lanes)
+    with fold_lanes = [fold_rows, fold_blocks] (`_fold_lanes`)."""
     sum_cols = tuple(int(i) for i in np.nonzero(meter_schema.sum_mask)[0])
     max_cols = tuple(int(i) for i in np.nonzero(meter_schema.max_mask)[0])
     return collector_fold_counted(state, acc, sum_cols, max_cols)
@@ -383,8 +393,9 @@ def _merge_fold_impl(state: StashState, acc: AccumState, hi_window, sum_cols_t, 
     SENTINEL_SLOT for the full-set fold (every live row folds, the ring
     empties — same contract as `_fold_impl`). Requires the canonical
     stash layout (see the section comment above); returns
-    (new_state, new_acc, fold_rows) where fold_rows counts the acc rows
-    this fold's keyed sort actually touched.
+    (new_state, new_acc, fold_lanes) where fold_lanes = [fold_rows,
+    fold_blocks] (`_fold_lanes`) and fold_rows counts the acc rows this
+    fold's keyed sort actually touched.
 
     One-pass scoping note (ISSUE 17): this sort is NOT a candidate for
     the sketch plane's shared batch sort — it runs once per FOLD (every
@@ -420,8 +431,7 @@ def _merge_fold_impl(state: StashState, acc: AccumState, hi_window, sum_cols_t, 
     new_acc = dataclasses.replace(
         acc, slot=jnp.where(fold_mask, jnp.uint32(SENTINEL_SLOT), acc.slot)
     )
-    fold_rows = jnp.sum(fold_mask).astype(jnp.uint32)
-    return new_state, new_acc, fold_rows
+    return new_state, new_acc, _fold_lanes(jnp.sum(fold_mask), new_state)
 
 
 collector_merge_fold = partial(
@@ -435,7 +445,7 @@ def stash_merge_fold(
     meter_schema: MeterSchema,
     hi_window=None,
 ) -> tuple[StashState, AccumState, jnp.ndarray]:
-    """Schema-keyed merge-fold → (state, acc, fold_rows). `hi_window`
+    """Schema-keyed merge-fold → (state, acc, fold_lanes). `hi_window`
     None = full-set fold (ring empties — callers reset their fill
     cursor); otherwise only acc rows with slot < hi_window fold (the
     span-bounded window advance — callers must NOT reset fill)."""

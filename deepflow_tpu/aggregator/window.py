@@ -48,7 +48,7 @@ import numpy as np
 from .. import chaos
 from ..datamodel.schema import FLOW_METER, TAG_SCHEMA, MeterSchema, TagSchema
 from ..ops.hashing import fingerprint64
-from ..ops.segment import _use_fused_sketch, _use_shared_sort
+from ..ops.segment import _use_fused_sketch, _use_shared_sort, out_blocks_total
 from .cascade import CascadeConfig, TierCascade, TierFlush
 from .sketchplane import (
     SENTINEL_WIN,
@@ -201,8 +201,13 @@ class _PagedRows:
 # the occupancy gauge (allocated compact slots + closed-pending wide
 # slots at dispatch), and cumulative compact→wide promotions. Zero in
 # slab mode (the pool lanes are zero-size arrays whose sums are 0).
+# v8 (PR 29): + fold_blocks — the trip count of the LAST fold's output
+# loop (ops/segment.py: blocks of OUT_BLOCK_ROWS segments that held a
+# live one), the second lane of the vector the fold kernels return
+# beside fold_rows. Against `out_blocks_total(capacity)` it says how
+# much of the stash's capacity the fold's output side paid for.
 
-COUNTER_BLOCK_VERSION = 7
+COUNTER_BLOCK_VERSION = 8
 (
     CB_VERSION,  # constant COUNTER_BLOCK_VERSION
     CB_T_MAX,  # max valid timestamp (pre-gate)
@@ -225,14 +230,16 @@ COUNTER_BLOCK_VERSION = 7
     CB_SKETCH_POOL_SPILL,  # cumulative pool-exhaustion counted spills
     CB_SKETCH_POOL_OCC,  # pool occupancy gauge at dispatch (compact+wide)
     CB_SKETCH_PROMOTIONS,  # cumulative compact→wide slot promotions
-) = range(21)
-CB_LEN = 21
+    CB_FOLD_BLOCKS,  # output blocks the last fold's loop ran
+) = range(22)
+CB_LEN = 22
 CB_FIELDS = (
     "version", "t_max", "t_min", "n_valid", "n_late", "prereduce_shed",
     "excess_word_hits", "stash_occupancy", "stash_evictions", "ring_fill",
     "feeder_shed", "fold_rows", "sketch_rows", "sketch_shed",
     "cascade_rows", "cascade_shed", "snapshot_reads", "snapshot_bytes",
     "sketch_pool_spill", "sketch_pool_occ", "sketch_promotions",
+    "fold_blocks",
 )
 
 
@@ -282,6 +289,7 @@ def batch_counter_block(
     sketch_pool_spill=None,
     sketch_pool_occ=None,
     sketch_promotions=None,
+    fold_blocks=None,
 ):
     """`batch_stats` widened into the versioned counter block (traced).
 
@@ -290,9 +298,10 @@ def batch_counter_block(
     occupancy summed from the (device-resident — zero transfer) valid
     plane, cumulative eviction count, the accumulator-ring fill at
     dispatch, the feeder's upstream shed count for this batch, and the
-    last fold's touched-row count (a device scalar the fold kernels
-    return — ISSUE 5). All optional inputs default to zero so every
-    caller of the old 5-vector shape can widen incrementally."""
+    last fold's touched-row count and output-loop trip count (device
+    scalars the fold kernels return — ISSUE 5, PR 29). All optional
+    inputs default to zero so every caller of the old 5-vector shape
+    can widen incrementally."""
     gated, window, stats = batch_stats(timestamp, valid, start_window, interval, aux=aux)
 
     def u32(x):
@@ -313,7 +322,7 @@ def batch_counter_block(
                        u32(cascade_rows), u32(cascade_shed),
                        u32(snapshot_reads), u32(snapshot_bytes),
                        u32(sketch_pool_spill), u32(sketch_pool_occ),
-                       u32(sketch_promotions)]),
+                       u32(sketch_promotions), u32(fold_blocks)]),
         ]
     )
     return gated, window, block
@@ -321,11 +330,12 @@ def batch_counter_block(
 
 @partial(jax.jit, donate_argnums=(0,), static_argnames=("interval",))
 def _raw_append_step(acc, offset, start_window, stash_valid, stash_evict,
-                     feeder_shed, fold_rows, casc_lanes, snap_lanes,
+                     feeder_shed, fold_lanes, casc_lanes, snap_lanes,
                      timestamp, key_hi, key_lo, tags, meters, valid,
                      *, interval):
     """One jitted call per raw doc batch: late gate + counter block +
-    ring append. `stash_valid`/`stash_evict`/`fold_rows` are
+    ring append. `stash_valid`/`stash_evict`/`fold_lanes` (the last
+    fold's [fold_rows, fold_blocks]) are
     device-resident lanes folded into the block — inputs already on
     device, no transfer. `feeder_shed` is the feeder's upstream drop
     count for this batch (a host scalar riding the upload direction);
@@ -336,7 +346,8 @@ def _raw_append_step(acc, offset, start_window, stash_valid, stash_evict,
     gated, window, block = batch_counter_block(
         timestamp, valid, start_window, interval,
         stash_valid=stash_valid, stash_evictions=stash_evict, ring_fill=offset,
-        feeder_shed=feeder_shed, fold_rows=fold_rows,
+        feeder_shed=feeder_shed, fold_rows=fold_lanes[0],
+        fold_blocks=fold_lanes[1],
         cascade_rows=casc_lanes[0], cascade_shed=casc_lanes[1],
         snapshot_reads=snap_lanes[0], snapshot_bytes=snap_lanes[1],
     )
@@ -458,7 +469,7 @@ def sketch_span_bounds(start_window, ts, valid, *, interval: int, delay: int):
                      "fused_sketch"),
 )
 def _raw_append_step_sk(acc, offset, start_window, stash_valid, stash_evict,
-                        feeder_shed, fold_rows, casc_lanes, snap_lanes, sk,
+                        feeder_shed, fold_lanes, casc_lanes, snap_lanes, sk,
                         timestamp, key_hi, key_lo, tags, meters, valid,
                         *, interval, delay, ix, spec, shared_sort=True,
                         fused_sketch=False):
@@ -500,7 +511,8 @@ def _raw_append_step_sk(acc, offset, start_window, stash_valid, stash_evict,
     gated, window, block = batch_counter_block(
         ts, valid_b, start_window, interval,
         stash_valid=stash_valid, stash_evictions=stash_evict, ring_fill=offset,
-        feeder_shed=feeder_shed, fold_rows=fold_rows,
+        feeder_shed=feeder_shed, fold_rows=fold_lanes[0],
+        fold_blocks=fold_lanes[1],
         sketch_rows=sk.rows, sketch_shed=sk.shed,
         cascade_rows=casc_lanes[0], cascade_shed=casc_lanes[1],
         snapshot_reads=snap_lanes[0], snapshot_bytes=snap_lanes[1],
@@ -775,9 +787,17 @@ class WindowManager:
         self.stash_evictions = 0
         self.device_ring_fill = 0
         self.fold_rows = 0  # CB_FOLD_ROWS mirror: last fold's sorted rows
-        # device scalar the fold kernels return; rides into the next
-        # dispatch's counter block like the stash lanes (zero transfer)
-        self._fold_rows_dev = jnp.zeros((), jnp.uint32)
+        # the last fold's output-loop trip count (CB_FOLD_BLOCKS) summed
+        # over every processed counter block, and the stash's block count
+        # as often: monotone like the live-rows pair above, so a delta of
+        # the two is the share of the capacity the folds' output side ran
+        self.fold_blocks_run_sum = 0
+        self.fold_blocks_total_sum = 0
+        self._fold_blocks_total = out_blocks_total(config.capacity)
+        # [fold_rows, fold_blocks] device vector the fold kernels return;
+        # rides into the next dispatch's counter block like the stash
+        # lanes (zero transfer)
+        self._fold_lanes_dev = jnp.zeros((2,), jnp.uint32)
         # merge mode drains through the compacting range flush so the
         # stash keeps the canonical layout the rank-merge requires
         self._flush_compact = config.fold_mode == "merge"
@@ -1117,11 +1137,11 @@ class WindowManager:
             return
         with self.tracer.span(SPAN_WINDOW_FOLD):
             if self.config.fold_mode == "merge":
-                self.state, self.acc, self._fold_rows_dev = stash_merge_fold(
+                self.state, self.acc, self._fold_lanes_dev = stash_merge_fold(
                     self.state, self.acc, self.meter_schema
                 )
             else:
-                self.state, self.acc, self._fold_rows_dev = stash_fold_counted(
+                self.state, self.acc, self._fold_lanes_dev = stash_fold_counted(
                     self.state, self.acc, self.meter_schema
                 )
         self.fill = 0
@@ -1135,7 +1155,7 @@ class WindowManager:
         if self.fill == 0:
             return
         with self.tracer.span(SPAN_WINDOW_FOLD):
-            self.state, self.acc, self._fold_rows_dev = stash_merge_fold(
+            self.state, self.acc, self._fold_lanes_dev = stash_merge_fold(
                 self.state, self.acc, self.meter_schema,
                 hi_window=np.uint32(hi_window),
             )
@@ -1338,6 +1358,8 @@ class WindowManager:
             self.device_ring_fill = vec[CB_RING_FILL]
             self.feeder_shed += vec[CB_FEEDER_SHED]
             self.fold_rows = vec[CB_FOLD_ROWS]
+            self.fold_blocks_run_sum += vec[CB_FOLD_BLOCKS]
+            self.fold_blocks_total_sum += self._fold_blocks_total
             # cumulative device scalars — mirror, don't accumulate
             self.sketch_rows = vec[CB_SKETCH_ROWS]
             self.sketch_shed = vec[CB_SKETCH_SHED]
@@ -1457,7 +1479,7 @@ class WindowManager:
                 st = self.state
                 return _raw_append_step_sk(
                     acc, offset, start_window, st.valid, st.dropped_overflow,
-                    jnp.uint32(feeder_shed), self._fold_rows_dev,
+                    jnp.uint32(feeder_shed), self._fold_lanes_dev,
                     self._cascade_lanes(), self._snapshot_lanes(), self.sk,
                     timestamp, key_hi, key_lo, tags, meters, valid,
                     interval=interval, delay=self.config.delay,
@@ -1477,7 +1499,7 @@ class WindowManager:
                 st = self.state
                 return _raw_append_step(
                     acc, offset, start_window, st.valid, st.dropped_overflow,
-                    jnp.uint32(feeder_shed), self._fold_rows_dev,
+                    jnp.uint32(feeder_shed), self._fold_lanes_dev,
                     self._cascade_lanes(), self._snapshot_lanes(),
                     timestamp, key_hi, key_lo, tags, meters, valid,
                     interval=interval,
@@ -1625,7 +1647,7 @@ class WindowManager:
             "stash": self.state,
             "accumulator": self.acc,  # None until the first batch
             "stats_ring": [self._cb_ring, self._sw_state],
-            "lanes": [self._fold_rows_dev, self._zero_lanes,
+            "lanes": [self._fold_lanes_dev, self._zero_lanes,
                       self._snap_lanes_dev],
             # async-drain holds: the deferred stats vector plus every
             # dispatched-but-unfetched flush's device handles (packed
@@ -1750,6 +1772,8 @@ class WindowManager:
             # whole live stash + ring, merge mode only the folded acc
             # rows — the lane the fold-work perf gate watches (ISSUE 5)
             "fold_rows": self.fold_rows,
+            "fold_blocks_run_sum": self.fold_blocks_run_sum,
+            "fold_blocks_total_sum": self.fold_blocks_total_sum,
             "window_advances": self.n_advances,
             "host_fetches": self.host_fetches,
             "bytes_fetched": self.bytes_fetched,

@@ -6,8 +6,13 @@ with a fully static-shape XLA pattern:
 
     lax.sort((slot, key_hi, key_lo, iota), num_keys=3)
       → head flags from key-change deltas → segment ids (one cumsum)
-      → segment_sum / segment_max with sorted ids, num_segments = cap
-      → representative-row gathers only at the ≤cap segment heads
+      → a suffix scan (TPU) or segment_sum / segment_max (CPU) of the
+        meter rows in sorted order
+      → the head positions in order (one single-key sort)
+      → a loop over the LIVE segments in blocks of OUT_BLOCK_ROWS: head
+        look-ups and representative-row gathers at that block's heads,
+        written into outputs that start as dead rows. The trip count is
+        a device scalar, so the shapes stay static.
 
 Layout at the interface: tags stay column-major ([T, N] with the row
 axis minor — it maps rows onto the 128-wide vector lanes and keeps
@@ -162,64 +167,107 @@ def groupby_reduce(
     )
 
 
-def _reduce_meters(meters_rows, perm, seg_id, cap: int, first_pos,
-                   sum_cols: np.ndarray, max_cols: np.ndarray):
-    """Per-segment SUM / MAX of the meter rows in sorted order →
-    [M, cap]; columns of absent segments are unspecified (callers mask
-    by their live-segment prefix)."""
-    m = meters_rows.shape[1]
-    # Full-width segment ops + per-column select, NOT subset-indexed
-    # ops: `meters_rows[:, sum_cols]` materializes a strided copy of
-    # [N, |subset|] before each op, which costs more than running the
-    # op over all M lanes and discarding the unwanted half (measured
-    # ~16% off the whole fold at 588k rows — PERF.md §7b follow-up).
-    # On TPU both ops fuse into ONE scatter-free Pallas suffix-scan
-    # pass (segreduce_pallas.py, PERF.md §9).
-    if m and _use_pallas_reduce():
-        from .segreduce_pallas import sorted_segment_sum_max
+# Output rows one trip of the group-by's output loop makes (PERF.md §6,
+# PR 29). Every stage whose result is as wide as the output capacity —
+# the head look-ups, the representative-row gathers, the select, mask
+# and transpose of the reduced meters — runs in blocks of this many
+# segments under a trip count the device takes from the live segment
+# count, so a stash that is 4% live pays for 4% of its rows. A power of
+# two; a capacity below it is one block, one it does not divide is
+# padded inside and cut statically. Read on the v5e at 8,192 / 16,384 /
+# 32,768: the post-sort phase of a 2^22 + 2^20-row fold with 2.19M live
+# segments took 337 / 371 / 371 ms, of a 2^21 + 2^20-row one with 118k
+# 78 / 82 / 81 ms, the step's pre-reduce 3.5 / 3.6 / 4.7 ms.
+OUT_BLOCK_ROWS = 8192
 
-        ps, pm = sorted_segment_sum_max(
-            jnp.take(meters_rows, perm, axis=0), seg_id, cap, first_pos
-        )
+
+def out_block_rows(cap: int) -> int:
+    return max(1, min(OUT_BLOCK_ROWS, int(cap)))
+
+
+def out_blocks_total(cap: int) -> int:
+    """Blocks the output loop runs when every row of `cap` is live."""
+    return -(-int(cap) // out_block_rows(cap))
+
+
+def out_blocks_run(num_segments, cap: int):
+    """The output loop's trip count (traced i32): blocks that hold a live
+    segment, of `out_blocks_total(cap)`."""
+    blk = out_block_rows(cap)
+    return (jnp.minimum(num_segments, cap).astype(jnp.int32) + (blk - 1)) // blk
+
+
+def _varying_like(const, ref):
+    """`const` typed like `ref`: under shard_map state varies over the
+    mesh axes; a loop's carry must enter with the type it leaves, and
+    every `cond` branch must return that same type."""
+    vma = tuple(jax.typeof(ref).vma)
+    return lax.pcast(const, vma, to="varying") if vma else const
+
+
+def _reduce_rows(meters_rows, perm, seg_id, cap_pad: int,
+                 sum_cols: np.ndarray, max_cols: np.ndarray):
+    """The row side of the per-segment SUM / MAX of the meter rows, N
+    rows wide, in sorted order. Returns `heads(k0, seg, first_pos)` →
+    [M, K]: the reduced meters of the K consecutive segments `seg`
+    (= k0 + arange(K)); columns of absent segments are unspecified
+    (callers mask by their live-segment prefix)."""
+    m = meters_rows.shape[1]
+    if not m:
+        return lambda k0, seg, first_pos: jnp.zeros((0, seg.shape[0]), meters_rows.dtype)
+
+    is_sum = np.zeros((m,), bool)
+    is_sum[sum_cols] = True
+
+    def select(ps, pm):
+        # Full-width segment ops + per-column select, NOT subset-indexed
+        # ops: `meters_rows[:, sum_cols]` materializes a strided copy of
+        # [N, |subset|] before each op, which costs more than running the
+        # op over all M lanes and discarding the unwanted half (measured
+        # ~16% off the whole fold at 588k rows — PERF.md §7b follow-up).
         if not max_cols.size:
-            out_meters = ps.T
-        elif not sum_cols.size:
-            out_meters = pm.T
-        else:
-            is_sum = np.zeros((m,), bool)
-            is_sum[sum_cols] = True
-            out_meters = jnp.where(jnp.asarray(is_sum)[None, :], ps, pm).T
-    elif m:
-        # One row-gather moves all M meter lanes of a row at once.
-        sorted_rows = jnp.take(meters_rows, perm, axis=0)  # [N, M]
-        # (segment_max yields -inf for empty segments; the caller's
-        # seg_valid mask zeroes those columns, so no isfinite rewrite — it
-        # would also mask NaNs from genuinely corrupt meters.)
-        ps = (
-            jax.ops.segment_sum(
-                sorted_rows, seg_id, num_segments=cap, indices_are_sorted=True
-            )
-            if sum_cols.size
-            else None
+            return ps.T
+        if not sum_cols.size:
+            return pm.T
+        return jnp.where(jnp.asarray(is_sum)[None, :], ps, pm).T  # [M, K]
+
+    # One row-gather moves all M meter lanes of a row at once.
+    sorted_rows = jnp.take(meters_rows, perm, axis=0)  # [N, M]
+    if _use_pallas_reduce():
+        # On TPU both ops fuse into ONE scatter-free Pallas suffix-scan
+        # pass (segreduce_pallas.py, PERF.md §9); a block of segments
+        # then costs its own head look-ups.
+        from .segreduce_pallas import segment_heads, sorted_segment_scan
+
+        scan = sorted_segment_scan(sorted_rows, seg_id)
+        return lambda k0, seg, first_pos: select(*segment_heads(scan, first_pos, seg))
+
+    # (segment_max yields -inf for empty segments; the caller's
+    # seg_valid mask zeroes those columns, so no isfinite rewrite — it
+    # would also mask NaNs from genuinely corrupt meters.) The scatter
+    # costs per ROW; a block reads its own slice of the result.
+    ps = (
+        jax.ops.segment_sum(
+            sorted_rows, seg_id, num_segments=cap_pad, indices_are_sorted=True
         )
-        pm = (
-            jax.ops.segment_max(
-                sorted_rows, seg_id, num_segments=cap, indices_are_sorted=True
-            )
-            if max_cols.size
-            else None
+        if sum_cols.size
+        else None
+    )
+    pm = (
+        jax.ops.segment_max(
+            sorted_rows, seg_id, num_segments=cap_pad, indices_are_sorted=True
         )
-        if pm is None:
-            out_meters = ps.T
-        elif ps is None:
-            out_meters = pm.T
-        else:
-            is_sum = np.zeros((m,), bool)
-            is_sum[sum_cols] = True
-            out_meters = jnp.where(jnp.asarray(is_sum)[None, :], ps, pm).T  # [M, cap]
-    else:
-        out_meters = jnp.zeros((0, cap), meters_rows.dtype)
-    return out_meters
+        if max_cols.size
+        else None
+    )
+
+    def heads(k0, seg, first_pos):
+        cut = lambda x: None if x is None else lax.dynamic_slice_in_dim(
+            x, k0, seg.shape[0], axis=0
+        )
+        return select(cut(ps), cut(pm))
+
+    return heads
 
 
 def groupby_reduce_sorted(
@@ -239,6 +287,14 @@ def groupby_reduce_sorted(
     full keyed re-sort, then reuses this exact reduce so the two fold
     paths cannot drift.
 
+    The row side is N rows wide: head flags, segment ids, the meter rows
+    in sorted order and their suffix scan. The output side costs what
+    the LIVE segments cost: live segments are ids [0, num_seg) and a
+    prefix of the output, so one loop makes the output in blocks of
+    `OUT_BLOCK_ROWS` segments and runs `out_blocks_run(num_seg, cap)`
+    times — a trip count, not a shape: one program for every live count.
+    Rows past the last live block keep the dead row's constants.
+
     Args:
       s_slot/s_hi/s_lo: [N] u32 key lanes in ascending (slot, hi, lo)
         order, PRE-normalized — invalid rows keyed
@@ -251,6 +307,9 @@ def groupby_reduce_sorted(
     cap = int(out_capacity) if out_capacity is not None else n
     sum_cols = np.asarray(sum_cols, np.int32)
     max_cols = np.asarray(max_cols, np.int32)
+    blk = out_block_rows(cap)
+    cap_pad = out_blocks_total(cap) * blk
+    t, m = tags_t.shape[0], meters_rows.shape[1]
 
     with jax.named_scope("fold.segments"):
         head = jnp.concatenate(
@@ -270,29 +329,49 @@ def groupby_reduce_sorted(
         # [cap, num_seg) and the id sequence must stay ascending for the
         # indices_are_sorted hint below to be honest.
         seg_id = jnp.where(live_row, seg_id, n)
-
-        # First sorted position of each kept segment: seg_id is ascending by
-        # construction, so first occurrence = binary search. A segment_min
-        # here measured ~24 ms at 2M rows (r5 bisect, stage G−F) because
-        # TPU scatter reductions cost per ROW; searchsorted is O(cap·log N).
-        first_pos = jnp.searchsorted(seg_id, jnp.arange(cap, dtype=jnp.int32))
+        head_pos = _head_positions(live_head, cap_pad)
 
     with jax.named_scope("fold.reduce"):
-        out_meters = _reduce_meters(
-            meters_rows, perm, seg_id, cap, first_pos, sum_cols, max_cols
-        )
+        heads = _reduce_rows(meters_rows, perm, seg_id, cap_pad, sum_cols, max_cols)
+
+    n_live = jnp.minimum(num_seg, cap)
+
+    def block(b, out):
+        o_slot, o_hi, o_lo, o_tags, o_meters = out
+        k0 = b * blk
+        seg = k0 + jnp.arange(blk, dtype=jnp.int32)
+        live = seg < n_live
+        with jax.named_scope("fold.segments"):
+            first_pos = lax.dynamic_slice_in_dim(head_pos, k0, blk)
+        with jax.named_scope("fold.reduce"):
+            meters = jnp.where(live[None, :], heads(k0, seg, first_pos), 0)
+        with jax.named_scope("fold.compact"):
+            fp = jnp.where(live, first_pos, 0).astype(jnp.int32)
+            slot = jnp.where(live, jnp.take(s_slot, fp), jnp.uint32(SENTINEL_SLOT))
+            hi = jnp.where(live, jnp.take(s_hi, fp), 0)
+            lo = jnp.where(live, jnp.take(s_lo, fp), 0)
+            rep_orig = jnp.take(perm, fp)
+            tags = jnp.where(live[None, :], jnp.take(tags_t, rep_orig, axis=1), 0)
+            upd = lax.dynamic_update_slice
+            return (
+                upd(o_slot, slot, (k0,)),
+                upd(o_hi, hi, (k0,)),
+                upd(o_lo, lo, (k0,)),
+                upd(o_tags, tags, (0, k0)),
+                upd(o_meters, meters, (0, k0)),
+            )
 
     with jax.named_scope("fold.compact"):
-        k = jnp.arange(cap, dtype=jnp.int32)
-        seg_valid = k < jnp.minimum(num_seg, cap)
-        fp = jnp.where(seg_valid, first_pos, 0).astype(jnp.int32)
-
-        out_slot = jnp.where(seg_valid, jnp.take(s_slot, fp), jnp.uint32(SENTINEL_SLOT))
-        out_hi = jnp.where(seg_valid, jnp.take(s_hi, fp), 0)
-        out_lo = jnp.where(seg_valid, jnp.take(s_lo, fp), 0)
-        rep_orig = jnp.take(perm, fp)
-        out_tags = jnp.where(seg_valid[None, :], jnp.take(tags_t, rep_orig, axis=1), 0)
-        out_meters = jnp.where(seg_valid[None, :], out_meters, 0)
+        dead = (
+            jnp.full((cap_pad,), SENTINEL_SLOT, dtype=s_slot.dtype),
+            jnp.zeros((cap_pad,), s_hi.dtype),
+            jnp.zeros((cap_pad,), s_lo.dtype),
+            jnp.zeros((t, cap_pad), tags_t.dtype),
+            jnp.zeros((m, cap_pad), meters_rows.dtype),
+        )
+        dead = tuple(_varying_like(x, s_slot) for x in dead)
+    out = lax.fori_loop(0, out_blocks_run(num_seg, cap), block, dead)
+    out_slot, out_hi, out_lo, out_tags, out_meters = (x[..., :cap] for x in out)
 
     return Grouped(
         slot=out_slot,
@@ -300,9 +379,24 @@ def groupby_reduce_sorted(
         key_lo=out_lo,
         tags=out_tags,
         meters=out_meters,
-        seg_valid=seg_valid,
+        seg_valid=jnp.arange(cap, dtype=jnp.int32) < n_live,
         num_segments=num_seg,
     )
+
+
+def _head_positions(live_head, cap_pad: int):
+    """First sorted position of every segment, [cap_pad] i32; what it
+    says of an absent segment is unspecified (callers mask). Live
+    segments are numbered in sorted order, so the live heads' positions
+    in ascending order ARE their first positions: one single-key sort of
+    N i32, dead and non-head rows keyed behind. A binary search of
+    `seg_id` for a block's ids was the other way; on the v5e it cost
+    2.6–3 ms for every block of 16,384 segments, and this whole phase
+    721 ms against 371 with the sort at 5.2M rows with 134 such blocks
+    live (PERF.md §6, PR 29)."""
+    n = live_head.shape[0]
+    pos = lax.sort(jnp.where(live_head, jnp.arange(n, dtype=jnp.int32), n))
+    return pos[:cap_pad] if n >= cap_pad else jnp.pad(pos, (0, cap_pad - n), constant_values=n)
 
 
 # ---------------------------------------------------------------------------

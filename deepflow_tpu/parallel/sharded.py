@@ -307,20 +307,20 @@ class ShardedPipeline:
             stash1 = jax.tree.map(lambda x: x[0], stash)
             acc1 = jax.tree.map(lambda x: x[0], acc)
             if merge:
-                new_stash, new_acc, rows = _merge_fold_impl(
+                new_stash, new_acc, lanes = _merge_fold_impl(
                     stash1, acc1, hi_window, sum_cols, max_cols
                 )
             else:
                 # full mode ignores the span bound (the managers never
                 # span-fold in full mode — host-side guard)
-                new_stash, new_acc, rows = _fold_counted_impl(
+                new_stash, new_acc, lanes = _fold_counted_impl(
                     stash1, acc1, sum_cols, max_cols
                 )
             expand = lambda x: x[None]
             return (
                 jax.tree.map(expand, new_stash),
                 jax.tree.map(expand, new_acc),
-                rows[None],
+                lanes[:1],  # fold_rows; the trip count has no lane here
             )
 
         pspec = P(self.axes)

@@ -123,7 +123,7 @@ def test_merge_fold_bitexact_vs_full_sort_fuzz():
         np.testing.assert_array_equal(np.asarray(fa.slot), np.asarray(ma.slot))
         saw_overflow += int(fs.dropped_overflow) > 0
         # fold_rows counts the live acc rows the merge sorted
-        assert int(rows) == int((np.asarray(acc.slot) != SENT).sum())
+        assert int(rows[0]) == int((np.asarray(acc.slot) != SENT).sum())
     assert saw_overflow >= 3, "fuzz never exercised the overflow stance"
 
 
@@ -159,7 +159,51 @@ def test_merge_fold_span_bounded_matches_masked_oracle():
             np.asarray(sa.meters).view(np.uint32),
             np.asarray(acc.meters).view(np.uint32),
         )
-        assert int(rows) == int((sl < hi).sum())
+        assert int(rows[0]) == int((sl < hi).sum())
+
+
+@pytest.mark.parametrize("live", [15, 16, 17, 32])
+def test_merge_fold_equals_full_fold_at_an_output_block_boundary(monkeypatch, live):
+    """PR 29: both folds make their output in blocks of OUT_BLOCK_ROWS
+    segments under a trip count. A stash live up to a block boundary,
+    one short of it, one past it and full, at 8-row blocks: full ≡ merge
+    bit for bit, and both report the same trip count."""
+    from deepflow_tpu.aggregator import stash as stash_mod
+    from deepflow_tpu.ops import segment
+
+    # the module's jitted folds read the constant when they trace: none
+    # traced before may serve here, none traced here may serve later
+    jitted = (stash_mod.collector_fold, stash_mod.collector_fold_counted,
+              stash_mod.collector_merge_fold)
+    for f in jitted:
+        f.clear_cache()
+    monkeypatch.setattr(segment, "OUT_BLOCK_ROWS", 8)
+    rng = np.random.default_rng(live)
+    scap, acap = 32, 27
+    state = stash_init(scap, TINY_TAGS, TINY_METER)
+    seed = _rand_acc(rng, acap, 0)
+    k = np.arange(acap, dtype=np.uint32)
+    seed = dataclasses.replace(
+        seed, slot=jnp.asarray(np.where(k < min(live, acap), 2, SENT).astype(np.uint32)),
+        key_hi=jnp.asarray(k), key_lo=jnp.asarray(k * 7 + 1),
+    )
+    state, _ = stash_fold(state, seed, TINY_METER)
+    more = max(0, live - acap)  # the second ring brings what is still missing
+    k2 = np.arange(acap, dtype=np.uint32)
+    acc = dataclasses.replace(
+        _rand_acc(rng, acap, acap),
+        slot=jnp.asarray(np.where(k2 < more + 6, 2, SENT).astype(np.uint32)),
+        # `more` new keys behind the first ring's, and six it already holds
+        key_hi=jnp.asarray(np.where(k2 < more, acap + k2, k2 - more).astype(np.uint32)),
+    )
+    acc = dataclasses.replace(acc, key_lo=acc.key_hi * 7 + 1)
+    fs, _, f_lanes = stash_fold_counted(_clone(state), _clone(acc), TINY_METER)
+    ms, _, m_lanes = stash_merge_fold(_clone(state), _clone(acc), TINY_METER)
+    _assert_state_equal(fs, ms, f"live {live}")
+    assert int(np.asarray(fs.valid).sum()) == live
+    assert int(f_lanes[1]) == int(m_lanes[1]) == -(-live // 8)
+    for f in jitted:
+        f.clear_cache()
 
 
 def test_merge_fold_scatter_order_variant(monkeypatch):
@@ -460,4 +504,4 @@ def test_stash_fold_counted_matches_plain_fold():
     _assert_state_equal(fs, cs, "counted fold")
     live_stash = int(np.asarray(state.valid).sum())
     live_acc = int((np.asarray(acc.slot) != SENT).sum())
-    assert int(rows) == live_stash + live_acc
+    assert int(rows[0]) == live_stash + live_acc

@@ -120,6 +120,7 @@ def test_counter_block_layout_constants():
     from deepflow_tpu.aggregator.window import (
         CB_CASCADE_ROWS,
         CB_CASCADE_SHED,
+        CB_FOLD_BLOCKS,
         CB_FOLD_ROWS,
         CB_SKETCH_ROWS,
         CB_SKETCH_SHED,
@@ -138,9 +139,10 @@ def test_counter_block_layout_constants():
     # appended the live read plane's snapshot_reads/snapshot_bytes
     # lanes, ISSUE 10; v7 appended the pooled sketch memory's
     # sketch_pool_spill/sketch_pool_occ/sketch_promotions lanes,
-    # ISSUE 20)
-    assert CB_VERSION == 0 and CB_LEN == 21
-    assert COUNTER_BLOCK_VERSION == 7
+    # ISSUE 20; v8 appended fold_blocks, the trip count of the last
+    # fold's output loop, PR 29)
+    assert CB_VERSION == 0 and CB_LEN == 22
+    assert COUNTER_BLOCK_VERSION == 8
     assert CB_STASH_OCCUPANCY == 7
     assert CB_FEEDER_SHED == 10
     assert CB_FOLD_ROWS == 11
@@ -153,6 +155,7 @@ def test_counter_block_layout_constants():
     assert CB_SKETCH_POOL_SPILL == 18
     assert CB_SKETCH_POOL_OCC == 19
     assert CB_SKETCH_PROMOTIONS == 20
+    assert CB_FOLD_BLOCKS == 21
     # the documented field-name table mirrors the index constants
     assert len(CB_FIELDS) == CB_LEN
     assert CB_FIELDS[CB_VERSION] == "version"
@@ -160,6 +163,7 @@ def test_counter_block_layout_constants():
     assert CB_FIELDS[CB_RING_FILL] == "ring_fill"
     assert CB_FIELDS[CB_FEEDER_SHED] == "feeder_shed"
     assert CB_FIELDS[CB_FOLD_ROWS] == "fold_rows"
+    assert CB_FIELDS[CB_FOLD_BLOCKS] == "fold_blocks"
     assert CB_FIELDS[CB_SKETCH_ROWS] == "sketch_rows"
     assert CB_FIELDS[CB_SKETCH_SHED] == "sketch_shed"
     assert CB_FIELDS[CB_CASCADE_ROWS] == "cascade_rows"

@@ -48,6 +48,7 @@ NEW_LAYERS = (
     "flush.fetched_rows_per_live_row",  # PR 27
     "stash.live_share", "step.doc_rows_per_record",  # PR 28
     "flush.docs_per_window", "flush.fetch_ms_per_window",
+    "fold.live_block_share",  # PR 29
 )
 
 
