@@ -150,7 +150,7 @@ def _ring_fold_impl(tier: StashState, acc, lanes, sum_cols_t, max_cols_t,
     ring's [A] rows sort and rank-merge against the standing run
     (stash._sorted_merge_reduce, the merge-fold body) instead of a
     second full [S+A] 3-key sort. Bit-exact vs the full-sort path
-    (same reduce, same overflow stance); A/B'd in bench/foldbench.py."""
+    (same reduce, same overflow stance)."""
     prev_dropped = tier.dropped_overflow
     if shared_sort:
         valid = _acc_valid(acc)

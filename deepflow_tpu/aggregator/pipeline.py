@@ -61,7 +61,7 @@ def _doc_fingerprint(doc_tags, with_excess: bool = False):
     """(hi, lo[, excess]) over a [T, N] doc tag matrix via the packed-word
     plan: the key columns are bin-packed into ~22 u32 words built once
     (datamodel/code.py), and both murmur seeds fold the words instead
-    of 32 raw columns (PERF.md §9d). Row extraction from the
+    of 32 raw columns. Row extraction from the
     column-major matrix is free (contiguous [N] slices).
 
     With `with_excess`, also returns the packing-guard excess word
@@ -84,7 +84,7 @@ def batch_prereduce(tags, meters, valid, interval, cap, sum_cols, max_cols):
     full tag fingerprint (incl. timestamp) and reduce meters. Exact:
     identical raw tag rows produce identical doc rows in every fanout
     lane, and the lanes' meter transforms are column permutations/
-    copies, which commute with per-column sum/max (PERF.md §7c). This
+    copies, which commute with per-column sum/max. This
     collapses the dup factor (10k-tuple rollup workloads repeat keys
     within a batch) so the fold sorts ~1 row/record instead of 4.
     Returns (tags, meters [cap, M], valid, dropped) — rows beyond `cap`
@@ -96,8 +96,8 @@ def batch_prereduce(tags, meters, valid, interval, cap, sum_cols, max_cols):
     cols = [jnp.asarray(tags[k], jnp.uint32) for k in names]
     tags_t = jnp.stack(cols)
     # fingerprint the PACKED words, not the raw columns: ~23 fold rounds
-    # instead of 37 per seed, built once for both seeds (PERF.md §9d;
-    # the [T, N] stack stays only as the groupby payload — r5 bisect V2
+    # instead of 37 per seed, built once for both seeds (the
+    # [T, N] stack stays only as the groupby payload — r5 bisect V2
     # already showed hashing through it wastes a materialization)
     hi, lo = fingerprint64_words(pack_tag_words(tags, RAW_TAG_PACK, jnp))
     slot = jnp.asarray(tags["timestamp"], jnp.uint32) // jnp.uint32(interval)
@@ -183,14 +183,13 @@ def make_ingest_step(fanout_config: FanoutConfig, interval: int = 1, app: bool =
             return stash, acc
     else:
         meter_ix = meter_schema.index
-        # one-pass knobs captured at BUILD time (ISSUE 17): the caller
+        # one-pass knob captured at BUILD time (ISSUE 17): the caller
         # jits this closure fresh per plane instance, so capturing here
         # pins the path for the closure's whole life — a retrace on a
         # new bucket shape cannot silently flip it mid-stream
-        from ..ops.segment import _use_fused_sketch, _use_shared_sort
+        from ..ops.segment import _use_shared_sort
 
         shared_sort = _use_shared_sort()
-        fused_sketch = _use_fused_sketch()
 
         def append(stash, acc, offset, sk, tags, meters, valid, start_window):
             stash, acc, r_tags, r_meters, r_valid = _base_append(
@@ -207,7 +206,7 @@ def make_ingest_step(fanout_config: FanoutConfig, interval: int = 1, app: bool =
                 sk, sketch_config.hist,
                 window=ts // jnp.uint32(interval), valid=r_valid,
                 base_w=base_w, close_w=close_w,
-                shared_sort=shared_sort, fused_sketch=fused_sketch, **inp,
+                shared_sort=shared_sort, **inp,
             )
             return stash, acc, sk
 
@@ -249,7 +248,7 @@ class PipelineConfig:
                 )
 
 
-# Back-compat alias (bench/entry scripts predate the L7 pipeline).
+# The name from before the L7 pipeline; tests and the verify skill use it.
 L4PipelineConfig = PipelineConfig
 
 
@@ -376,12 +375,11 @@ class RollupPipeline:
         sketch_cfg = self.config.window.sketch
         m_ix = m.index
 
-        # one-pass knobs captured at step-BUILD time (ISSUE 17) — same
+        # one-pass knob captured at step-BUILD time (ISSUE 17) — same
         # retrace-stability stance as make_ingest_step's sketch append
-        from ..ops.segment import _use_fused_sketch, _use_shared_sort
+        from ..ops.segment import _use_shared_sort
 
         shared_sort = _use_shared_sort()
-        fused_sketch = _use_fused_sketch()
 
         def _sketch(sk, tags, meters, valid, start_window):
             """Per-window plane update from the RAW flow rows (ISSUE 8):
@@ -399,7 +397,7 @@ class RollupPipeline:
                 sk, sketch_cfg.hist,
                 window=ts // jnp.uint32(interval), valid=valid,
                 base_w=base_w, close_w=close_w,
-                shared_sort=shared_sort, fused_sketch=fused_sketch, **inp,
+                shared_sort=shared_sort, **inp,
             )
 
         def step(acc, offset, start_window, stash_valid, stash_evict,
@@ -706,9 +704,8 @@ class RollupPipeline:
             default_collector.deregister(src)
 
     def telemetry(self) -> dict:
-        """JSON-able snapshot for bench records: the counter-block-backed
-        counters plus the per-stage span summary (BENCH files carry
-        stage attribution — PERF.md §13) and, since ISSUE 12, the
+        """JSON-able snapshot: the counter-block-backed
+        counters plus the per-stage span summary and, since ISSUE 12, the
         device profile record (per-plane HBM bytes + step census, no
         analysis — absence-tolerant consumers)."""
         return {
@@ -748,7 +745,7 @@ class DualGranularityPipeline:
     device-side fold of closed 1s windows — so dual-granularity traffic
     costs one fused dispatch per batch plus a per-advance tier fold.
     The old double-ingest survives as `DoubleIngestPipeline`, kept as
-    the conformance oracle and the cascadebench A/B baseline.
+    the conformance oracle.
 
     ingest() returns (flags, DocBatch) pairs: PER_SECOND_METRICS for 1s
     windows, NONE for 1m — exactly what encode_docbatch/table routing
@@ -863,9 +860,9 @@ class DoubleIngestPipeline:
     """The pre-ISSUE-9 dual-granularity implementation: a full second
     device ingest into a parallel minute pipeline. Kept ONLY as the
     conformance oracle (tests/test_cascade.py pins cascade 1m meters
-    bit-exact against it) and the cascadebench A/B baseline — new code
-    wants `DualGranularityPipeline`, which produces the same
-    (flags, DocBatch) stream from one dispatch per batch."""
+    bit-exact against it) — new code wants `DualGranularityPipeline`,
+    which produces the same (flags, DocBatch) stream from one dispatch
+    per batch."""
 
     def __init__(
         self,

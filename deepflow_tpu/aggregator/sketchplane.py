@@ -61,7 +61,7 @@ from ..ops.histogram import (
     loghist_coarsen_bin,
     loghist_expand,
 )
-from ..ops.segment import _use_fused_sketch, _use_shared_sort
+from ..ops.segment import _use_shared_sort
 from ..ops.tdigest import tdigest_compress, tdigest_quantile
 from ..ops.topk import (
     _apply_challengers,
@@ -164,8 +164,7 @@ class SketchConfig:
 
     hll_precision=14 meets the <1% north-star cardinality bound
     (~0.81% standard error); the defaults here are sized for the
-    many-windows-resident case — bench/sketchbench.py carries the
-    measured error/recall for the production settings."""
+    many-windows-resident case."""
 
     num_groups: int = 16  # service rows (HLL + histogram group axis)
     hll_precision: int = 12
@@ -792,7 +791,6 @@ def _scatter_rows(
     id_a,
     id_b,
     presorted=None,
-    fused_sketch: bool = False,
 ) -> SketchState:
     """Fold one phase's rows into their ring slots (claiming empties).
     Callers guarantee the phase's window span is < R wide, so slots are
@@ -809,9 +807,7 @@ def _scatter_rows(
     whose folds are idempotent or count-shaped (win claim, count, HLL
     register max, histogram) stay on the original row order — a run
     spans one flow key, not one client, so they cannot ride the run
-    dedup. `fused_sketch` additionally routes HLL + count-min + the
-    challenger scan through the single-pass Pallas kernel
-    (ops/sketch_pallas.py) when the shapes support it."""
+    dedup."""
     r = sk.ring
     g, m = sk.hll.shape[1], sk.hll.shape[2]
     d_cms, w_cms = sk.cms.shape[1], sk.cms.shape[2]
@@ -904,48 +900,31 @@ def _scatter_rows(
     s_ib = jnp.asarray(id_b, jnp.uint32)[s_pos]
     rs = row_slots(s_hi, s_lo, d_cms, w_cms)  # [D, N] in [0, D*W)
 
-    fused_done = False
-    if fused_sketch:
-        from ..ops.sketch_pallas import fused_sketch_guard, sketch_update_fused
-
-        ok = fused_sketch_guard(
-            n, r, g, m, d_cms, w_cms, d_tk, sk.tk_votes.shape[2]
+    hll = sk.hll.at[gslot, gid, reg].max(rho, mode="drop")
+    # one add per run HEAD (carrying the run's summed weight) instead of
+    # per row: non-head rows add 0 at a live cell — a no-op — so cell
+    # totals stay bit-identical to the per-row oracle while the
+    # scatter's live writes drop to one per (window, key) run. Head
+    # slots are always in-range (window % R), so no index masking is
+    # needed: fully-unmasked runs carry w_head == 0.
+    flat = s_slot[None, :] * (d_cms * w_cms) + rs
+    cms = (
+        sk.cms.reshape(-1)
+        .at[flat.reshape(-1)]
+        .add(
+            jnp.broadcast_to(w_head[None, :], flat.shape).reshape(-1),
+            mode="drop",
         )
-        if ok:
-            hll, cms, challengers = sketch_update_fused(
-                sk.hll, sk.cms, tk_shape=(d_tk, sk.tk_votes.shape[2]),
-                s_slot=s_slot, s_gid=gid[s_pos], s_reg=reg[s_pos],
-                s_rho=rho[s_pos], s_mask=s_mask, w_head=w_head, rw=rw,
-                cms_slots=rs, s_hi=s_hi, s_lo=s_lo, s_ia=s_ia, s_ib=s_ib,
-            )
-            fused_done = True
-    if not fused_done:
-        hll = sk.hll.at[gslot, gid, reg].max(rho, mode="drop")
-        # one add per run HEAD (carrying the run's summed weight)
-        # instead of per row: non-head rows add 0 at a live cell — a
-        # no-op — so cell totals stay bit-identical to the per-row
-        # oracle while the scatter's live writes drop to one per
-        # (window, key) run. Head slots are always in-range (window
-        # % R), so no index masking is needed: fully-unmasked runs
-        # carry w_head == 0.
-        flat = s_slot[None, :] * (d_cms * w_cms) + rs
-        cms = (
-            sk.cms.reshape(-1)
-            .at[flat.reshape(-1)]
-            .add(
-                jnp.broadcast_to(w_head[None, :], flat.shape).reshape(-1),
-                mode="drop",
-            )
-            .reshape(r, d_cms, w_cms)
+        .reshape(r, d_cms, w_cms)
+    )
+    challengers = (
+        topk_challengers_presorted(
+            s_slot, s_hi, s_lo, s_ia, s_ib, rw, s_mask,
+            r, d_tk, sk.tk_votes.shape[2],
         )
-        challengers = (
-            topk_challengers_presorted(
-                s_slot, s_hi, s_lo, s_ia, s_ib, rw, s_mask,
-                r, d_tk, sk.tk_votes.shape[2],
-            )
-            if d_tk
-            else []
-        )
+        if d_tk
+        else []
+    )
     tkv, tkh, tkl, tia, tib = (
         _apply_challengers(lanes, challengers) if d_tk else lanes
     )
@@ -974,7 +953,6 @@ def sketch_plane_step(
     id_a,
     id_b,
     shared_sort: bool | None = None,
-    fused_sketch: bool | None = None,
 ) -> SketchState:
     """One batch through the plane, in window order (traced):
 
@@ -1003,21 +981,11 @@ def sketch_plane_step(
     both phases consume it — the per-hash-row fresh sorts inside
     `topk_update` (2 phases × topk_rows sorts) collapse into this one,
     and the count-min scatter dedups to run heads. Bit-exact vs the
-    multi-sort path (tests/test_sketch_onepass.py). `fused_sketch`
-    (default: DEEPFLOW_FUSED_SKETCH, OFF until on-chip numbers) further
-    collapses the sorted-order folds into the single-pass Pallas
-    kernel. Both knobs resolve at TRACE time — callers whose jitted
-    step outlives an env flip must thread them as static arguments
-    (aggregator/window.py does)."""
+    multi-sort path (tests/test_sketch_onepass.py). The knob resolves
+    at TRACE time — callers whose jitted step outlives an env flip must
+    thread it as a static argument (aggregator/window.py does)."""
     if shared_sort is None:
         shared_sort = _use_shared_sort()
-    if fused_sketch is None:
-        fused_sketch = _use_fused_sketch()
-    if _pool_mode(sk):
-        # the Pallas kernel folds into per-ring-slot slabs; the pooled
-        # arenas route through plain XLA scatters until the kernel
-        # learns the dual-arena layout (documented in PERF.md §28)
-        fused_sketch = False
     r = sk.ring
     window = jnp.asarray(window, jnp.uint32)
     base_w = jnp.asarray(base_w, jnp.uint32)
@@ -1060,10 +1028,9 @@ def sketch_plane_step(
 
     args = (group, client_hi, client_lo, key_hi, key_lo, weight, rtt,
             rtt_valid, id_a, id_b)
-    kw = dict(presorted=presorted, fused_sketch=fused_sketch)
-    sk = _scatter_rows(sk, spec, in_a, window, *args, **kw)
+    sk = _scatter_rows(sk, spec, in_a, window, *args, presorted=presorted)
     sk = sketch_close(sk, close_w)
-    sk = _scatter_rows(sk, spec, in_c, window, *args, **kw)
+    sk = _scatter_rows(sk, spec, in_c, window, *args, presorted=presorted)
     if _pool_mode(sk):
         sk = _maybe_promote(sk)
     folded = (jnp.sum(in_a) + jnp.sum(in_c)).astype(jnp.uint32)

@@ -230,7 +230,7 @@ def stash_fold(
 # Incremental merge-fold (ISSUE 5). The full-sort fold above re-sorts
 # the whole [S+A] stash+accumulator concatenation on every trigger even
 # though the stash is ALREADY sorted by (slot, key) — the fold-dominated
-# windowed advance (PERF.md §12 drain_ms) pays O((S+A) log(S+A)) 3-key
+# windowed advance pays O((S+A) log(S+A)) 3-key
 # compare-exchange for state it holds sorted. The merge-fold sorts only
 # the accumulator's [A] rows, rank-merges them against the stash
 # (ops/segment.merge_ranks — searchsorted-based merge ranks, then one
@@ -479,8 +479,8 @@ def stash_flush(state: StashState, window_idx) -> tuple[StashState, dict]:
 
     This is the per-window oracle shape; the production drain is
     `stash_flush_range` (ONE device call + ONE packed fetch for every
-    closed window at once — PERF.md §8's per-fetch latency made the
-    per-window loop the windowed path's floor).
+    closed window at once — a fetch per window made the per-window
+    loop the windowed path's floor).
     """
     window_idx = jnp.asarray(window_idx, dtype=jnp.uint32)
     mask = state.valid & (state.slot == window_idx)

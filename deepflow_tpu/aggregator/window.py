@@ -48,7 +48,7 @@ import numpy as np
 from .. import chaos
 from ..datamodel.schema import FLOW_METER, TAG_SCHEMA, MeterSchema, TagSchema
 from ..ops.hashing import fingerprint64
-from ..ops.segment import _use_fused_sketch, _use_shared_sort, out_blocks_total
+from ..ops.segment import _use_shared_sort, out_blocks_total
 from .cascade import CascadeConfig, TierCascade, TierFlush
 from .sketchplane import (
     SENTINEL_WIN,
@@ -465,14 +465,12 @@ def sketch_span_bounds(start_window, ts, valid, *, interval: int, delay: int):
 @partial(
     jax.jit,
     donate_argnums=(0, 9),
-    static_argnames=("interval", "delay", "ix", "spec", "shared_sort",
-                     "fused_sketch"),
+    static_argnames=("interval", "delay", "ix", "spec", "shared_sort"),
 )
 def _raw_append_step_sk(acc, offset, start_window, stash_valid, stash_evict,
                         feeder_shed, fold_lanes, casc_lanes, snap_lanes, sk,
                         timestamp, key_hi, key_lo, tags, meters, valid,
-                        *, interval, delay, ix, spec, shared_sort=True,
-                        fused_sketch=False):
+                        *, interval, delay, ix, spec, shared_sort=True):
     """`_raw_append_step` with the per-window sketch plane fused in
     (ISSUE 8): the SAME jit dispatch updates HLL/CMS/histogram/top-K
     slots for every accepted row — key identity is the caller's doc
@@ -481,10 +479,10 @@ def _raw_append_step_sk(acc, offset, start_window, stash_valid, stash_evict,
     Zero new fetches: the plane's closed blocks leave the device via
     the advance drain, not here.
 
-    `shared_sort`/`fused_sketch` (ISSUE 17) are STATIC: this step is
+    `shared_sort` (ISSUE 17) is STATIC: this step is
     module-level-jitted, so an env flip after the first trace would be
-    invisible if the plane read the knobs at trace time — the caller
-    (WindowManager.merge_batch) reads them per dispatch instead and a
+    invisible if the plane read the knob at trace time — the caller
+    (WindowManager.merge_batch) reads it per dispatch instead and a
     flip recompiles (counted by the jit monitor like any retrace)."""
     ts = jnp.asarray(timestamp, dtype=jnp.uint32)
     valid_b = jnp.asarray(valid)
@@ -500,7 +498,7 @@ def _raw_append_step_sk(acc, offset, start_window, stash_valid, stash_evict,
         sk, spec,
         window=ts // jnp.uint32(interval), valid=valid_b,
         base_w=base_w, close_w=close_w,
-        shared_sort=shared_sort, fused_sketch=fused_sketch, **inp,
+        shared_sort=shared_sort, **inp,
     )
     # pool lanes (CB v7): occupancy gauges sum zero-size arrays in slab
     # mode, so the lanes are 0 there without a mode branch
@@ -1484,11 +1482,10 @@ class WindowManager:
                     timestamp, key_hi, key_lo, tags, meters, valid,
                     interval=interval, delay=self.config.delay,
                     ix=self._sketch_ix, spec=self.config.sketch.hist,
-                    # env knobs read at DISPATCH time (static argnames —
+                    # env knob read at DISPATCH time (a static argname —
                     # the step is module-level-jitted, so a flip must
                     # recompile rather than silently keep the old path)
                     shared_sort=_use_shared_sort(),
-                    fused_sketch=_use_fused_sketch(),
                 )
         else:
             def dispatch(acc, offset, start_window):
@@ -1830,7 +1827,7 @@ class WindowManager:
         out.update(
             {
                 # scalar device reductions fetched on demand — never the
-                # full valid plane (PERF.md §8); live values, unlike the
+                # full valid plane; live values, unlike the
                 # dispatch-time block cache above. Through _fetch: probe
                 # syncs must show up in the transfer accounting too.
                 "drop_overflow": int(self._fetch(self.state.dropped_overflow)),

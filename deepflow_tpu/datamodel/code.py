@@ -188,7 +188,7 @@ class MeterId(enum.IntEnum):
 # (25-37 u32 lanes × 2 seeds); most of those columns carry far fewer
 # than 32 meaningful bits (flags, enums, ports, i16 EPC ids). These
 # helpers bin-pack the narrow columns into full u32 words once, so the
-# fold runs over ~22 words instead of ~37 (PERF.md §9d). Packing is
+# fold runs over ~22 words instead of ~37. Packing is
 # injective for in-range values: each field gets a disjoint bit span.
 # Values wider than their declared span would alias, so the excess bits
 # (value >> width) are rotated per-field and XOR-folded into one extra

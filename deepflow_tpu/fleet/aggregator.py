@@ -31,7 +31,7 @@ REST `GET /v1/fleet/{health,hosts,skew}` pane:
     consecutive frames' cumulative counters.
 
 Aggregator work per tick is O(hosts × lanes), independent of how many
-raw samples each host ingested — `bench/fleetbench.py` pins that.
+raw samples each host ingested.
 """
 
 from __future__ import annotations

@@ -65,21 +65,13 @@ def fingerprint64(tags, xp=jnp):
     """[N, T] u32 tag matrix → (hi, lo) pair of [N] u32 fingerprints.
 
     Unrolled over the (static) column count; each step is a handful of VPU
-    ops on [N] vectors. Device callers on the hot path should prefer
-    `fingerprint64_t` — extracting columns from a row-major [N, T] device
-    array is a strided gather on TPU (~100x the cost of the hash itself).
+    ops on [N] vectors. Device callers on the hot path use
+    `fingerprint64_words` on column-major words — extracting columns from
+    a row-major [N, T] device array is a strided gather on TPU (~100x the
+    cost of the hash itself).
     """
     tags = xp.asarray(tags, dtype=xp.uint32)
     cols = [tags[:, j] for j in range(tags.shape[1])]
-    return _fold(cols, SEED_HI, xp), _fold(cols, SEED_LO, xp)
-
-
-def fingerprint64_t(tags_t, xp=jnp):
-    """Column-major twin: [T, N] u32 → (hi, lo) [N] u32. Identical hash
-    values to `fingerprint64` on the transposed matrix; each column is a
-    contiguous [N] vector so the fold stays pure VPU work."""
-    tags_t = xp.asarray(tags_t, dtype=xp.uint32)
-    cols = [tags_t[j] for j in range(tags_t.shape[0])]
     return _fold(cols, SEED_HI, xp), _fold(cols, SEED_LO, xp)
 
 
@@ -87,7 +79,7 @@ def fingerprint64_words(words, xp=jnp):
     """Fold a pre-packed word list (datamodel.code.pack_tag_words) into
     the (hi, lo) pair. The packed representation covers the same key
     bits in ~40% fewer fold rounds than the raw column fold — the hot
-    paths build the words ONCE and feed both seeds (PERF.md §9d).
+    paths build the words ONCE and feed both seeds.
     Hash VALUES differ from fingerprint64 on the raw columns; only
     within-path consistency matters (every producer of a given key
     space goes through the same packing plan)."""

@@ -58,7 +58,7 @@ SENTINEL_SLOT = np.uint32(0xFFFFFFFF)
 
 def _use_pallas_reduce() -> bool:
     """The Pallas suffix-scan reduce replaces the per-row scatter
-    segment ops on TPU (PERF.md §9); XLA ops stay for CPU (fast there,
+    segment ops on TPU; XLA ops stay for CPU (fast there,
     and the conformance suite pins the two paths equal).
     DEEPFLOW_SEGREDUCE=pallas|xla overrides."""
     mode = os.environ.get("DEEPFLOW_SEGREDUCE", "auto")
@@ -91,16 +91,6 @@ def _use_shared_sort() -> bool:
     tests/test_sketch_onepass.py), so it defaults ON.
     DEEPFLOW_SHARED_SORT=0 restores the per-consumer sorts for A/B."""
     return os.environ.get("DEEPFLOW_SHARED_SORT", "1") != "0"
-
-
-def _use_fused_sketch() -> bool:
-    """On the shared-sort path, run the HLL/CMS/top-K challenger update
-    as ONE Pallas pass over the sorted batch (ops/sketch_pallas.py)
-    instead of the XLA presorted path. Default OFF until on-chip
-    numbers land (the §15 flip-the-default convention); interpret-mode
-    parity is pinned on CPU either way. DEEPFLOW_FUSED_SKETCH=1
-    enables."""
-    return os.environ.get("DEEPFLOW_FUSED_SKETCH", "0") == "1"
 
 
 _U32_MAX = np.uint32(0xFFFFFFFF)
@@ -224,7 +214,7 @@ def _reduce_rows(meters_rows, perm, seg_id, cap_pad: int,
         # ops: `meters_rows[:, sum_cols]` materializes a strided copy of
         # [N, |subset|] before each op, which costs more than running the
         # op over all M lanes and discarding the unwanted half (measured
-        # ~16% off the whole fold at 588k rows — PERF.md §7b follow-up).
+        # ~16% off the whole fold at 588k rows).
         if not max_cols.size:
             return ps.T
         if not sum_cols.size:
@@ -235,7 +225,7 @@ def _reduce_rows(meters_rows, perm, seg_id, cap_pad: int,
     sorted_rows = jnp.take(meters_rows, perm, axis=0)  # [N, M]
     if _use_pallas_reduce():
         # On TPU both ops fuse into ONE scatter-free Pallas suffix-scan
-        # pass (segreduce_pallas.py, PERF.md §9); a block of segments
+        # pass (segreduce_pallas.py); a block of segments
         # then costs its own head look-ups.
         from .segreduce_pallas import segment_heads, sorted_segment_scan
 

@@ -1,6 +1,6 @@
 """Scatter-free sorted segmented sum+max — the Pallas hot-loop kernel.
 
-The r5 bisection (PERF.md §9) showed TPU segment reductions pay a
+The r5 bisection on the chip showed TPU segment reductions pay a
 per-ROW scatter cost regardless of lane width: at 2M rows,
 `segment_sum` ≈ 10 ms, `segment_max` ≈ 29 ms — 39 ms of the 82 ms
 append. This kernel replaces both with one streaming pass:

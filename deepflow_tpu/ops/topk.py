@@ -73,10 +73,9 @@ def bucket_cols(key_hi, key_lo, row: int, cols: int, xp=jnp):
 
 
 def _apply_challengers(lanes, challengers):
-    """Weighted-MJRTY vote epilogue, shared by every update path (the
-    fresh-sort oracle, the shared-sort presorted path, and the fused
-    Pallas kernel — ops/sketch_pallas.py): apply, per hash row, ONE
-    challenger per flat [R*C] bucket. `challengers` is a list of
+    """Weighted-MJRTY vote epilogue, shared by both update paths (the
+    fresh-sort oracle and the shared-sort presorted path): apply, per
+    hash row, ONE challenger per flat [R*C] bucket. `challengers` is a list of
     (got, h_hi, h_lo, h_ia, h_ib, hw) tuples, one per hash row, with hw
     already clamped ≥ 0 and 0 wherever got is False."""
     votes, l_hi, l_lo, l_ia, l_ib = lanes

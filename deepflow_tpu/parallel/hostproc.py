@@ -1,11 +1,10 @@
 """Process-lifecycle helpers for multi-host CPU runs (ISSUE 14). For
-the CPU mesh tests and bench only: these children name the CPU platform
+the CPU mesh tests only: these children name the CPU platform
 and never take a chip (one process holds a chip at a time, so a child
 that needed it could not be started from a parent that has touched JAX).
 
-Shared by tests/mesh_harness.py and bench/mesh_scaling.py — the two
-drivers that spawn real N-process `jax.distributed` deployments. Both
-need the same two tricky pieces, and a fix to either must land once:
+Used by tests/mesh_harness.py, the driver that spawns real N-process
+`jax.distributed` deployments. Its two tricky pieces live here:
 
 * **clean_cpu_env** — the dryrun_multichip stance: name the CPU
   platform BEFORE any jax import in the child and pin the virtual

@@ -127,7 +127,7 @@ class ShardedConfig:
     # batches accumulated per device between sort+reduce folds
     # (same amortization as WindowConfig.accum_batches)
     accum_batches: int = 8
-    # per-device batch-local pre-reduce before fanout (PERF.md §7);
+    # per-device batch-local pre-reduce before fanout;
     # None = off. Bounds each batch's unique raw keys; overflow is shed
     # and counted in the device stash's overflow counter.
     batch_unique_cap: int | None = None
@@ -241,13 +241,12 @@ class ShardedPipeline:
         )
         t_idx = TAG_SCHEMA.index
         m_idx = FLOW_METER.index
-        # one-pass knobs captured at step-BUILD time (ISSUE 17): the
+        # one-pass knob captured at step-BUILD time (ISSUE 17): the
         # sharded twin pins the same path as the single-chip step for
         # the life of this jitted closure
-        from ..ops.segment import _use_fused_sketch, _use_shared_sort
+        from ..ops.segment import _use_shared_sort
 
         shared_sort = _use_shared_sort()
-        fused_sketch = _use_fused_sketch()
 
         def device_step(stash, acc, offset, sk, tag_mat, meters, valid,
                         start_window, close_below):
@@ -279,7 +278,7 @@ class ShardedPipeline:
                 sk1, c.hist,
                 window=ts // jnp.uint32(c.interval), valid=valid1,
                 base_w=start_window, close_w=close_below,
-                shared_sort=shared_sort, fused_sketch=fused_sketch, **inp,
+                shared_sort=shared_sort, **inp,
             )
 
             expand = lambda x: x[None]
@@ -525,7 +524,7 @@ class ShardedPipeline:
         host compacts all shards into one DocBatch.
 
         This is the per-window oracle shape; the production drain is
-        `flush_range` (all closed windows in one call — PERF.md §8).
+        `flush_range` (all closed windows in one call).
         """
         if self.config.fold_mode == "merge":
             # stash_flush punches sentinel holes mid-prefix, silently

@@ -20,8 +20,7 @@ step (or for a GC'd callable), the entry degrades to shapes + compile
 wall time with an `analysis_error` note instead of raising — the
 profile surface must never take down the server.
 
-Next on-chip session: PERF.md §21 reserves columns for these numbers —
-per-bucket flops/bytes make the fused step's arithmetic intensity (and
+Per-bucket flops/bytes make the fused step's arithmetic intensity (and
 therefore which window lever to pull next) a lookup, not a guess.
 """
 
@@ -66,7 +65,7 @@ class _Entry:
 
 #: the headline cost_analysis keys (XLA also emits per-operand
 #: `bytes_accessed<N>{}` / `utilization<N>{}` rows — noise for a
-#: per-step census; the totals are what PERF.md §21 tabulates)
+#: per-step census; the totals are what the census serves)
 _COST_KEYS = ("flops", "bytes accessed", "transcendentals",
               "optimal_seconds")
 

@@ -2,7 +2,7 @@
 
 A dashboard storm is thousands of clients asking the SAME question at
 the same cadence. The r14 result cache collapsed the *recompute* cost
-(81× on the repeated read, PERF.md §19) but every client still polls;
+(a repeated read is a dict lookup) but every client still polls;
 this module inverts the flow: a PromQL/SQL query registers ONCE, the
 `events.QueryEventBus` tells the manager when its (db, table) moved,
 the manager re-evaluates against the live overlay ONE time and fans

@@ -158,7 +158,7 @@ class FlowMetricsIngester:
                 # ONE fixed kernel shape: enrich in fixed-size chunks
                 # (pad the tail) so the whole run compiles exactly once —
                 # per-frame power-of-2 padding recompiled on every new
-                # drain size and dominated e2e time (bench/e2e_ingest.py)
+                # drain size and dominated the run's time
                 n = decoded.tags.shape[0]
                 c = self.enrich_chunk
                 s0_parts, s1_parts, keep_parts, drops = [], [], [], 0

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One whole-program compile of the device step costs minutes, so every
-launcher (chip_smoke.py, bench.py, bench_all.py, Server.start) keeps the
+launcher (chip_smoke.py, bench.py, Server.start) keeps the
 cache on. The directory is part of the cache key: it must not move.
 
   * `JAX_COMPILATION_CACHE_DIR` set → JAX reads it itself; nothing is

@@ -218,7 +218,7 @@ def test_live_capture_loopback():
     agent.close()
 
 
-def test_live_capture_ring_loopback():
+def test_live_capture_ring_loopback(monkeypatch):
     """TPACKET_V3 mmap block-ring capture (recv_engine/af_packet fast
     path): real UDP over loopback through ring → parse → FlowMap."""
     import socket as pysocket
@@ -227,27 +227,51 @@ def test_live_capture_ring_loopback():
 
     import pytest
 
-    try:
-        from deepflow_tpu.agent.capture import AfPacketRingCapture
+    from deepflow_tpu.agent import capture as capture_mod
 
-        probe = AfPacketRingCapture("lo")
+    try:
+        probe = capture_mod.AfPacketRingCapture("lo")
         probe.close()
     except (PermissionError, OSError):
         pytest.skip("AF_PACKET ring unavailable")
 
+    up, over, captures = threading.Event(), threading.Event(), []
+
+    class AnnouncedCapture(capture_mod.AfPacketRingCapture):
+        """Says when its ring is mapped and bound: a datagram sent before
+        that is never captured."""
+
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            captures.append(self)
+            up.set()
+
+    monkeypatch.setattr(capture_mod, "AfPacketRingCapture", AnnouncedCapture)
     agent = Agent(AgentConfig(batch_size=256), senders={})
 
     def chatter():
+        up.wait()
+        if not captures:
+            return  # the run ended before a capture was built
         s = pysocket.socket(pysocket.AF_INET, pysocket.SOCK_DGRAM)
-        for i in range(120):
+        i = 0
+        # the ring hands frames over a block at a time: keep the traffic
+        # up until the capture has counted its 120, or the run is over
+        while captures[0].counters["frames"] < 120 and not over.is_set():
             s.sendto(b"ring-%d" % i, ("127.0.0.1", 39998))
+            i += 1
             pytime.sleep(0.002)
         s.close()
 
     t = threading.Thread(target=chatter)
     t.start()
-    stats = agent.run_live("lo", duration_s=1.5, ring=True)
-    t.join()
+    try:
+        stats = agent.run_live("lo", duration_s=1.5, ring=True)
+    finally:
+        over.set()
+        up.set()
+        t.join(30)
+    assert not t.is_alive()
     agent.close()
     assert stats["capture"]["frames"] >= 120, stats["capture"]
     assert stats["capture"]["blocks"] >= 1

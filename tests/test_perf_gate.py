@@ -1,24 +1,27 @@
-"""Perf regression gate — small-shape smoke bounds on the hot path.
+"""Perf regression gate — what a kernel regression changes and a loaded
+CPU does not.
 
 Round 3 shipped a 7x kernel regression behind 164 green correctness
-tests because nothing in the suite watched time. This gate bounds, on
-the CPU backend the suite runs on (tests/conftest.py):
+tests because nothing in the suite watched the programs. The first two
+gates compile the append+fold pair of the production cadence
+(append × accum_batches + fold, aggregator/pipeline.py) at a small shape
+and count, in the compiled programs' HLO text:
 
-  * compile+first-execute time of the append+fold pair, and
-  * steady-state per-batch time of the production cadence
-    (append × accum_batches + fold, aggregator/pipeline.py).
+  * the `sort` instructions, exactly: a second keyed sort is the cost
+    the one-sort designs exist to avoid, and
+  * all instructions, under about three times today's count: a
+    log-depth scan in place of a linear one, or a program whose size
+    grows with its shapes — the round-3 failure modes — multiplies it.
 
-Bounds are ~6x the values measured when the gate was written (PERF.md
-§gate: compile+first 2.7 s, steady 4.8 ms/batch at this shape on the
-build container's CPU), so host jitter can't flake it but an
-order-of-magnitude regression — the round-3 failure mode: superlinear
-compile blowup or a log-depth-scan kernel — still trips it.
+The cycles still run, so the programs still execute; no clock is read.
+Times, rates and compile seconds come from the chip (chipbench/).
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -37,92 +40,70 @@ BATCH = 1024
 CAPACITY = 1 << 12
 ACCUM_BATCHES = 4
 
-COMPILE_BOUND_S = 16.0
-STEADY_BOUND_MS = 30.0
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = ", re.M)
+
+
+def _program_counts(compiled) -> tuple[int, int]:
+    """(sort instructions, all instructions) in a compiled program."""
+    text = compiled.as_text()
+    return len(re.findall(r" sort\(", text)), len(_HLO_INSTRUCTION.findall(text))
+
+
+def _compile_count_and_cycle(batch_unique_cap):
+    """Compile the append and the fold at the gate's shape, run three
+    cycles of the production cadence on them, and return their counts
+    as ((append sorts, append instructions), (fold sorts, fold
+    instructions))."""
+    gen = SyntheticFlowGen(num_tuples=500, seed=0)
+    fb = gen.flow_batch(BATCH, 1_700_000_000)
+    tags = {k: jnp.asarray(v) for k, v in fb.tags.items()}
+    meters, valid = jnp.asarray(fb.meters), jnp.asarray(fb.valid)
+
+    append_fn, fold_fn = make_ingest_step(
+        FanoutConfig(), interval=1, batch_unique_cap=batch_unique_cap
+    )
+    stride = FANOUT_LANES * (batch_unique_cap or BATCH)
+    state = stash_init(CAPACITY, TAG_SCHEMA, FLOW_METER)
+    acc = accum_init(ACCUM_BATCHES * stride, TAG_SCHEMA, FLOW_METER)
+    append = jax.jit(append_fn, donate_argnums=(0, 1)).lower(
+        state, acc, jnp.int32(0), tags, meters, valid).compile()
+    fold = jax.jit(fold_fn, donate_argnums=(0, 1)).lower(state, acc).compile()
+
+    live = []
+    for _ in range(3):
+        for k in range(ACCUM_BATCHES):
+            state, acc = append(
+                state, acc, jnp.int32(k * stride), tags, meters, valid
+            )
+        state, acc = fold(state, acc)
+        live.append(int(jnp.sum(state.valid)))
+    # the same batch every time: the stash holds its keys after the first
+    # fold and nothing more after the third
+    assert live[0] > 0 and live == [live[0]] * 3, live
+    assert int(state.dropped_overflow) == 0
+    return _program_counts(append), _program_counts(fold)
 
 
 def test_hot_path_compile_and_steady_state_bounds():
-    gen = SyntheticFlowGen(num_tuples=500, seed=0)
-    fb = gen.flow_batch(BATCH, 1_700_000_000)
-    tags = {k: jnp.asarray(v) for k, v in fb.tags.items()}
-    meters, valid = jnp.asarray(fb.meters), jnp.asarray(fb.valid)
-
-    append_fn, fold_fn = make_ingest_step(FanoutConfig(), interval=1)
-    append = jax.jit(append_fn, donate_argnums=(0, 1))
-    fold = jax.jit(fold_fn, donate_argnums=(0, 1))
-
-    doc_rows = FANOUT_LANES * BATCH
-    state = stash_init(CAPACITY, TAG_SCHEMA, FLOW_METER)
-    acc = accum_init(ACCUM_BATCHES * doc_rows, TAG_SCHEMA, FLOW_METER)
-
-    t0 = time.perf_counter()
-    state, acc = append(state, acc, jnp.int32(0), tags, meters, valid)
-    state, acc = fold(state, acc)
-    jax.block_until_ready(acc.slot)
-    compile_s = time.perf_counter() - t0
-    assert compile_s < COMPILE_BOUND_S, (
-        f"hot-path compile+first-run took {compile_s:.1f}s "
-        f"(bound {COMPILE_BOUND_S}s) — compile-time regression"
-    )
-
-    cycles = 3
-    t0 = time.perf_counter()
-    for _ in range(cycles):
-        for k in range(ACCUM_BATCHES):
-            state, acc = append(
-                state, acc, jnp.int32(k * doc_rows), tags, meters, valid
-            )
-        state, acc = fold(state, acc)
-    jax.block_until_ready(acc.slot)
-    per_batch_ms = (time.perf_counter() - t0) / (cycles * ACCUM_BATCHES) * 1e3
-    assert per_batch_ms < STEADY_BOUND_MS, (
-        f"hot-path steady state {per_batch_ms:.1f} ms/batch "
-        f"(bound {STEADY_BOUND_MS} ms) — kernel regression"
-    )
+    (append_sorts, append_instr), (fold_sorts, fold_instr) = (
+        _compile_count_and_cycle(None))
+    # no pre-reduce: the append sorts nothing; the fold sorts its keys
+    # once and the positions of its segment heads once
+    assert (append_sorts, fold_sorts) == (0, 2)
+    assert append_instr < 5000, append_instr  # 1626 when written
+    assert fold_instr < 1700, fold_instr  # 571 when written
 
 
 def test_prereduce_hot_path_bounds():
-    """Same bounds for the production bench cadence: batch-local
-    pre-reduce (batch_unique_cap) before fanout (PERF.md §7). Guards the
-    path bench.py actually ships."""
-    gen = SyntheticFlowGen(num_tuples=500, seed=0)
-    fb = gen.flow_batch(BATCH, 1_700_000_000)
-    tags = {k: jnp.asarray(v) for k, v in fb.tags.items()}
-    meters, valid = jnp.asarray(fb.meters), jnp.asarray(fb.valid)
-
-    cap_u = 512
-    append_fn, fold_fn = make_ingest_step(
-        FanoutConfig(), interval=1, batch_unique_cap=cap_u
-    )
-    append = jax.jit(append_fn, donate_argnums=(0, 1))
-    fold = jax.jit(fold_fn, donate_argnums=(0, 1))
-
-    stride = FANOUT_LANES * cap_u
-    state = stash_init(CAPACITY, TAG_SCHEMA, FLOW_METER)
-    acc = accum_init(ACCUM_BATCHES * stride, TAG_SCHEMA, FLOW_METER)
-
-    t0 = time.perf_counter()
-    state, acc = append(state, acc, jnp.int32(0), tags, meters, valid)
-    state, acc = fold(state, acc)
-    jax.block_until_ready(acc.slot)
-    compile_s = time.perf_counter() - t0
-    assert compile_s < COMPILE_BOUND_S, (
-        f"pre-reduce compile+first-run took {compile_s:.1f}s "
-        f"(bound {COMPILE_BOUND_S}s) — compile-time regression"
-    )
-
-    cycles = 3
-    t0 = time.perf_counter()
-    for _ in range(cycles):
-        for k in range(ACCUM_BATCHES):
-            state, acc = append(state, acc, jnp.int32(k * stride), tags, meters, valid)
-        state, acc = fold(state, acc)
-    jax.block_until_ready(acc.slot)
-    per_batch_ms = (time.perf_counter() - t0) / (cycles * ACCUM_BATCHES) * 1e3
-    assert per_batch_ms < STEADY_BOUND_MS, (
-        f"pre-reduce steady state {per_batch_ms:.1f} ms/batch "
-        f"(bound {STEADY_BOUND_MS} ms) — kernel regression"
-    )
+    """The same gate for the cadence bench.py and the served path run:
+    the batch-local pre-reduce (batch_unique_cap) before fanout is the
+    fold's group-by over the batch, so the append gains the fold's two
+    sorts and the fold stays as it was."""
+    (append_sorts, append_instr), (fold_sorts, fold_instr) = (
+        _compile_count_and_cycle(512))
+    assert (append_sorts, fold_sorts) == (2, 2)
+    assert append_instr < 10000, append_instr  # 3309 when written
+    assert fold_instr < 1700, fold_instr  # 571 when written
 
 
 # ---------------------------------------------------------------------------
@@ -941,10 +922,8 @@ def test_profiling_budget(monkeypatch):
     retraces the fused step. Every profile read itself is fetch-free;
     the census's XLA analysis (which may compile via the AOT path) runs
     once post-measurement and must not disturb fetch accounting or the
-    dispatch cache either. The <2% wall-clock overhead acceptance is
-    measured by bench/profbench.py (PROFBENCH_r01.json, PERF.md §21) —
-    wall time on a noisy CI container is not a deterministic gate;
-    fetch parity is."""
+    dispatch cache either. Wall time on a loaded CPU is not a
+    deterministic gate; fetch parity is."""
     import deepflow_tpu.aggregator.window as window_mod
     from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
     from deepflow_tpu.aggregator.window import WindowConfig

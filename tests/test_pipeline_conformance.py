@@ -163,7 +163,7 @@ def test_conformance_forced_pallas(monkeypatch):
 
 
 def test_batch_unique_cap_prereduce_exact():
-    """The batch-local pre-reduce (fanout-after-reduce, PERF.md §7) must
+    """The batch-local pre-reduce (fanout-after-reduce) must
     be EXACT: same fold output as the plain step, because identical raw
     tag rows land identical doc rows per lane and the lane meter
     transforms are column permutations (sum/max commute)."""

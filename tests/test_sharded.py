@@ -244,7 +244,7 @@ def test_hll_sharded_equals_single_device():
 
 def test_sharded_prereduce_matches_single_device_oracle():
     """Same 8-device vs single-device equality with the batch-local
-    pre-reduce on (ShardedConfig.batch_unique_cap, PERF.md §7)."""
+    pre-reduce on (ShardedConfig.batch_unique_cap)."""
     from deepflow_tpu.aggregator.pipeline import PipelineConfig, RollupPipeline
     from deepflow_tpu.aggregator.window import WindowConfig
     from deepflow_tpu.datamodel.batch import FlowBatch

@@ -1,20 +1,17 @@
-"""One-pass sketch fold (ISSUE 17) — the shared-sort rewrite and the
-fused Pallas kernel, pinned bit-exact against the multi-sort oracle.
+"""One-pass sketch fold (ISSUE 17) — the shared-sort rewrite, pinned
+bit-exact against the multi-sort oracle.
 
-Three layers:
+Two layers:
 
   * jaxpr-level sort attribution: the census's static sort counter on
     `sketch_plane_step` itself — shared ON pays exactly ONE sort where
     the oracle pays 2 phases × topk_rows, and a top-K-less plane pays
     ZERO either way (the shared sort must never ADD a sort);
   * WindowManager-level bit-exactness: identical flushed exact rows and
-    identical sketch blocks (every lane) across oracle / shared /
-    fused-kernel runs of the same stream — seeded fuzz over batch
-    sizes, bucket counts, sketch shapes and fold modes, with invalid
-    rows and multi-window batches in the mix;
-  * the loud-fallback contract: an unsupported shape must take the XLA
-    presorted path (bit-exact), warn once, and count the miss in
-    `ops.sketch_pallas.FUSED_SKETCH_FALLBACKS`.
+    identical sketch blocks (every lane) across oracle and shared
+    runs of the same stream — seeded fuzz over batch sizes, bucket
+    counts, sketch shapes and fold modes, with invalid rows and
+    multi-window batches in the mix.
 
 The census end-to-end gate (telemetry()["profile"]["census"] showing
 sorts/dispatch 4 → 1 on the REAL fused step) lives with the budget
@@ -28,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepflow_tpu.ops.sketch_pallas as sketch_pallas
 from deepflow_tpu.aggregator.sketchplane import (
     SketchConfig,
     sketch_init,
@@ -92,14 +88,13 @@ def _fuzz_batches(rng, n_batches, size, key_space):
     return batches
 
 
-def _run_variant(monkeypatch, batches, *, shared, fused, sketch=SK,
-                 capacity=1 << 10, fold_mode="full"):
+def _run_variant(monkeypatch, batches, *, shared, sketch=SK,
+                 fold_mode="full"):
     """One full WindowManager run of `batches` under the given knob
-    setting (dispatch-time env reads — aggregator/window.py)."""
+    setting (a dispatch-time env read — aggregator/window.py)."""
     monkeypatch.setenv("DEEPFLOW_SHARED_SORT", "1" if shared else "0")
-    monkeypatch.setenv("DEEPFLOW_FUSED_SKETCH", "1" if fused else "0")
     wm = WindowManager(WindowConfig(
-        capacity=capacity, delay=2, sketch=sketch, fold_mode=fold_mode,
+        capacity=1 << 10, delay=2, sketch=sketch, fold_mode=fold_mode,
     ))
     out = []
     for keys, ts, valid, weights in batches:
@@ -151,7 +146,7 @@ def _plane_sorts(cfg: SketchConfig, shared: bool) -> int:
             close_w=u32(11), group=group, client_hi=client_hi,
             client_lo=client_lo, key_hi=key_hi, key_lo=key_lo,
             weight=weight, rtt=rtt, rtt_valid=rtt_valid, id_a=id_a,
-            id_b=id_b, shared_sort=shared, fused_sketch=False,
+            id_b=id_b, shared_sort=shared,
         )
 
     jaxpr = jax.make_jaxpr(step)(
@@ -184,8 +179,7 @@ def test_shared_sort_never_adds_a_sort_without_topk():
 
 
 # ---------------------------------------------------------------------------
-# WindowManager-level bit-exactness (tentpole a) + kernel parity fuzz
-# (tentpole b / satellite 3)
+# WindowManager-level bit-exactness
 
 
 def test_shared_sort_bit_exact_vs_oracle(monkeypatch):
@@ -195,8 +189,8 @@ def test_shared_sort_bit_exact_vs_oracle(monkeypatch):
     the multi-sort oracle."""
     rng = np.random.default_rng(170)
     batches = _fuzz_batches(rng, n_batches=6, size=257, key_space=40)
-    oracle = _run_variant(monkeypatch, batches, shared=False, fused=False)
-    shared = _run_variant(monkeypatch, batches, shared=True, fused=False)
+    oracle = _run_variant(monkeypatch, batches, shared=False)
+    shared = _run_variant(monkeypatch, batches, shared=True)
     assert any(f.sketches is not None for f in oracle)
     _assert_flush_identical(oracle, shared, "shared-vs-oracle")
 
@@ -216,64 +210,17 @@ def test_shared_sort_bit_exact_vs_oracle(monkeypatch):
         ),
     ],
 )
-def test_fused_kernel_parity_fuzz(monkeypatch, seed, size, key_space,
-                                  sketch, fold_mode):
-    """Interpret-mode Pallas parity pin (CPU tier-1): oracle, XLA
-    shared-sort, and the fused kernel all produce bit-identical flushed
+def test_shared_sort_parity_fuzz(monkeypatch, seed, size, key_space,
+                                 sketch, fold_mode):
+    """The oracle and the shared sort produce bit-identical flushed
     streams and sketch blocks over seeded fuzz covering batch sizes,
     top-K bucket counts, count-min shapes and both fold modes."""
     rng = np.random.default_rng(seed)
     batches = _fuzz_batches(rng, n_batches=5, size=size,
                             key_space=key_space)
     kw = dict(sketch=sketch, fold_mode=fold_mode)
-    oracle = _run_variant(monkeypatch, batches, shared=False, fused=False,
-                          **kw)
-    shared = _run_variant(monkeypatch, batches, shared=True, fused=False,
-                          **kw)
-    fused = _run_variant(monkeypatch, batches, shared=True, fused=True,
-                         **kw)
+    oracle = _run_variant(monkeypatch, batches, shared=False, **kw)
+    shared = _run_variant(monkeypatch, batches, shared=True, **kw)
     assert any(f.sketches is not None and f.sketches.tk_votes.size
                for f in oracle)
     _assert_flush_identical(oracle, shared, "shared-vs-oracle")
-    _assert_flush_identical(shared, fused, "fused-vs-shared")
-
-
-def test_fused_sketch_guard_falls_back_loudly(monkeypatch):
-    """Unsupported shapes degrade LOUDLY: the guard warns once per
-    shape, counts the miss in FUSED_SKETCH_FALLBACKS, and the step
-    lands on the XLA presorted path — still bit-exact vs the oracle."""
-    monkeypatch.setattr(sketch_pallas, "MAX_FUSED_ROWS", 64)
-    sketch_pallas._WARNED_SHAPES.clear()
-    rng = np.random.default_rng(173)
-    # batch size 150 > the patched row cap, and a capacity not used by
-    # the other variants so the knob-matrix jit cache can't serve a
-    # stale trace from before the patch
-    batches = _fuzz_batches(rng, n_batches=3, size=150, key_space=25)
-    before = sketch_pallas.FUSED_SKETCH_FALLBACKS
-    with pytest.warns(UserWarning, match="falling back"):
-        fused = _run_variant(monkeypatch, batches, shared=True, fused=True,
-                             capacity=1 << 9)
-    assert sketch_pallas.FUSED_SKETCH_FALLBACKS > before
-    oracle = _run_variant(monkeypatch, batches, shared=False, fused=False,
-                          capacity=1 << 9)
-    _assert_flush_identical(oracle, fused, "fallback-vs-oracle")
-
-
-def test_fused_guard_accepts_supported_shape():
-    """The guard's accept side: the tier-1 fuzz shapes are inside both
-    budgets, so the kernel actually ran in the parity test above."""
-    assert sketch_pallas.fused_sketch_guard(
-        257, 4, SK.num_groups, SK.hll_m, SK.cms_depth, SK.cms_width,
-        SK.topk_rows, SK.topk_cols,
-    )
-    # and the reject side counts without raising
-    before = sketch_pallas.FUSED_SKETCH_FALLBACKS
-    with pytest.warns(UserWarning):
-        sketch_pallas._WARNED_SHAPES.discard(
-            (1 << 20, 4, SK.num_groups, SK.hll_m, SK.cms_depth,
-             SK.cms_width, SK.topk_rows, SK.topk_cols))
-        assert not sketch_pallas.fused_sketch_guard(
-            1 << 20, 4, SK.num_groups, SK.hll_m, SK.cms_depth,
-            SK.cms_width, SK.topk_rows, SK.topk_cols,
-        )
-    assert sketch_pallas.FUSED_SKETCH_FALLBACKS == before + 1

@@ -88,8 +88,7 @@ def test_pool_vs_slab_closed_blocks_equal_accuracy():
     """The tentpole acceptance shape at test scale: the pooled plane
     closes the same windows with the same coverage, bit-exact HLL,
     mass-conserving histograms, in-envelope CMS estimates and the same
-    recovered heavies — at a fraction of the slab's resident bytes
-    (bench/sketchbench.py carries the measured ≥4× density)."""
+    recovered heavies — at a fraction of the slab's resident bytes."""
     rng = np.random.default_rng(60)
     per_window = {}
     for t in (T0, T0 + 1, T0 + 2):
