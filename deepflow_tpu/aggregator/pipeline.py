@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..datamodel.batch import DocBatch, FlowBatch
+from ..datamodel.batch import DocBatch, FlowBatch, StagingBuffer, StagingRing
 from ..datamodel.code import DOC_KEY_PACK, RAW_TAG_PACK, DocumentFlag, pack_tag_words
 from ..datamodel.schema import APP_METER, FLOW_METER, TAG_SCHEMA, MeterSchema
 from ..ops.hashing import fingerprint64_words
@@ -262,6 +262,9 @@ class StagedBatch:
     meters: jnp.ndarray  # [B, M] f32 (device)
     valid: jnp.ndarray  # [B] bool (device)
     padded_rows: int  # B — the bucket this batch padded to
+    # the host buffer the three arrays were uploaded from: not written
+    # again until this batch's step has run (StagingRing.acquire)
+    source: StagingBuffer
     # lineage plane (ISSUE 13): the batch's host-side event-time bounds
     # (valid rows only), captured in stage() BEFORE upload — t_max <
     # t_min means "not computed" (no lineage attached)
@@ -290,6 +293,9 @@ class RollupPipeline:
         )
         self._tag_names: tuple | None = None  # fixed on first batch
         self._step = None
+        # the host memory every batch is written into, once, in the
+        # upload's layout — by stage(FlowBatch) and by the feeder's sink
+        self.staging = StagingRing(self.meter_schema.num_fields)
         # closed-window sketch blocks (ISSUE 8): DocBatch is the exact
         # writer format, so blocks accumulate here for the sketch sink
         # (integration/dfstats.sketch_system_sink) / querier instead.
@@ -472,48 +478,62 @@ class RollupPipeline:
             f"{buckets[-1]}; the feeder must slice to max(bucket_sizes)"
         )
 
-    def stage(self, batch: FlowBatch) -> "StagedBatch | None":
-        """Pad to the shape bucket and START the host→device upload of
-        the packed tag matrix + meters + valid (JAX device puts are
-        async) WITHOUT dispatching the fused step. The feeder runtime
-        stages batch i+1 while batch i's dispatch is still in flight —
-        the upload overlaps compute, mirroring async_drain on the
-        output side. Returns None for an all-padding batch."""
+    def stage(self, batch: "FlowBatch | StagingBuffer") -> "StagedBatch | None":
+        """START the host→device upload of a staging buffer's tag matrix
+        + meters + valid (JAX device puts are async) WITHOUT dispatching
+        the fused step. The feeder runtime hands over the buffer it
+        assembled and stages batch i+1 while batch i's dispatch is still
+        in flight — the upload overlaps compute, mirroring async_drain
+        on the output side. A FlowBatch is first written into a buffer
+        of its bucket by the same writer, as its one chunk. Returns None
+        for an all-padding batch."""
         with self.tracer.span(SPAN_INGEST_STAGE):
+            if not isinstance(batch, StagingBuffer):
+                buf = self.staging.acquire(
+                    self._pad_target(batch.size),
+                    self._tag_names or tuple(sorted(batch.tags)),
+                )
+                buf.write(batch.tags, batch.meters, batch.valid)
+                buf.finish()
+                batch = buf
             return self._stage(batch)
 
-    def _stage(self, batch: FlowBatch) -> "StagedBatch | None":
-        batch = batch.pad_to(self._pad_target(batch.size))
-        if not np.any(batch.valid):
+    def _stage(self, buf: StagingBuffer) -> "StagedBatch | None":
+        if buf.n_valid == 0:
             return None
         lin = self._lineage
         t_min, t_max, s0 = 0, -1, 0.0
         if lin is not None:
             # host event-time bounds BEFORE the upload (numpy — free);
             # the dispatch binds them to the lineage window span
-            ts = np.asarray(batch.tags["timestamp"])[batch.valid]
-            if ts.size:
-                t_min, t_max = int(ts.min()), int(ts.max())
+            ts = buf.tag_mat[buf.names.index("timestamp"), : buf.rows]
+            if buf.n_valid < buf.rows:
+                ts = ts[buf.valid[: buf.rows]]
+            t_min, t_max = int(ts.min()), int(ts.max())
             s0 = lin.clock()
         if self._tag_names is None:
-            self._tag_names = tuple(sorted(batch.tags))
+            self._tag_names = buf.names
             self._step = self._build_step(self._tag_names)
             self._jit.attach(self._step)
-        # pack the ~37 tag columns into ONE host→device upload
-        tag_mat = jnp.asarray(
-            np.stack(
-                [np.asarray(batch.tags[k], dtype=np.uint32) for k in self._tag_names]
+        elif buf.names != self._tag_names:
+            raise ValueError(
+                f"staging buffer's tag rows {buf.names} are not the order "
+                f"the fused step was built with {self._tag_names}"
             )
-        )
-        meters = jnp.asarray(batch.meters)
-        valid = jnp.asarray(batch.valid)
+        # three uploads: the ~37 tag columns travel as ONE matrix
+        tag_mat = jnp.asarray(buf.tag_mat)
+        meters = jnp.asarray(buf.meters)
+        valid = jnp.asarray(buf.valid)
         self.wm.bytes_uploaded += (
             tag_mat.nbytes + meters.nbytes + valid.nbytes
         )
         if lin is not None:
             lin.note_stage(s0)
-        return StagedBatch(tag_mat=tag_mat, meters=meters, valid=valid,
-                           padded_rows=batch.size, t_min=t_min, t_max=t_max)
+        staged = StagedBatch(tag_mat=tag_mat, meters=meters, valid=valid,
+                             padded_rows=buf.bucket, source=buf,
+                             t_min=t_min, t_max=t_max)
+        buf.uploaded(staged)
+        return staged
 
     def ingest(self, batch: FlowBatch, feeder_shed: int = 0) -> list[DocBatch]:
         """Feed one decoded flow batch; returns any closed windows."""
@@ -564,7 +584,11 @@ class RollupPipeline:
                     self._census_service, "fused_step", staged.padded_rows,
                     self._step, args,
                 )
-            return self._step(*args)
+            out = self._step(*args)
+            # the counter block is an output nothing donates: ready once
+            # this step has run, i.e. has read the staged arrays
+            staged.source.dispatched(out[1])
+            return out
 
         window_span = None
         if self._lineage is not None and staged.t_max >= staged.t_min:

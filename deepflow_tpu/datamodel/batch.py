@@ -9,7 +9,9 @@ the shape every device kernel consumes.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import weakref
 from typing import Mapping
 
 import numpy as np
@@ -132,6 +134,159 @@ class FlowBatch:
             meters=np.concatenate([p.meters for p in parts]),
             valid=np.concatenate([p.valid for p in parts]),
         )
+
+
+# ---------------------------------------------------------------------------
+# staging buffers — a batch written once, in the upload's layout
+
+# the row order of the packed tag matrix every fused step is built with
+STAGED_TAG_ORDER: tuple[str, ...] = tuple(sorted(FLOW_RECORD_TAG_FIELDS))
+STAGING_RING_LEN = 3  # the feeder holds one staged batch back; one is being written
+
+
+def _aligned_zeros(shape, dtype) -> np.ndarray:
+    """Zeros that start on a 64-byte line: what the CPU backend asks of
+    host memory before it aliases it in place of copying, so the CPU
+    tests reuse these buffers under the same hazard the chip's
+    asynchronous transfer makes."""
+    n = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    raw = np.zeros(n + 64, dtype=np.uint8)
+    off = -raw.ctypes.data % 64
+    return raw[off : off + n].view(dtype).reshape(shape)
+
+
+class StagingBuffer:
+    """One batch's host memory as the upload takes it: `tag_mat`
+    [T, B] u32 with rows in `names` order, `meters` [B, M] f32, `valid`
+    [B] bool. `write` appends a chunk's rows, `finish` zeroes what an
+    earlier, longer fill left past them; between the two every byte of a
+    record is written once. Reused: see StagingRing.acquire for when."""
+
+    def __init__(self, bucket: int, names: tuple[str, ...], n_meters: int):
+        self.names = names
+        self.tag_mat = _aligned_zeros((len(names), bucket), np.uint32)
+        self.meters = _aligned_zeros((bucket, n_meters), np.float32)
+        self.valid = _aligned_zeros((bucket,), bool)
+        self.rows = 0  # rows of the fill under way
+        self.n_valid = 0  # of them valid
+        self._dirty = 0  # rows an earlier fill left non-zero
+        # wire row i (FLOW_RECORD_TAG_FIELDS order) lands in row _from_wire[i]
+        self._from_wire = (
+            np.array([names.index(f) for f in FLOW_RECORD_TAG_FIELDS])
+            if sorted(names) == sorted(FLOW_RECORD_TAG_FIELDS) else None
+        )
+        self._owner = None  # weakref: the staged batch uploaded from here, until dispatched
+        self._done = None  # a device array that is ready once the device has read this memory
+
+    @property
+    def bucket(self) -> int:
+        return int(self.valid.shape[0])
+
+    @property
+    def row_bytes(self) -> int:
+        return 4 * self.tag_mat.shape[0] + 4 * self.meters.shape[1] + 1
+
+    def write(self, tags, meters: np.ndarray, valid: np.ndarray | None = None) -> int:
+        """Rows [rows, rows + n) ← one chunk: `tags` the frame's [T, n]
+        matrix in FLOW_RECORD_TAG_FIELDS order (one assignment through
+        the row permutation) or a mapping name → [n] column; `valid`
+        None = all of them. Returns the bytes written."""
+        n = int(meters.shape[0])
+        s, e = self.rows, self.rows + n
+        if e > self.bucket:
+            raise ValueError(f"batch of {e} cannot pad to {self.bucket}")
+        if isinstance(tags, np.ndarray):
+            if self._from_wire is None:
+                raise ValueError(
+                    "a wire-order tag matrix needs a buffer whose rows are "
+                    f"FLOW_RECORD_TAG_FIELDS, not {self.names}")
+            self.tag_mat[self._from_wire, s:e] = tags
+        else:
+            for j, k in enumerate(self.names):
+                self.tag_mat[j, s:e] = tags[k]
+        self.meters[s:e] = meters
+        if valid is None:
+            self.valid[s:e] = True
+            self.n_valid += n
+        else:
+            self.valid[s:e] = valid
+            self.n_valid += int(np.count_nonzero(valid))
+        self.rows = e
+        return n * self.row_bytes
+
+    def finish(self) -> int:
+        """Zero the tail an earlier fill left behind — rows past
+        `_dirty` have been zero since the buffer was made. Returns the
+        bytes zeroed."""
+        s, e = self.rows, max(self.rows, self._dirty)
+        if e > s:
+            self.tag_mat[:, s:e] = 0
+            self.meters[s:e] = 0
+            self.valid[s:e] = False
+        self._dirty = self.rows
+        return (e - s) * self.row_bytes
+
+    def tag_columns(self) -> dict[str, np.ndarray]:
+        """name → row view of `tag_mat` (a FlowBatch's `tags`, no copy)."""
+        return {k: self.tag_mat[j] for j, k in enumerate(self.names)}
+
+    # -- when the memory may be written again ---------------------------
+    def uploaded(self, staged) -> None:
+        """`staged` holds the device arrays made from this memory; until
+        it is dispatched (or dropped) the buffer is not offered again."""
+        self._owner = weakref.ref(staged)
+        self._done = None
+
+    def dispatched(self, done) -> None:
+        """The step that reads the staged arrays is on its way; `done`
+        (an output of that step) is ready once it has run — and then the
+        transfer has finished and, where the device array aliases this
+        memory (the CPU backend), nothing reads it any more."""
+        self._owner = None
+        self._done = done
+
+    def held(self) -> bool:
+        return self._owner is not None and self._owner() is not None
+
+    def wait(self) -> bool:
+        """Block until the device has read this memory → whether it had
+        to (the handle was not ready yet)."""
+        done, self._done = self._done, None
+        if done is None or done.is_ready():
+            return False
+        done.block_until_ready()
+        return True
+
+
+class StagingRing:
+    """The staging buffers of one consumer, a few per (bucket, tag
+    names): made on first use, then reused for the life of the process."""
+
+    def __init__(self, n_meters: int):
+        self.n_meters = n_meters
+        self._rings: dict = {}  # (bucket, names) → deque of buffers, least recently used first
+        self.allocated = 0  # buffers ever made
+        self.waits = 0  # acquires that found their buffer still in flight
+
+    def acquire(self, bucket: int, names: tuple[str, ...] = STAGED_TAG_ORDER) -> StagingBuffer:
+        """The least recently used buffer of this shape, empty. A buffer
+        is written again only when the batch staged from it has been
+        dispatched and the device has read it (`wait`, counted when it
+        blocks); one whose staged batch is still held undispatched is
+        passed over, and the ring grows only if every buffer is."""
+        bufs = self._rings.setdefault((bucket, names), collections.deque())
+        buf = None
+        if len(bufs) >= STAGING_RING_LEN:
+            buf = next((b for b in bufs if not b.held()), None)
+        if buf is None:
+            buf = StagingBuffer(bucket, names, self.n_meters)
+            self.allocated += 1
+        else:
+            bufs.remove(buf)
+        bufs.append(buf)
+        self.waits += buf.wait()
+        buf.rows = buf.n_valid = 0
+        return buf
 
 
 @dataclasses.dataclass

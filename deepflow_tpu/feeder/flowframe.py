@@ -101,10 +101,12 @@ def peek_rows(body: bytes) -> int:
     return int(n)
 
 
-def decode_flowframe_body(body: bytes) -> FlowBatch:
-    """One flowframe message body → all-valid FlowBatch. Raises
-    ValueError on magic/version/field-count/size drift (the untrusted-
-    edge stance every decoder in ingest/ takes)."""
+def decode_flowframe_matrices(body: bytes) -> tuple[np.ndarray, np.ndarray]:
+    """One flowframe message body → (tag matrix [T, n] u32 in
+    FLOW_RECORD_TAG_FIELDS order, meters [n, M] f32), both views of
+    `body`: nothing is copied. Raises ValueError on magic/version/
+    field-count/size drift (the untrusted-edge stance every decoder in
+    ingest/ takes)."""
     if len(body) < _HDR.size:
         raise ValueError("flowframe: short body")
     magic, version, n, t, m = _HDR.unpack_from(body, 0)
@@ -125,12 +127,15 @@ def decode_flowframe_body(body: bytes) -> FlowBatch:
     tag_mat = np.frombuffer(body, dtype="<u4", count=t * n, offset=off).reshape(t, n)
     off += 4 * t * n
     meters = np.frombuffer(body, dtype="<f4", count=n * m, offset=off).reshape(n, m)
-    tags = {
-        f: np.ascontiguousarray(tag_mat[i])
-        for i, f in enumerate(FLOW_RECORD_TAG_FIELDS)
-    }
+    return tag_mat, meters
+
+
+def decode_flowframe_body(body: bytes) -> FlowBatch:
+    """One flowframe message body → all-valid FlowBatch whose columns are
+    rows of the frame's own matrix (see decode_flowframe_matrices)."""
+    tag_mat, meters = decode_flowframe_matrices(body)
     return FlowBatch(
-        tags=tags,
-        meters=np.ascontiguousarray(meters),
-        valid=np.ones(n, dtype=bool),
+        tags={f: tag_mat[i] for i, f in enumerate(FLOW_RECORD_TAG_FIELDS)},
+        meters=meters,
+        valid=np.ones(meters.shape[0], dtype=bool),
     )

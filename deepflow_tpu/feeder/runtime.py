@@ -26,7 +26,11 @@ arXiv:2503.13515). This module is that stage:
     the next dispatched batch;
   * **double-buffered upload**: the pipeline sink stages batch i+1's
     packed tag matrix (async device put) before dispatching batch i,
-    mirroring `async_drain` on the output side.
+    mirroring `async_drain` on the output side;
+  * **one write a record**: a decoded frame stays views of its bytes
+    (FlowChunk) until the sink writes it into a reused staging buffer
+    that already has the upload's shape (datamodel/batch.StagingRing) —
+    nothing is concatenated, padded or stacked on the way.
 
 Fault tolerance (ISSUE 6) — every failure class on the
 feeder→device→flush path is either retried, contained, or counted:
@@ -73,7 +77,8 @@ from pathlib import Path
 import numpy as np
 
 from .. import chaos
-from ..datamodel.batch import FlowBatch
+from ..datamodel.batch import StagingBuffer, StagingRing
+from ..datamodel.schema import FLOW_METER
 from ..ingest.framing import HEADER_LEN, FlowHeader, MessageType, split_message_spans
 from ..utils.spans import (
     SPAN_FEEDER_ASSEMBLE,
@@ -86,7 +91,7 @@ from ..utils.spans import (
 )
 from ..utils.retry import RetryPolicy, decorrelated_rng
 from ..utils.stats import register_countable
-from .flowframe import decode_flowframe_body, peek_rows
+from .flowframe import decode_flowframe_matrices, peek_rows
 
 _log = logging.getLogger(__name__)
 
@@ -96,16 +101,20 @@ _log = logging.getLogger(__name__)
 
 @dataclasses.dataclass
 class FlowChunk:
-    """Flow records (pre-fanout), wrapping a FlowBatch."""
+    """Flow records (pre-fanout) as their frame held them: `tags` the
+    [T, n] u32 matrix in FLOW_RECORD_TAG_FIELDS order, `meters` [n, M]
+    f32 — views of the frame's bytes; splitting slices them."""
 
-    fb: FlowBatch
+    tags: np.ndarray
+    meters: np.ndarray
 
     @property
     def rows(self) -> int:
-        return self.fb.size
+        return int(self.meters.shape[0])
 
     def split(self, n: int) -> tuple["FlowChunk", "FlowChunk"]:
-        return FlowChunk(self.fb.slice(0, n)), FlowChunk(self.fb.slice(n, self.fb.size))
+        return (FlowChunk(self.tags[:, :n], self.meters[:n]),
+                FlowChunk(self.tags[:, n:], self.meters[n:]))
 
 
 @dataclasses.dataclass
@@ -168,7 +177,22 @@ class FrameCodecBase:
 
 class _FlowFrameCodec(FrameCodecBase):
     """Shared decode face for sinks that eat flowframe (TAGGEDFLOW)
-    frames."""
+    frames, and the one place their batches are assembled. `staging`
+    is the ring the batches are written into (the consumer's own where
+    it has one, else one made here); `host_copy_bytes` counts every byte
+    the feed writes to host memory between a decoded frame and the
+    upload, zero fill included, `staging_waits` the times a writer
+    found the ring's next buffer still in flight (both reach the
+    feeder's get_counters())."""
+
+    def __init__(self, staging: StagingRing | None = None):
+        super().__init__()
+        self.staging = staging or StagingRing(FLOW_METER.num_fields)
+        self.host_copy_bytes = 0
+
+    @property
+    def staging_waits(self) -> int:
+        return self.staging.waits
 
     def count_records(self, raw: bytes) -> int:
         body = raw[HEADER_LEN:]
@@ -180,18 +204,30 @@ class _FlowFrameCodec(FrameCodecBase):
             raise ValueError(f"flow sink got msg_type {header.msg_type}")
         body = raw[HEADER_LEN:]
         parts = [
-            decode_flowframe_body(body[o : o + ln])
+            decode_flowframe_matrices(body[o : o + ln])
             for o, ln in split_message_spans(body)
         ]
         if not parts:
             return None
-        return FlowChunk(FlowBatch.concat(parts))
+        if len(parts) == 1:
+            return FlowChunk(*parts[0])
+        # a frame of several messages (no sender of this repo makes one)
+        # is joined here, and the join counted with the feed's copies
+        chunk = FlowChunk(np.concatenate([t for t, _ in parts], axis=1),
+                          np.concatenate([m for _, m in parts]))
+        self.host_copy_bytes += chunk.tags.nbytes + chunk.meters.nbytes
+        return chunk
 
-    def _assemble(self, chunks: list[FlowChunk]) -> FlowBatch:
-        """One batch's chunks → one FlowBatch (37 tag columns and the
-        meter matrix concatenated: ~50 MB at a full 131072-row bucket)."""
+    def _assemble(self, chunks: list[FlowChunk], rows: int, bucket: int) -> StagingBuffer:
+        """One batch's chunks → one staging buffer of `bucket` rows in
+        the upload's layout (datamodel/batch.StagingBuffer): each chunk
+        written once where the upload reads it, the stale tail zeroed."""
         with self.tracer.span(SPAN_FEEDER_ASSEMBLE):
-            return FlowBatch.concat([c.fb for c in chunks])
+            buf = self.staging.acquire(bucket)
+            copied = sum(buf.write(c.tags, c.meters) for c in chunks)
+            assert buf.rows == rows
+            self.host_copy_bytes += copied + buf.finish()
+            return buf
 
 
 class PipelineFeedSink(_FlowFrameCodec):
@@ -207,7 +243,7 @@ class PipelineFeedSink(_FlowFrameCodec):
     emit dispatches it, so one device hiccup costs exactly one batch."""
 
     def __init__(self, pipeline, *, double_buffer: bool = True):
-        super().__init__()
+        super().__init__(pipeline.staging)
         if not pipeline.config.bucket_sizes:
             raise ValueError(
                 "PipelineFeedSink needs PipelineConfig.bucket_sizes — the "
@@ -239,20 +275,20 @@ class PipelineFeedSink(_FlowFrameCodec):
         }
 
     def emit(self, chunks: list[FlowChunk], rows: int, bucket: int, shed: int) -> list:
-        fb = self._assemble(chunks)
-        assert fb.size == rows
+        buf = self._assemble(chunks, rows, bucket)
         carried = self._shed_carry
         shed += carried
         self._shed_carry = 0
         try:
-            staged = self.pipeline.stage(fb)  # pads to `bucket`, starts upload
+            staged = self.pipeline.stage(buf)  # starts the three uploads
         except Exception:
             # admission itself failed (e.g. device OOM on the async
             # put): this batch's rows are gone and must be counted, or
             # delivered = records_out − lost_records over-reports. The
             # runtime re-arms only the shed IT passed in, so the carry
             # must go back into the buffer or it undercounts the
-            # device-plane feeder_shed lane.
+            # device-plane feeder_shed lane. The staging buffer went
+            # back to its ring: no staged batch holds it.
             self.lost_records += rows
             self._shed_carry += carried
             raise
@@ -327,8 +363,13 @@ class ShardedFeedSink(_FlowFrameCodec):
         self.feeder_shed = 0  # sharded path has no device counter block
 
     def emit(self, chunks: list[FlowChunk], rows: int, bucket: int, shed: int) -> list:
-        fb = self._assemble(chunks).pad_to(bucket)
-        out = self.swm.ingest(fb.tags, fb.meters, fb.valid)
+        buf = self._assemble(chunks, rows, bucket)
+        out = self.swm.ingest(buf.tag_columns(), buf.meters, buf.valid)
+        # the sharded step has no per-batch sync and its outputs are
+        # donated to the next one, so the buffer waits on a one-column
+        # read of the ring the step wrote: ready once the step has run
+        if self.swm.acc is not None:
+            buf.dispatched(self.swm.acc.slot[:, :1])
         # only account the shed once the batch actually landed — on a
         # failed dispatch the runtime re-owns it
         self.feeder_shed += shed
@@ -593,6 +634,8 @@ class FeederRuntime:
         out["healthy"] = int(not self.degraded and self._pump_failstreak == 0)
         out["last_checkpoint_ok"] = int(self.last_checkpoint_ok)
         out["decode_errors"] = int(getattr(self.sink, "decode_errors", 0))
+        out["host_copy_bytes"] = int(getattr(self.sink, "host_copy_bytes", 0))
+        out["staging_waits"] = int(getattr(self.sink, "staging_waits", 0))
         if self._journal is not None:
             for k, v in self._journal.get_counters().items():
                 out[f"journal_{k}"] = v

@@ -187,9 +187,12 @@ class FlowMetricsIngester:
             else:
                 s0 = s1 = None
                 keep = valid
+            self.writer.put(EnrichedBatch(header=header, decoded=decoded, side0=s0, side1=s1, keep=keep))
+            # counted once the writer HAS them: a reader that waits for
+            # this count and then flushes the writer must find every
+            # table's writer made and its rows queued
             with self._lock:
                 self.counters["docs_written"] += int(keep.sum())
-            self.writer.put(EnrichedBatch(header=header, decoded=decoded, side0=s0, side1=s1, keep=keep))
 
 
 class ListWriter:

@@ -35,8 +35,8 @@ tracer, `p` = the pipeline's / WindowManager's; a name lives on one):
         feeder.decode            f  the round's sink.decode_frame calls,
                                     summed, ONE record a round
         feeder.dispatch          f  sink.emit of one bucket batch
-          feeder.assemble        f  FlowBatch.concat of the chunks
-          ingest.stage           p  pad, np.stack, three uploads
+          feeder.assemble        f  the chunks' writes into the staging buffer
+          ingest.stage           p  three uploads of the staging buffer
           window.fold            p  fold dispatch when the ring is full
           ingest.dispatch        p  the fused step's dispatch
           stats.fetch            p  the per-batch counter-block sync
@@ -97,7 +97,7 @@ import numpy as np
 # The pipeline stage vocabulary (explicit names, ISSUE 3). Everything
 # the window managers emit uses these; ad-hoc names are allowed but the
 # docs/tests pin this set.
-SPAN_INGEST_STAGE = "ingest.stage"  # pad to bucket + np.stack + the three uploads
+SPAN_INGEST_STAGE = "ingest.stage"  # the three uploads of a staging buffer (a FlowBatch is first written into one)
 SPAN_INGEST_DISPATCH = "ingest.dispatch"  # fused jit step dispatch (async — host-side cost)
 SPAN_STATS_FETCH = "stats.fetch"  # the ONE per-batch device→host stats sync
 SPAN_WINDOW_ADVANCE = "window.advance"  # fold + flush_range dispatch on window close
@@ -140,7 +140,7 @@ SPAN_FEEDER_DRAIN = "feeder.drain"  # a round's queue gets
 SPAN_FEEDER_COALESCE = "feeder.coalesce"  # journal + decode + bucket assembly
 SPAN_FEEDER_DECODE = "feeder.decode"  # a round's decode_frame calls, summed
 SPAN_FEEDER_DISPATCH = "feeder.dispatch"  # staged batch → sink ingest
-SPAN_FEEDER_ASSEMBLE = "feeder.assemble"  # FlowBatch.concat of a batch's chunks
+SPAN_FEEDER_ASSEMBLE = "feeder.assemble"  # a batch's chunks written into its staging buffer, the stale tail zeroed
 FEEDER_SPAN_NAMES = (
     SPAN_FEEDER_PUMP,
     SPAN_FEEDER_DRAIN,

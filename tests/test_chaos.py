@@ -762,6 +762,7 @@ def test_single_buffer_dispatch_failure_restores_shed_carry():
     emit must go back into _shed_carry when the dispatch fails — the
     runtime re-arms only the shed IT passed in, so dropping the carry
     permanently undercounts the device-plane feeder_shed lane."""
+    from deepflow_tpu.datamodel.batch import FLOW_RECORD_TAG_FIELDS
     from deepflow_tpu.feeder.runtime import FlowChunk
 
     pipe = _mk_pipe()
@@ -773,7 +774,10 @@ def test_single_buffer_dispatch_failure_restores_shed_carry():
         chaos.FaultRule(chaos.SITE_DISPATCH, count=10**9, error=chaos.DeviceLost)
     ))
     with pytest.raises(chaos.DeviceLost):
-        sink.emit([FlowChunk(fb)], fb.size, 64, shed=2)
+        # a chunk is what a frame holds: the [T, n] matrix in wire order
+        chunk = FlowChunk(
+            np.stack([fb.tags[f] for f in FLOW_RECORD_TAG_FIELDS]), fb.meters)
+        sink.emit([chunk], fb.size, 64, shed=2)
     chaos.uninstall()
     assert sink.lost_records == 64
     assert sink._shed_carry == 5  # carried share restored, not dropped

@@ -9,7 +9,12 @@ import pytest
 
 from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
 from deepflow_tpu.aggregator.window import WindowConfig, WindowManager
-from deepflow_tpu.datamodel.batch import FlowBatch
+from deepflow_tpu.datamodel.batch import (
+    FLOW_RECORD_TAG_FIELDS,
+    STAGING_RING_LEN,
+    FlowBatch,
+    StagingRing,
+)
 from deepflow_tpu.datamodel.schema import FLOW_METER, TAG_SCHEMA
 from deepflow_tpu.feeder import (
     FeederConfig,
@@ -21,6 +26,7 @@ from deepflow_tpu.feeder import (
     encode_flowbatch_frames,
     peek_rows,
 )
+from deepflow_tpu.feeder.runtime import _FlowFrameCodec
 from deepflow_tpu.ingest.queues import PyOverwriteQueue, register_queue_stats
 from deepflow_tpu.ingest.replay import SyntheticFlowGen
 
@@ -282,6 +288,157 @@ def test_flowframe_rejects_garbage():
     with pytest.raises(ValueError, match="truncated"):
         decode_flowframe_body(body[:-8])
     assert peek_rows(b"\x00" * 3) == 0  # short peek is a 0, not a crash
+
+
+# ---------------------------------------------------------------------------
+# the staging buffer (ISSUE 31): a batch written once, in the upload's layout
+
+
+class _CaptureSink(_FlowFrameCodec):
+    """The flow sinks' decode and assembly with no device behind them:
+    every emitted batch's staged arrays are copied out."""
+
+    def __init__(self, bucket_sizes):
+        super().__init__(StagingRing(FLOW_METER.num_fields))
+        self.bucket_sizes = bucket_sizes
+        self.batches = []  # (tag_mat, meters, valid, rows, bucket)
+
+    def emit(self, chunks, rows, bucket, shed):
+        buf = self._assemble(chunks, rows, bucket)
+        self.batches.append((buf.tag_mat.copy(), buf.meters.copy(),
+                             buf.valid.copy(), rows, bucket))
+        return []
+
+    def flush(self):
+        return []
+
+
+def _old_staged_arrays(parts, bucket):
+    """What the upload read before ISSUE 31, kept as the reference:
+    FlowBatch.concat of the chunks, pad_to the bucket, np.stack of the
+    tag columns in sorted-name order."""
+    fb = FlowBatch.concat(parts).pad_to(bucket)
+    tag_mat = np.stack([np.asarray(fb.tags[k], dtype=np.uint32)
+                        for k in sorted(fb.tags)])
+    return tag_mat, fb.meters, fb.valid
+
+
+def _poison(ring, buckets):
+    """Every buffer of every bucket filled to its last row with
+    non-zero garbage, as an earlier, longer batch would leave it."""
+    for b in buckets:
+        tags = np.full((len(FLOW_RECORD_TAG_FIELDS), b), 0xDEADBEEF, np.uint32)
+        meters = np.full((b, FLOW_METER.num_fields), 7.5, np.float32)
+        for _ in range(STAGING_RING_LEN):
+            buf = ring.acquire(b)
+            buf.write(tags, meters)
+            buf.finish()
+
+
+STAGING_CASES = {
+    # name: (buckets, pumps: each a list of frames, a frame a list of
+    # message row counts)
+    "one_chunk": ((64, 256), [[[100]]]),
+    "52_x_2048": ((32768, 131072), [[[2048]] * 52]),
+    "chunk_split_by_take": ((64, 128), [[[50]] * 7]),
+    "rows_equal_bucket": ((64, 128), [[[64], [64]]]),
+    "under_smallest_bucket": ((64, 128), [[[5]]]),
+    "frame_of_several_messages": ((64, 128), [[[10, 20, 30], [7]]]),
+    "shorter_after_longer": ((64, 128), [[[120]], [[90]], [[3]], [[128]], [[1]]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STAGING_CASES))
+def test_staged_arrays_bit_for_bit_vs_concat_pad_stack(case):
+    """The writer's tag_mat / meters / valid equal the old formulation
+    on the same rows, batch by batch, and nothing a buffer held before
+    shows in the tail."""
+    from deepflow_tpu.ingest.framing import FlowHeader, MessageType, encode_frame
+
+    buckets, pumps = STAGING_CASES[case]
+    gen = SyntheticFlowGen(num_tuples=500, seed=31)
+    sink = _CaptureSink(buckets)
+    q = PyOverwriteQueue(1 << 10)
+    feeder = FeederRuntime([q], sink, FeederConfig(frames_per_queue=64))
+    _poison(sink.staging, buckets)
+    sent, t = [], 0
+    for frames in pumps:
+        for messages in frames:
+            fbs = [gen.flow_batch(n, T0 + (t := t + 1)) for n in messages]
+            q.put(encode_frame(
+                FlowHeader(msg_type=int(MessageType.TAGGEDFLOW), agent_id=1),
+                [encode_flowbatch_body(fb) for fb in fbs]))
+            sent += fbs
+        feeder.pump()
+    assert sink.decode_errors == 0 and sink.batches
+    # cut the sent rows where the feeder cut its batches, as _take did
+    pending = list(sent)
+    for tag_mat, meters, valid, rows, bucket in sink.batches:
+        parts, need = [], rows
+        while need:
+            fb = pending.pop(0)
+            if fb.size > need:
+                pending.insert(0, fb.slice(need, fb.size))
+                fb = fb.slice(0, need)
+            parts.append(fb)
+            need -= fb.size
+        want_tags, want_meters, want_valid = _old_staged_arrays(parts, bucket)
+        assert tag_mat.dtype == np.uint32 and meters.dtype == np.float32
+        np.testing.assert_array_equal(tag_mat, want_tags)
+        assert meters.tobytes() == np.ascontiguousarray(want_meters).tobytes()
+        np.testing.assert_array_equal(valid, want_valid)
+    assert not pending
+    assert feeder.get_counters()["records_out"] == sum(fb.size for fb in sent)
+
+
+def test_host_copy_bytes_is_rows_written_plus_the_zeroed_tail():
+    """[count] A record is 37 u32 tags + 62 f32 meters + its mask byte =
+    397 B, written once; the tail costs only what the buffer's previous
+    fill left past the new rows."""
+    record = 4 * len(FLOW_RECORD_TAG_FIELDS) + 4 * FLOW_METER.num_fields + 1
+    assert record == 397
+    gen = SyntheticFlowGen(num_tuples=100, seed=32)
+    sink = _CaptureSink((128,))
+    q = PyOverwriteQueue(64)
+    feeder = FeederRuntime([q], sink, FeederConfig())
+    want = 0
+    # one bucket, a ring of three: batch i reuses the buffer of batch i-3
+    sizes = [100, 128, 40, 60, 128, 90, 10, 5, 90]
+    for i, n in enumerate(sizes):
+        for fr in encode_flowbatch_frames(gen.flow_batch(n, T0 + i), max_rows_per_frame=50):
+            q.put(fr)
+        feeder.pump()
+        before = sizes[i - STAGING_RING_LEN] if i >= STAGING_RING_LEN else 0
+        want += record * (n + max(0, before - n))
+        assert feeder.get_counters()["host_copy_bytes"] == want, i
+    assert feeder.get_counters()["staging_waits"] == 0
+    assert sink.staging.allocated == STAGING_RING_LEN
+    # a frame of several messages is joined at decode: counted too
+    from deepflow_tpu.ingest.framing import FlowHeader, MessageType, encode_frame
+
+    q.put(encode_frame(
+        FlowHeader(msg_type=int(MessageType.TAGGEDFLOW), agent_id=1),
+        [encode_flowbatch_body(gen.flow_batch(n, T0 + 20)) for n in (4, 6)]))
+    feeder.pump()
+    want += (record - 1) * 10 + record * (10 + max(0, sizes[-3] - 10))
+    assert feeder.get_counters()["host_copy_bytes"] == want
+
+
+def test_ring_allocates_its_length_per_bucket_used():
+    """[count] 50 batches over two of three buckets make 2 x the ring's
+    length of buffers and no more; the bucket never used makes none."""
+    gen = SyntheticFlowGen(num_tuples=100, seed=33)
+    sink = _CaptureSink((32, 64, 128))
+    q = PyOverwriteQueue(64)
+    feeder = FeederRuntime([q], sink, FeederConfig())
+    for i in range(50):
+        n = 20 if i % 2 else 100
+        for fr in encode_flowbatch_frames(gen.flow_batch(n, T0 + i)):
+            q.put(fr)
+        feeder.pump()
+    assert len(sink.batches) == 50
+    assert sink.staging.allocated == 2 * STAGING_RING_LEN
+    assert sorted(b for b, _ in sink.staging._rings) == [32, 128]
 
 
 # ---------------------------------------------------------------------------

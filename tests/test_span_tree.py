@@ -49,6 +49,7 @@ NEW_LAYERS = (
     "stash.live_share", "step.doc_rows_per_record",  # PR 28
     "flush.docs_per_window", "flush.fetch_ms_per_window",
     "fold.live_block_share",  # PR 29
+    "feeder.host_copy_bytes_per_record",  # PR 31
 )
 
 

@@ -292,8 +292,8 @@ def test_live_block_share_layer_file_reads_the_two_sums():
     mod_spec.loader.exec_module(layers)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert len(bench["per_layer"]) == 23
-    entry = bench["per_layer"][-1]
+    assert len(bench["per_layer"]) == 24  # 23 when this one came; PR 31 appended one
+    entry = bench["per_layer"][22]
     layer = layers.load_layer("fold.live_block_share")
     assert {k: layer[k] for k in entry} == entry and "workloads" not in entry
     assert (entry["layer"], entry["moves"], entry["better"]) == ("fold", "records_per_s", "lower")
