@@ -2,7 +2,11 @@
 
 The chip belongs to the runner; this process makes the records from the
 seed (gen.py), encodes them as TAGGEDFLOW frames (wire.py) and writes
-them to the Receiver's TCP port. It keeps its own clock for the window:
+them to the Receiver's TCP port over the traffic file's `clients`
+connections (1 where it says nothing), dealing the frames to them in
+turn: frame i of the run goes out on connection i mod clients, so one
+client sends the frames in today's order and several send the same frames.
+It keeps its own clock for the window:
 the runner's `start <t0> <seconds>` line fixes both ends, and no frame
 goes out at or after t0 + seconds. The loop is closed: a frame goes out
 whenever fewer than the budget (`in_flight_event_seconds` event-seconds'
@@ -108,9 +112,12 @@ def main(argv=None) -> int:
         gen.FlowSource(schema, config["population"], a.seed, schedule.key_draw),
         schedule, schema)
     producer.start()
-    sock = socket.create_connection(("127.0.0.1", a.port), timeout=30)
-    sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-    sock.settimeout(None)
+    socks = []
+    for _ in range(int(traffic.get("clients", 1))):
+        sock = socket.create_connection(("127.0.0.1", a.port), timeout=30)
+        sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        sock.settimeout(None)
+        socks.append(sock)
     while producer.out.qsize() < 2:
         time.sleep(0.01)
     print(json.dumps({"ready": True}), flush=True)
@@ -118,13 +125,14 @@ def main(argv=None) -> int:
     start = sys.stdin.readline().split()
     if len(start) != 3 or start[0] != "start":  # the runner gave up
         producer.stop.set()
-        sock.close()
+        for sock in socks:
+            sock.close()
         return 1
     deadline = float(start[1]) + float(start[2])
     taken = Taken()
     taken.start()
     budget = schedule.budget
-    sent, total = [], 0
+    sent, total, dealt = [], 0, 0
     try:
         while time.monotonic() < deadline and not taken.quit:
             k, n, frames, check = producer.out.get()
@@ -135,7 +143,8 @@ def main(argv=None) -> int:
                     time.sleep(0.0005)
                 if taken.quit or time.monotonic() >= deadline:
                     break
-                sock.sendall(frame)
+                socks[dealt % len(socks)].sendall(frame)
+                dealt += 1
                 done += 1
                 total += min(schedule.rows, n - j * schedule.rows)
             if done:
@@ -148,7 +157,8 @@ def main(argv=None) -> int:
                 break
     finally:
         producer.stop.set()
-        sock.close()
+        for sock in socks:
+            sock.close()
     print(json.dumps({
         "done": True, "seconds": sent,
         "sent_records": sum(s["records"] for s in sent),

@@ -7,8 +7,9 @@ One process holds the chip. It builds the cell's deployment (sut.py),
 starts the load generator as a process of its own (loadgen.py), warms up
 every program the window uses, runs the window, reads the peak memory,
 checks what the window produced against the plain reference
-(reference.py; server_check.py for the store and query), and prints one
-JSON line last. Everything of one configuration, one traffic mix or one
+(reference.py; server_check.py for the store and query; then the checks
+the configuration names, checks/<name>.py), and prints one JSON line
+last. Everything of one configuration, one traffic mix or one
 per-layer metric is in a data file that BENCHMARK.json names; see
 README.md for how a later PR adds one.
 
@@ -47,6 +48,9 @@ import trace_reduce  # noqa: E402
 SAMPLED_WINDOWS = 3  # full windows compared row by row, beside the prefix
 IDLE_SLEEP_S = 0.0005  # drive_feeder's wait when a pump took nothing
 DRAIN_TIMEOUT_S = 120.0
+# where a configuration's `checks` names are looked for; a test appends a
+# directory of its own
+CHECK_DIRS = [os.path.join(HERE, "checks")]
 
 
 def say(**rec) -> None:
@@ -243,7 +247,7 @@ def run_window(served, generator, t0: float, seconds: float, slice_=None) -> dic
     flushed, on_host = [], {}
 
     def take(out, now):
-        for db in out:
+        for db in served.documents(out):
             flushed.append(db)
             on_host[int(db.timestamp[0])] = now
 
@@ -306,8 +310,9 @@ def by_window(docbatches: list) -> dict:
 
 
 def check(schema, source, schedule, sent_seconds, got, closed_in_window,
-          seed, served, store_dir) -> dict:
-    """Every compared number -> (value, limit).
+          seed, flushed, store_dir) -> dict:
+    """The base checks, which every configuration gets and none can switch
+    off: every compared number -> (value, limit).
 
     All windows: each holds exactly the records sent for it (the sum of
     `packet_tx` over its edge documents against the generator's own sum),
@@ -367,12 +372,41 @@ def check(schema, source, schedule, sent_seconds, got, closed_in_window,
         for k, lim in reference.LIMITS.items():
             numbers[f"oracle.{k}"] = (o[k], lim)
         srv = server_check.through_server(
-            *got[gen.T0], gen.T0, store_dir,
-            served.pipe.get_counters()["flushed_doc"])
+            *got[gen.T0], gen.T0, store_dir, *flushed)
         numbers.update({k: (srv[k], server_check.LIMITS[k]) for k in srv})
     else:
         numbers["prefix_window_missing"] = (1, 0)
     return numbers
+
+
+def guarantees_of(served) -> tuple:
+    """The counters held to 0: sut.GUARANTEE_COUNTERS, or the longer
+    list of a deployment that has more to lose."""
+    import sut
+
+    short = set(sut.GUARANTEE_COUNTERS) - set(served.guarantee_counters)
+    if short:
+        raise HarnessFailure(f"the deployment drops guarantee counters "
+                             f"{sorted(short)}: the list may grow, never shrink")
+    return tuple(served.guarantee_counters)
+
+
+def named_checks(names: list, ctx: dict, numbers: dict) -> None:
+    """The configuration's own checks, after the base checks and outside
+    the window: checks/<name>.py exports `check(ctx) -> {number: (value,
+    limit)}`, merged into `numbers`. `ctx`: schema, source, schedule,
+    sent_seconds (the generator's report of each event-second sent), got
+    (window -> (tags, meters) as by_window gives them), closed_in_window,
+    seed, config, side_outputs (the deployment's). A number that is
+    already there is an error: a named check adds, it replaces nothing."""
+    import sut
+
+    for name in names:
+        for k, pair in sut.load_named("check", name, CHECK_DIRS).check(ctx).items():
+            if k in numbers:
+                raise HarnessFailure(
+                    f"check {name!r} gives the number {k!r}, which is already compared")
+            numbers[k] = tuple(pair)
 
 
 # ---------------------------------------------------------------------------
@@ -393,22 +427,22 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     schema = gen.load_schema()
     schedule = gen.Schedule(traffic, schema["wire"]["rows_per_frame"])
     source = gen.FlowSource(schema, config["population"], seed, schedule.key_draw)
-    served = sut.Served(config)
+    served = sut.build(config)
     generator = Generator(spec["config_path"], spec["traffic_path"], seed, served.port)
     try:
+        guarantees = guarantees_of(served)
         if on_built is not None:
             on_built(served)
         warm_docs = served.warm_up(schema, source, schedule)
         generator.wait_ready()
         say(stage="set up", queue=served.queue_kind, warm_up_docs=warm_docs,
             slowest_compiles=clock.slowest(), **clock.read())
-        # What compiles inside the window (the program compiles at every
-        # window close whose document count is new) is paid in full by
-        # every run: with the persistent cache on (the program's default)
-        # a cell's second run would find those programs on disk only
-        # because the benchmark replays the same flows, which no
-        # deployment does. So no cache hit or miss can be counted in the
-        # window; the compiles themselves are.
+        # Nothing should compile inside the window: since PR 27 no program
+        # on the close path has a shape that depends on a document count.
+        # The persistent cache goes off all the same, so that a compile
+        # that comes back is paid in full by every run and counted
+        # (`compiles_in_window`), not hidden by a cell's second run finding
+        # on disk what the first one left there.
         persistent_cache(False)
         c0, s0, k0 = served.counters(), served.spans(), clock.read()
         slice_ = None
@@ -425,9 +459,12 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         compile_s = k1["compile_s"] - k0["compile_s"]
         report = win["report"]
         closed_in_window = set(win["on_host"])
-        after = served.pipe.drain()
+        after = served.documents(served.drain())
         got = by_window(win["flushed"] + after)
         counters = {k: c1[k] - c0.get(k, 0) for k in c1}
+        absent = [k for k in guarantees if k not in counters]
+        if absent:
+            raise HarnessFailure(f"the deployment does not count {absent}")
         spans = {n: {f: s1[n][f] - s0.get(n, {}).get(f, 0) for f in s1[n]}
                  for n in s1}
         folded = counters["feeder.records_out"] - counters["feeder.lost_records"]
@@ -438,15 +475,21 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
             compiles_in_window=k1["compiles"] - k0["compiles"],
             compile_s_in_window=compile_s,
             compiled_in_window=clock.programs[k0["compiles"]:k1["compiles"]],
-            jit_compiles=c1["pipeline.jit_compiles"])
+            jit_compiles=c1.get("pipeline.jit_compiles"))
 
         # the guarantees: nothing shed, lost, overwritten, refused, retraced
-        numbers = {k: (counters.get(k, 0), 0) for k in sut.GUARANTEE_COUNTERS}
+        numbers = {k: (counters[k], 0) for k in guarantees}
         numbers["records_unaccounted"] = (report["sent_records"] - folded, 0)
         t_check = time.monotonic()
         numbers.update(check(schema, source, schedule, report["seconds"], got,
-                             closed_in_window, seed, served,
+                             closed_in_window, seed,
+                             (served.flushed_docs(), served.stats_module),
                              os.path.join(workdir, "store")))
+        named_checks(config.get("checks", []), {
+            "schema": schema, "source": source, "schedule": schedule,
+            "sent_seconds": report["seconds"], "got": got,
+            "closed_in_window": closed_in_window, "seed": seed,
+            "config": config, "side_outputs": served.side_outputs()}, numbers)
         check_s = time.monotonic() - t_check
         correct = all(lim is None or v <= lim for v, lim in numbers.values())
         rows = schema["wire"]["rows_per_frame"]
@@ -469,6 +512,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
             # control cell that starts compiling there reports no such metric
             e2e["steady_records_per_s"] = e2e["records_per_s"]
         planes = {"spans": spans, "counters": counters, "generator": report,
+                  "schema": {"record_bytes": trace_reduce.record_bytes(schema)},
                   "run": {"windows_closed": len(closed_in_window),
                           "compile_s_in_window": compile_s,
                           "compiles_in_window": k1["compiles"] - k0["compiles"],
@@ -479,31 +523,28 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         breakdown = None
         if slice_ is not None and slice_.state == "closed" \
                 and dev.get("platform") == "tpu":  # no device plane off the chip
-            with open(os.path.join(HERE, "trace_groups.json")) as f:
-                groups = json.load(f)["modules"]
             events = trace_reduce.extract(trace_reduce.find_xplane(slice_.dir))
             host_spans = [(r.name, r.start_s, r.duration_us / 1e6)
-                          for tr in (served.feeder.tracer, served.pipe.tracer)
-                          for r in tr.recent()]
-            red = trace_reduce.reduce(events, groups, host_spans, slice_.anchor_wall)
-            peaks = trace_reduce.load_peaks(dev.get("kind", ""))
+                          for tr in served.tracers() for r in tr.recent()]
+            red = trace_reduce.reduce(events, trace_reduce.load_groups(),
+                                      host_spans, slice_.anchor_wall)
             slice_records = slice_.records1 - slice_.records0
-            red["fused_step_roofline_pct"] = trace_reduce.roofline_pct(
-                slice_records * trace_reduce.record_bytes(schema),
-                red["module_s"].get("fused_step", 0.0),
-                peaks["hbm_bytes_per_s"])
             red["slice_records"] = slice_records
             planes["trace"] = red
+            planes["peaks"] = trace_reduce.load_peaks(dev.get("kind", ""))
             dev["busy_s"], dev["window_s"] = red["busy_s"], red["window_s"]
             breakdown = {"device_ops": red["device_ops"],
                          "idle_gaps": red["idle_gaps"]}
             say(stage="trace", lines_seen=events["lines_seen"],
                 module_s=red["module_s"], longest_gap_s=red["longest_gap_s"],
-                slice_records=slice_records)
+                slice_records=slice_records, per_device=red["per_device"])
             shutil.rmtree(slice_.dir, ignore_errors=True)
 
         metrics = {}
         if trace:
+            # the window's raw counter deltas, for a reader of the run's
+            # output: the last line holds metrics only
+            say(stage="counters", counters=counters)
             for m in spec["per_layer"]:
                 value = layers.read_metric(layers.load_layer(m["name"]), planes)
                 if value is not None:

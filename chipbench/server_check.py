@@ -67,11 +67,14 @@ def _totals(srv, table: str, want_count: float, timeout_s: float = 20.0) -> dict
 
 
 def through_server(doc_tags: np.ndarray, doc_meters: np.ndarray, window: int,
-                   store_dir: str, flushed_doc: int) -> dict:
+                   store_dir: str, flushed_doc: int,
+                   stats_module: str = "tpu_pipeline") -> dict:
     """One window's documents as METRICS frames into the composed Server,
     then SQL totals of both tables and two PromQL sums over the server's
     own telemetry, each beside the number the same documents give in
-    NumPy. Returns the gaps (see LIMITS)."""
+    NumPy. `flushed_doc` is what the deployment says it flushed and
+    `stats_module` the name its telemetry reports that under. Returns the
+    gaps (see LIMITS)."""
     from deepflow_tpu.datamodel.batch import DocBatch
     from deepflow_tpu.datamodel.code import CodeId, DocumentFlag
     from deepflow_tpu.datamodel.schema import FLOW_METER, TAG_SCHEMA
@@ -140,7 +143,7 @@ def through_server(doc_tags: np.ndarray, doc_meters: np.ndarray, window: int,
             now = int(time.time()) + 1
             gap = 0.0
             for metric, want in (
-                (system_metric_name("tpu_pipeline", "flushed_doc"),
+                (system_metric_name(stats_module, "flushed_doc"),
                  float(flushed_doc)),
                 (system_metric_name("flow_metrics_ingester", "docs_written"),
                  float(len(msgs))),
@@ -157,7 +160,7 @@ def through_server(doc_tags: np.ndarray, doc_meters: np.ndarray, window: int,
             print("promql sources:", [
                 (p.module, p.timestamp, p.fields.get("flushed_doc"),
                  p.fields.get("docs_written"))
-                for m in ("tpu_pipeline", "flow_metrics_ingester")
+                for m in (stats_module, "flow_metrics_ingester")
                 for p in default_collector.recent(m)][-12:], file=sys.stderr)
         out["promql_gap"] = gap
         return out

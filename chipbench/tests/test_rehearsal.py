@@ -35,10 +35,12 @@ def test_refuses_to_run_without_a_tpu():
     (tiny.STEADY, 1, "l4_10k.steady",
      {"setup_s", "records_per_s", "steady_records_per_s"}),
     # flows this process has not closed yet, drawn anew each second, under
-    # the control cell's metric list: its closes compile, so the metric
-    # that carries the tight bound is not reported
-    (tiny.SATURATE, 903, "l4_10k.steady", {"setup_s", "records_per_s"}),
-], ids=["saturate", "steady", "steady_cell_that_compiles"])
+    # the control cell's metric list: since PR 27 a close with a new
+    # document count compiles nothing, so the metric that carries the
+    # tight bound is reported here too
+    (tiny.SATURATE, 903, "l4_10k.steady",
+     {"setup_s", "records_per_s", "steady_records_per_s"}),
+], ids=["saturate", "steady", "steady_cell_with_new_document_counts"])
 def test_last_line_shape(tmp_path, traffic, flows, cell, metrics):
     device = {"platform": "cpu", "kind": "cpu", "count": 1}
     out = chipbench_run.run_cell(
@@ -73,9 +75,9 @@ def test_traced_run_reports_no_device_metric_off_the_chip(tmp_path):
 
 
 def test_steady_traffic_compiles_nothing_in_the_window(tmp_path):
-    """One draw of flows serves every second and set-up closes seconds 0
-    and 1 once: every close of the window finds its programs compiled.
-    With the flows drawn anew each second, every close compiles."""
+    """Nothing compiles in the window, whether one draw of flows serves
+    every second or each second draws its own (a new document count at
+    every close): since PR 27 the close fetches fixed-size pages."""
     device = {"platform": "cpu", "kind": "cpu", "count": 1}
     per_window = {}
     for flows, traffic in ((901, tiny.STEADY), (902, tiny.SATURATE)):
@@ -84,5 +86,4 @@ def test_steady_traffic_compiles_nothing_in_the_window(tmp_path):
             workdir=str(tmp_path), device=device)
         assert out["correct"] is True, over_limit(out)
         per_window[traffic["name"]] = out["metrics"]["close.compile_ms_per_window"]["value"]
-    assert per_window["tiny_steady"] == 0.0
-    assert per_window["tiny_saturate"] > 0.0
+    assert per_window == {"tiny_steady": 0.0, "tiny_saturate": 0.0}

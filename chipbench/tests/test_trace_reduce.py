@@ -11,8 +11,7 @@ import gen
 import trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-with open(os.path.join(os.path.dirname(HERE), "trace_groups.json")) as f:
-    GROUPS = json.load(f)["modules"]
+GROUPS = trace_reduce.load_groups()
 
 
 def test_recorded_slice():
@@ -35,6 +34,49 @@ def test_recorded_slice():
                or name.startswith(("module:", "jit_"))
                for name, _s in red["device_ops"])
     assert red["idle_gaps"][0][0] == "unattributed"  # no host spans were given
+    # to the last digit what the parent commit's reduce() read from this
+    # file (c200a7a, before per-device numbers were returned beside them)
+    assert red["busy_s"] == 1.769335169
+    assert red["idle_share_pct"] == 19.57567413636364
+    assert red["module_s"] == {"jit_convert_element_type": 8.898e-06,
+                               "fused_step": 0.132207289, "fold": 1.637161951}
+    assert red["idle_gaps"] == [["unattributed", 0.430664831]]
+    assert red["longest_gap_s"] == 0.232240877
+    # one device: its own numbers are the means
+    assert red["per_device"] == {"plane": ["/device:TPU:0"],
+                                 "busy_s": [red["busy_s"]],
+                                 "module_s": [red["module_s"]]}
+
+
+def test_two_devices_read_each_and_the_mean():
+    """A synthesised two-device slice: device 1 runs the same step and a
+    fold twice as long. busy_s, module_s and idle_share_pct are means over
+    the devices; `per_device` gives each in the planes' order."""
+    ms = 1e6
+    events = {
+        "annotations": [["chipbench.anchor", 0.0, 1.0], ["chipbench.end", 100 * ms, 1.0]],
+        "devices": [
+            {"plane": "/device:TPU:0",
+             "modules": [["jit_step(1)", 10 * ms, 10 * ms],
+                         ["jit__fold_counted_impl(2)", 40 * ms, 20 * ms]],
+             "ops": [["%a = x", 10 * ms, 10 * ms], ["%b = y", 40 * ms, 20 * ms]]},
+            {"plane": "/device:TPU:1",
+             "modules": [["jit_step(1)", 10 * ms, 10 * ms],
+                         ["jit__fold_counted_impl(2)", 40 * ms, 40 * ms]],
+             "ops": [["%a = x", 10 * ms, 10 * ms], ["%b = y", 40 * ms, 40 * ms]]},
+        ],
+    }
+    red = trace_reduce.reduce(events, GROUPS)
+    assert red["per_device"]["plane"] == ["/device:TPU:0", "/device:TPU:1"]
+    assert red["per_device"]["busy_s"] == pytest.approx([0.030, 0.050])
+    assert red["per_device"]["module_s"] == [
+        {"fused_step": pytest.approx(0.010), "fold": pytest.approx(0.020)},
+        {"fused_step": pytest.approx(0.010), "fold": pytest.approx(0.040)}]
+    assert red["busy_s"] == pytest.approx(0.040)
+    assert red["module_s"] == {"fused_step": pytest.approx(0.010),
+                               "fold": pytest.approx(0.030)}
+    assert red["idle_share_pct"] == pytest.approx(60.0)
+    assert red["window_s"] == pytest.approx(0.100)
 
 
 def test_union_gaps_and_attribution():

@@ -113,9 +113,10 @@ def test_zipf_draw_is_skewed_and_the_active_set_changes_each_second():
 
 
 def test_every_full_window_of_saturate_has_another_document_count():
-    """What makes every close of l4_10k.saturate compile: at the cell's
-    own size, the first 12 event-seconds' windows hold 12 different
-    document counts; under `steady` they hold one."""
+    """What made every close of l4_10k.saturate compile before PR 27 (and
+    what the two traffic files still differ in): at the cell's own size,
+    the first 12 event-seconds' windows hold 12 different document
+    counts; under `steady` they hold one."""
     root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     with open(os.path.join(root, "chipbench", "configs", "l4_1s_10k.json")) as f:
         population = json.load(f)["population"]

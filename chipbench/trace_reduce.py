@@ -1,6 +1,7 @@
 """From a profiler trace to device metrics: busy and idle time, time by
 XLA module and by op, the longest idle gaps and what the host was doing in
-them, and the fused step's share of its roofline.
+them, and the arithmetic of a kernel's share of its roofline (what goes
+into it is a layer file's: layers.py).
 
 Two steps, so that the arithmetic can be checked on a small recorded trace
 (chipbench/tests/data/trace_events.json) without the profiler:
@@ -36,6 +37,31 @@ def load_peaks(device_kind: str) -> dict:
     if device_kind not in table:
         raise KeyError(f"no peaks for device kind {device_kind!r} in peaks.json")
     return table[device_kind]
+
+
+def load_groups(paths=None) -> dict:
+    """XLA module groups: `trace_groups.json` and every
+    `trace_groups/*.json` beside it (each {"modules": {group: [name
+    prefixes]}}). A later file adds groups; a group name or a prefix that
+    an earlier file has defined is an error, so none can be redefined."""
+    if paths is None:
+        paths = [os.path.join(HERE, "trace_groups.json")] + sorted(
+            glob.glob(os.path.join(HERE, "trace_groups", "*.json")))
+    groups: dict = {}
+    owner: dict = {}
+    for path in paths:
+        with open(path) as f:
+            modules = json.load(f)["modules"]
+        for group, prefixes in modules.items():
+            if group in groups:
+                raise ValueError(f"{path}: module group {group!r} is already defined")
+            for prefix in prefixes:
+                if prefix in owner:
+                    raise ValueError(f"{path}: prefix {prefix!r} already belongs "
+                                     f"to group {owner[prefix]!r}")
+                owner[prefix] = group
+            groups[group] = list(prefixes)
+    return groups
 
 
 def record_bytes(schema: dict) -> int:
@@ -111,7 +137,9 @@ def reduce(events: dict, groups: dict, host_spans=(), anchor_wall_s=None) -> dic
     duration_s); `anchor_wall_s` is the wall clock read when the anchor
     was written. Returns busy_s and window_s (averaged over the devices),
     idle_share_pct, seconds by module group, the top ops and the longest
-    idle gaps with the innermost host span open at each gap's middle."""
+    idle gaps with the innermost host span open at each gap's middle; and
+    under `per_device` each device plane's own busy_s and module_s, in
+    the planes' order (the means above are over these)."""
     marks = {name: (start, dur) for name, start, dur in events["annotations"]}
     if ANCHOR not in marks or END not in marks:
         raise ValueError("trace holds no anchor/end annotation")
@@ -120,14 +148,21 @@ def reduce(events: dict, groups: dict, host_spans=(), anchor_wall_s=None) -> dic
         raise ValueError("trace holds no device plane or an empty slice")
     window_s = (hi - lo) / 1e9
     busy_ns, by_op, by_module, gaps = 0.0, {}, {}, []
+    per_device = {"plane": [], "busy_s": [], "module_s": []}
     for dev in events["devices"]:
         ops = list(_clip(dev["ops"], lo, hi))
         busy = _union([(a, b) for _n, a, b in ops])
-        busy_ns += sum(b - a for a, b in busy)
+        dev_busy_ns = sum(b - a for a, b in busy)
+        busy_ns += dev_busy_ns
         mods = sorted((a, b, module_group(name, groups))
                       for name, a, b in _clip(dev["modules"], lo, hi))
+        dev_module: dict = {}
         for a, b, g in mods:
             by_module[g] = by_module.get(g, 0.0) + (b - a)
+            dev_module[g] = dev_module.get(g, 0.0) + (b - a)
+        per_device["plane"].append(dev.get("plane"))
+        per_device["busy_s"].append(dev_busy_ns / 1e9)
+        per_device["module_s"].append({g: v / 1e9 for g, v in dev_module.items()})
         starts = [m[0] for m in mods]
         for name, a, b in ops:
             # an op is named by its instruction and the module it ran in
@@ -158,6 +193,7 @@ def reduce(events: dict, groups: dict, host_spans=(), anchor_wall_s=None) -> dic
         "busy_s": busy_s, "window_s": window_s,
         "idle_share_pct": 100.0 * (1.0 - busy_s / window_s),
         "module_s": {k: v / 1e9 / n_dev for k, v in by_module.items()},
+        "per_device": per_device,
         "device_ops": top({**{f"module:{k}": v for k, v in by_module.items()},
                            **by_op}),
         "idle_gaps": top(by_gap),
