@@ -79,6 +79,15 @@ def _doc_fingerprint(doc_tags, with_excess: bool = False):
     return hi, lo
 
 
+# What a row of the sketch plane is (PR 33): the fused step and
+# `make_ingest_step` update the plane from the batch's rows as they came,
+# ahead of the pre-reduce, so `sketch_rows`, a block's `n_updates` and its
+# histogram count RECORDS. Before, they counted pre-reduced rows (one a
+# flow a batch), which depended on where the feeder cut its batches. A
+# deployment that promises one update a record asks for this by name.
+SKETCH_ROWS_ARE_RECORDS = True
+
+
 def batch_prereduce(tags, meters, valid, interval, cap, sum_cols, max_cols):
     """Batch-local pre-reduce BEFORE fanout: group raw rows by their
     full tag fingerprint (incl. timestamp) and reduce meters. Exact:
@@ -175,12 +184,10 @@ def make_ingest_step(fanout_config: FanoutConfig, interval: int = 1, app: bool =
         hi, lo = _doc_fingerprint(doc_tags)  # packed key words, no key_mat take
         window = (ts // jnp.uint32(interval)).astype(jnp.uint32)
         acc = _append_impl(acc, window, hi, lo, doc_tags, doc_meters, doc_valid, offset)
-        return stash, acc, tags, meters, valid
+        return stash, acc
 
     if sketch_config is None:
-        def append(stash, acc, offset, tags, meters, valid):
-            stash, acc, _, _, _ = _base_append(stash, acc, offset, tags, meters, valid)
-            return stash, acc
+        append = _base_append
     else:
         meter_ix = meter_schema.index
         # one-pass knob captured at BUILD time (ISSUE 17): the caller
@@ -192,22 +199,22 @@ def make_ingest_step(fanout_config: FanoutConfig, interval: int = 1, app: bool =
         shared_sort = _use_shared_sort()
 
         def append(stash, acc, offset, sk, tags, meters, valid, start_window):
-            stash, acc, r_tags, r_meters, r_valid = _base_append(
-                stash, acc, offset, tags, meters, valid
-            )
-            ts = jnp.asarray(r_tags["timestamp"], jnp.uint32)
+            # the plane takes the rows as they came, ahead of the
+            # pre-reduce: a record counts once (RollupPipeline._build_step)
+            ts = jnp.asarray(tags["timestamp"], jnp.uint32)
             base_w, close_w = sketch_span_bounds(
-                start_window, ts, r_valid, interval=interval, delay=delay
+                start_window, ts, valid, interval=interval, delay=delay
             )
             inp = sketch_inputs_from_columns(
-                r_tags, r_meters, sk.hll.shape[1], meter_ix
+                tags, meters, sk.hll.shape[1], meter_ix
             )
             sk = sketch_plane_step(
                 sk, sketch_config.hist,
-                window=ts // jnp.uint32(interval), valid=r_valid,
+                window=ts // jnp.uint32(interval), valid=valid,
                 base_w=base_w, close_w=close_w,
                 shared_sort=shared_sort, **inp,
             )
+            stash, acc = _base_append(stash, acc, offset, tags, meters, valid)
             return stash, acc, sk
 
     if fold_mode == "merge":
@@ -305,6 +312,13 @@ class RollupPipeline:
         # stance as the device pending buffer).
         self.closed_sketches: list = []
         self.max_held_sketches = 512
+        if config.window.sketch is not None:
+            # the cap is one of bytes too (PR 33): at a deployment's
+            # widths a block is tens of megabytes unpacked (43 MB at 512
+            # groups, p = 14), and 512 of them would be 22 GB of host
+            # memory; ~2.4 GB of blocks, and never fewer than four
+            block_bytes = 4 * config.window.sketch.block_width
+            self.max_held_sketches = max(4, min(512, (2 << 30) // block_bytes))
         self.sketch_blocks_dropped = 0
         # rollup-cascade tier outputs (ISSUE 9): merged tier sketch
         # blocks held for the sketch sink, same bounded stance
@@ -390,10 +404,13 @@ class RollupPipeline:
         def _sketch(sk, tags, meters, valid, start_window):
             """Per-window plane update from the RAW flow rows (ISSUE 8):
             pre-fanout, so a flow counts once — doc-lane replication
-            would multiply every CMS/top-K weight by FANOUT_LANES. With
-            the pre-reduce on, the post-reduce rows carry the summed
-            meters, so weights stay exact. Traced into the same fused
-            step — zero extra dispatches or fetches."""
+            would multiply every CMS/top-K weight by FANOUT_LANES — and
+            since PR 33 ahead of the pre-reduce too, so a RECORD counts
+            once: rows the pre-reduce had merged gave the histogram one
+            mean latency and `n_updates` one row for however many of a
+            flow's records one batch happened to hold, which made both
+            depend on where the feeder cut its batches. Traced into the
+            same fused step — zero extra dispatches or fetches."""
             ts = jnp.asarray(tags["timestamp"], jnp.uint32)
             base_w, close_w = sketch_span_bounds(
                 start_window, ts, valid, interval=interval, delay=delay
@@ -413,14 +430,14 @@ class RollupPipeline:
             # a device profile can say which one an op belongs to
             tags = {k: tag_mat[i] for i, k in enumerate(names)}
             aux = None
+            if sk is not None:
+                with jax.named_scope("step.sketch"):
+                    sk = _sketch(sk, tags, meters, valid, start_window)
             if cap_u is not None:
                 with jax.named_scope("step.prereduce"):
                     tags, meters, valid, aux = batch_prereduce(
                         tags, meters, valid, interval, cap_u, sum_cols, max_cols
                     )
-            if sk is not None:
-                with jax.named_scope("step.sketch"):
-                    sk = _sketch(sk, tags, meters, valid, start_window)
             with jax.named_scope("step.fanout"):
                 doc_tags, doc_meters, ts, doc_valid = fanout_fn(
                     tags, meters, valid, fanout_cfg

@@ -74,6 +74,7 @@ from ..utils.spans import (
     SPAN_FLUSH_FETCH,
     SPAN_FLUSH_JOIN,
     SPAN_FLUSH_ROWS,
+    SPAN_FLUSH_SKETCH,
     SPAN_FLUSH_SPLIT,
     SPAN_FLUSH_WAIT,
     SPAN_INGEST_DISPATCH,
@@ -132,14 +133,17 @@ def _take_page(x, start, *, rows: int, axis: int = 0):
 
 class _PagedRows:
     """The first `n` rows of device array `x` along `axis`, as pages of
-    min(PAGE_ROWS, rows of x): `pages` are the device handles to fetch
+    min(`page_rows`, rows of x): `pages` are the device handles to fetch
     (none when n == 0; `x` itself when it is under one page) and `join`
-    cuts the fetched pages back to exactly those `n` rows."""
+    cuts the fetched pages back to exactly those `n` rows. `page_rows`
+    is PAGE_ROWS unless the caller's rows are wide: a packed sketch
+    block is megabytes a row, so its part pages by the row."""
 
-    def __init__(self, x, n: int, axis: int = 0):
+    def __init__(self, x, n: int, axis: int = 0, page_rows: int | None = None):
         size = x.shape[axis]
         self.n, self.axis = int(n), axis
-        self.page = min(PAGE_ROWS, size)
+        self.page = min(page_rows or PAGE_ROWS, size)
+        self.row_bytes = x.nbytes // max(size, 1)
         # dynamic_slice moves a start past this back to it
         self.last_start = size - self.page
         self._no_rows = (x.shape[:axis] + (0,) + x.shape[axis + 1:], x.dtype)
@@ -843,6 +847,13 @@ class WindowManager:
         self.flush_pages = 0
         self.flush_rows_fetched = 0
         self.flush_rows_live = 0
+        # the sketch plane's share of the drains (zero with the plane
+        # off): blocks handed over with their windows, the bytes of
+        # packed block rows (`pend` pages, closed wide slots) the drains
+        # fetched, and the bytes of the blocks they wanted
+        self.sketch_blocks_closed = 0
+        self.sketch_bytes_fetched = 0
+        self.sketch_bytes_live = 0
         self.feeder_shed = 0  # CB_FEEDER_SHED lane mirror
         # live read plane (ISSUE 10): host-authoritative snapshot
         # counters + the cached [reads, bytes] device vector riding into
@@ -988,18 +999,25 @@ class WindowManager:
         # (sketch-only coverage) still closes there
         with self.tracer.span(SPAN_FLUSH_ROWS):
             parts = [_PagedRows(entry.packed, total)]
+            sk_parts = []  # (packed block rows of this drain, how many it wants)
             if has_sketch:
-                parts += [_PagedRows(entry.pend, n_blocks),
-                          _PagedRows(entry.pend_win, n_blocks)]
+                # a page of ONE block: a drain that holds one closed
+                # block fetches that block, not all of `pend`
+                sk_parts.append(
+                    (_PagedRows(entry.pend, n_blocks, page_rows=1), n_blocks))
+                parts += [sk_parts[-1][0], _PagedRows(entry.pend_win, n_blocks)]
             if n_wide:
                 pw = entry.wide_rows.shape[0]  # every slot: the host filters
-                parts += [_PagedRows(entry.wide_rows, pw),
-                          _PagedRows(entry.wide_wins, pw)]
+                sk_parts.append((_PagedRows(entry.wide_rows, pw), n_wide))
+                parts += [sk_parts[-1][0], _PagedRows(entry.wide_wins, pw)]
             parts += [_PagedRows(tf.packed, t)
                       for tf, t in zip(entry.tiers, tier_totals)]
             self.flush_pages += sum(len(p.pages) for p in parts)
             self.flush_rows_fetched += sum(p.rows_fetched for p in parts)
             self.flush_rows_live += sum(p.n for p in parts)
+            for part, wanted in sk_parts:
+                self.sketch_bytes_fetched += part.rows_fetched * part.row_bytes
+                self.sketch_bytes_live += wanted * part.row_bytes
             got = iter(self._fetch_parts(parts))
             rows = next(got)
             blocks = (next(got), next(got)) if has_sketch else None
@@ -1026,12 +1044,14 @@ class WindowManager:
         arena's slots, each as (rows, window ids)) and marry them to the
         windows, then build the tier windows from `tier_rows`."""
         flushed = []
-        if blocks is not None:
-            self._hold_sketch_blocks(*blocks)
-        if wide is not None:
-            w_rows, w_wins = wide
-            keep = w_wins != np.uint32(SENTINEL_WIN)
-            self._hold_sketch_blocks(w_rows[keep], w_wins[keep])
+        if blocks is not None or wide is not None:
+            with self.tracer.span(SPAN_FLUSH_SKETCH):
+                if blocks is not None:
+                    self._hold_sketch_blocks(*blocks)
+                if wide is not None:
+                    w_rows, w_wins = wide
+                    keep = w_wins != np.uint32(SENTINEL_WIN)
+                    self._hold_sketch_blocks(w_rows[keep], w_wins[keep])
         if rows.shape[0]:
             flushed = self._split_flushed(rows, rows.shape[0])
         # marry blocks to this drain's window range; blocks whose exact
@@ -1058,6 +1078,7 @@ class WindowManager:
                     )
                 )
         flushed.sort(key=lambda f: f.window_idx)
+        self.sketch_blocks_closed += sum(f.sketches is not None for f in flushed)
         lin = self.lineage
         if lin is not None and flushed:
             lin.note_flush_windows([(f.window_idx, f.count) for f in flushed])
@@ -1780,6 +1801,9 @@ class WindowManager:
             "flush_pages": self.flush_pages,
             "flush_rows_fetched": self.flush_rows_fetched,
             "flush_rows_live": self.flush_rows_live,
+            "sketch_blocks_closed": self.sketch_blocks_closed,
+            "sketch_bytes_fetched": self.sketch_bytes_fetched,
+            "sketch_bytes_live": self.sketch_bytes_live,
             # transient-failure lanes (ISSUE 6): non-zero means the
             # retry policy absorbed device hiccups
             "dispatch_retries": self.dispatch_retries,

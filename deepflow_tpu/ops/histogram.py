@@ -13,11 +13,11 @@ centroids (ops/tdigest.py) for compact export.
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,11 +38,26 @@ def loghist_init(num_groups: int, spec: LogHistSpec) -> jnp.ndarray:
     return jnp.zeros((num_groups, spec.bins), dtype=jnp.int32)
 
 
+def loghist_edges(spec: LogHistSpec) -> np.ndarray:
+    """[bins - 1] f32: the lower edges of bins 1 … bins-1, vmin·gamma^k
+    computed in float64 and rounded once to float32. The table IS the
+    binning rule: a host reference that holds the same table bins every
+    value as the device does."""
+    k = np.arange(1, spec.bins, dtype=np.float64)
+    return (spec.vmin * np.power(spec.gamma, k)).astype(np.float32)
+
+
 def loghist_bin(values: jnp.ndarray, spec: LogHistSpec) -> jnp.ndarray:
-    """[N] f32 values → [N] i32 bin ids."""
-    v = jnp.maximum(values.astype(jnp.float32), spec.vmin)
-    b = jnp.floor(jnp.log(v / spec.vmin) / math.log(spec.gamma)).astype(jnp.int32)
-    return jnp.clip(b, 0, spec.bins - 1)
+    """[N] f32 values → [N] i32 bin ids: the number of edges at or
+    below the value (bin(v) = floor(log_gamma(v / vmin)) cut to
+    0 … bins-1, decided by float32 comparisons against `loghist_edges`
+    and by no platform's `log`: on the TPU a float32 log is some 30 ulp
+    off, which moved a value near an edge into the neighbouring bin and
+    made a closed block differ from the same records binned on a host,
+    PR 33)."""
+    v = values.astype(jnp.float32)
+    edges = jnp.asarray(loghist_edges(spec))
+    return jnp.sum(v[..., None] >= edges, axis=-1, dtype=jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("spec",), donate_argnums=(0,))

@@ -50,6 +50,8 @@ tracer, `p` = the pipeline's / WindowManager's; a name lives on one):
               flush.join         p  the host's cut and concatenate
             flush.split          p  unpack, per-window split, sketch
                                     and tier marrying
+              flush.sketch       p  sketch plane on: the drain's packed
+                                    block rows unpacked and held
       feeder.dispatch            f  the pump's sub-bucket tail emit
     checkpoint.save, query.snapshot, query.cache   p  roots
     xla.compile                  a ring record (no aggregate of its
@@ -119,6 +121,11 @@ SPAN_FLUSH_SPLIT = "flush.split"
 # out of FLUSH_SPAN_NAMES (the page dispatches are flush.rows' own).
 SPAN_FLUSH_FETCH = "flush.fetch"
 SPAN_FLUSH_JOIN = "flush.join"
+# flush.split's sketch half (PR 33), where a closed block is tens of
+# megabytes: `unpack_drained` + `_hold_sketch_blocks` of one drain. Host
+# NumPy only, so it cannot compile and stays out of FLUSH_SPAN_NAMES;
+# absent from a manager without the plane.
+SPAN_FLUSH_SKETCH = "flush.sketch"
 FLUSH_SPAN_NAMES = (
     SPAN_FLUSH_DRAIN, SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT
 )

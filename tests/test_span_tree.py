@@ -50,6 +50,9 @@ NEW_LAYERS = (
     "flush.docs_per_window", "flush.fetch_ms_per_window",
     "fold.live_block_share",  # PR 29
     "feeder.host_copy_bytes_per_record",  # PR 31
+    # PR 33, the sketch cell's own (they carry a `workloads` list)
+    "sketch.flush_ms_per_window", "sketch.fetched_bytes_per_block_byte",
+    "sketch.rows_per_record",
 )
 
 
@@ -173,14 +176,12 @@ def _pump_until_taken(feeder, want: int, base_in: int, flushed: list) -> None:
             time.sleep(0.001)
 
 
-@pytest.fixture(scope="module")
-def tiny_run(chipbench_modules):
+def _tiny_run(m, config):
     """Receiver -> queues -> FeederRuntime -> PipelineFeedSink ->
     L4Pipeline at tiny.py's sizes: five event-seconds over TCP, pumped
     until taken, then planes in the harness's shape (run.py)."""
-    m = chipbench_modules
     schema = m["gen"].load_schema()
-    served = m["sut"].Served(m["tiny"].CONFIG)
+    served = m["sut"].build(config)
     try:
         source = m["gen"].FlowSource(schema, m["tiny"].CONFIG["population"], 5)
         c0, s0 = served.counters(), served.spans()
@@ -213,6 +214,23 @@ def tiny_run(chipbench_modules):
         }
     finally:
         served.close()
+
+
+@pytest.fixture(scope="module")
+def tiny_run(chipbench_modules):
+    yield from _tiny_run(chipbench_modules, chipbench_modules["tiny"].CONFIG)
+
+
+@pytest.fixture(scope="module")
+def tiny_sketch_run(chipbench_modules):
+    """The same run with the sketch plane on, built as the sketch cell's
+    configuration names it (`built_by`: chipbench/deployments/l4_sketch.py)."""
+    tiny = chipbench_modules["tiny"].CONFIG
+    yield from _tiny_run(chipbench_modules, {
+        **tiny, "built_by": "l4_sketch", "pipeline": {**tiny["pipeline"], "sketch": {
+            "num_groups": 16, "hll_precision": 12, "cms_depth": 4, "cms_width": 4096,
+            "hist_bins": 256, "hist_vmin": 1.0, "hist_gamma": 1.04,
+            "topk_rows": 2, "topk_cols": 512, "pool": None, "pending": 4}}})
 
 
 def test_served_path_emits_every_leaf_span_with_its_count(tiny_run):
@@ -288,13 +306,23 @@ def test_ring_records_name_their_parents(tiny_run):
 
 
 @pytest.mark.parametrize("name", NEW_LAYERS)
-def test_new_layer_file_reads_a_number_from_a_tiny_run(name, tiny_run, chipbench_modules):
+def test_new_layer_file_reads_a_number_from_a_tiny_run(name, request, chipbench_modules):
     layers = chipbench_modules["layers"]
     spec = layers.load_layer(name)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         entry, = [m for m in json.load(f)["per_layer"] if m["name"] == name]
     assert {k: spec[k] for k in entry} == entry  # the file and its entry agree
-    value = layers.read_metric(spec, tiny_run["planes"])
+    sketch = name.startswith("sketch.")
+    assert ("workloads" in entry) == sketch  # only the sketch cell reports its own
+    run = request.getfixturevalue("tiny_sketch_run" if sketch else "tiny_run")
+    value = layers.read_metric(spec, run["planes"])
+    if sketch:
+        # a run without the plane reads nothing (its `sketch_rows` lane
+        # rides every counter block and reads 0: no record passed a plane)
+        plain = request.getfixturevalue("tiny_run")["planes"]
+        assert not layers.read_metric(spec, plain)
+        if name != "sketch.flush_ms_per_window":  # one block a page, every record
+            assert value == 1.0
     assert isinstance(value, float) and value >= 0.0
     if name != "flush.compile_ms_per_window":  # 0.0 where no close compiled
         assert value > 0.0

@@ -292,7 +292,8 @@ def test_live_block_share_layer_file_reads_the_two_sums():
     mod_spec.loader.exec_module(layers)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert len(bench["per_layer"]) == 24  # 23 when this one came; PR 31 appended one
+    # 23 when this one came; PR 31 appended one, PR 33 the sketch cell's three
+    assert len(bench["per_layer"]) == 27
     entry = bench["per_layer"][22]
     layer = layers.load_layer("fold.live_block_share")
     assert {k: layer[k] for k in entry} == entry and "workloads" not in entry
