@@ -613,13 +613,20 @@ stash_snapshot_range = jax.jit(_snapshot_range_impl)
 
 def unpack_flush_rows(rows: np.ndarray, num_tags: int):
     """Split fetched packed flush rows ([n, 3+T+M] u32, host) back into
-    (window, key_hi, key_lo, tags [n, T], meters [n, M] f32)."""
+    (window, key_hi, key_lo, tags [n, T], meters [n, M] f32).
+
+    Every output is a VIEW of `rows`: nothing is copied. The meters are
+    the row's last M words read as float32 (a `.view` between two 4-byte
+    dtypes needs no contiguity), so like the tags they keep the
+    matrix's strides, whatever its memory order (row-major from the CPU
+    backend, column-major from a TPU), and share its memory: whoever
+    holds an output holds the whole matrix, and a write through one
+    shows in `rows`."""
     t0 = FLUSH_META_COLS
-    meters = np.ascontiguousarray(rows[:, t0 + num_tags :]).view(np.float32)
     return (
         rows[:, 0],
         rows[:, 1],
         rows[:, 2],
         rows[:, t0 : t0 + num_tags],
-        meters,
+        rows[:, t0 + num_tags :].view(np.float32),
     )

@@ -73,6 +73,7 @@ from ..utils.spans import (
     SPAN_FLUSH_DRAIN,
     SPAN_FLUSH_FETCH,
     SPAN_FLUSH_JOIN,
+    SPAN_FLUSH_RESERVE,
     SPAN_FLUSH_ROWS,
     SPAN_FLUSH_SKETCH,
     SPAN_FLUSH_SPLIT,
@@ -131,15 +132,52 @@ def _take_page(x, start, *, rows: int, axis: int = 0):
     return jax.lax.dynamic_slice_in_dim(x, start, rows, axis=axis)
 
 
+# What a drain reserves for its exact rows beyond the rows it expects
+# (the rows per window of the manager's last drain x the windows it
+# closes): 1/32 of them. A window's document count moves ~1% from one
+# second to the next in both benchmark deployments, and every reserved
+# row is touched and, past the live count, wasted; a drain that outgrows
+# its reserve joins the old way.
+RESERVE_MARGIN_SHIFT = 5
+
+
+def reserve_rows(expected: int) -> int:
+    """Rows a drain that expects `expected` exact rows reserves."""
+    return expected + (expected >> RESERVE_MARGIN_SHIFT)
+
+
+def _memory_order(a: np.ndarray) -> str:
+    """"F" for a column-major host array, else "C". A fetched page has
+    the order the device program gave its output: the TPU lays a
+    `[rows, 99]` u32 matrix out with the rows minor (`{0,1}`) and the
+    host array keeps that, the CPU backend hands back row-major."""
+    return "F" if a.ndim > 1 and a.strides[0] < a.strides[-1] else "C"
+
+
+def _touched_rows(rows: int, width: int, order: str) -> np.ndarray:
+    """A fresh `[rows, width]` u32 array in memory order `order` whose
+    memory is already the process's: one word written every 4 KiB, so
+    every page is faulted in (and zeroed by the kernel) here and not
+    under the first copy into it. On the chip's host that is ~0.93 ms a
+    MB, nearly all of what a fresh `np.concatenate` costs (PERF.md §6,
+    PR 34); an allocation of this size is mapped anew every time."""
+    flat = np.empty(rows * width, np.uint32)
+    flat[::1024] = 0
+    return flat.reshape((rows, width), order=order)
+
+
 class _PagedRows:
     """The first `n` rows of device array `x` along `axis`, as pages of
     min(`page_rows`, rows of x): `pages` are the device handles to fetch
     (none when n == 0; `x` itself when it is under one page) and `join`
     cuts the fetched pages back to exactly those `n` rows. `page_rows`
     is PAGE_ROWS unless the caller's rows are wide: a packed sketch
-    block is megabytes a row, so its part pages by the row."""
+    block is megabytes a row, so its part pages by the row. `dst`, a
+    host array the caller reserved, is where `join` writes the rows if
+    it holds them all in the pages' memory order (axis 0 only)."""
 
-    def __init__(self, x, n: int, axis: int = 0, page_rows: int | None = None):
+    def __init__(self, x, n: int, axis: int = 0, page_rows: int | None = None,
+                 dst: np.ndarray | None = None):
         size = x.shape[axis]
         self.n, self.axis = int(n), axis
         self.page = min(page_rows or PAGE_ROWS, size)
@@ -147,6 +185,14 @@ class _PagedRows:
         # dynamic_slice moves a start past this back to it
         self.last_start = size - self.page
         self._no_rows = (x.shape[:axis] + (0,) + x.shape[axis + 1:], x.dtype)
+        self.dst = dst
+        # what the last `join` saw and did: the fetched pages' memory
+        # order (None: no pages), whether the rows landed in `dst`, and
+        # the bytes of the fresh array it made (0: a view of a fetched
+        # page, or rows written into `dst`)
+        self.order: str | None = None
+        self.landed = False
+        self.joined_bytes = 0
         if self.page == size:
             self.pages = [x] if self.n else []
         else:
@@ -160,14 +206,36 @@ class _PagedRows:
         return len(self.pages) * self.page
 
     def join(self, fetched: list) -> np.ndarray:
+        """The `n` live rows of the fetched pages as ONE host array the
+        caller owns, in the pages' memory order: a view of the page when
+        there is one and nothing was reserved; the cuts written into
+        `dst[:n]` when the reserved destination holds them and has the
+        pages' order (each row is written once, into memory that was
+        touched before: the copy alone); else a fresh `np.concatenate`
+        of them, whose first-touch page faults cost several times the
+        copy. A destination of the other order would turn the copy into
+        a transpose, which costs as much as the faults: it is left."""
         cut = []
         lead = (slice(None),) * self.axis
         for s, page in zip(range(0, self.n, self.page), fetched):
             at = min(s, self.last_start)  # where the page really starts
             cut.append(page[lead + (slice(s - at, min(self.n, s + self.page) - at),)])
+        self.order, self.landed, self.joined_bytes = None, False, 0
         if not cut:
             return np.zeros(*self._no_rows)
-        return cut[0] if len(cut) == 1 else np.concatenate(cut, axis=self.axis)
+        self.order = _memory_order(fetched[0])
+        dst = self.dst
+        if (dst is not None and self.n <= dst.shape[0]
+                and _memory_order(dst) == self.order):
+            self.landed = True
+            out = dst[: self.n]
+            np.concatenate(cut, axis=self.axis, out=out)
+            return out
+        if len(cut) == 1:
+            return cut[0]
+        out = np.concatenate(cut, axis=self.axis)
+        self.joined_bytes = out.nbytes
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -702,7 +770,18 @@ class FlushedWindow:
 
     tags/meters are row-major ([n, T] u32 / [n, M] f32) — already
     unpacked from the single flush matrix, so consumers index rows
-    directly instead of masking full-capacity device planes."""
+    directly instead of masking full-capacity device planes. Both, and
+    key_hi / key_lo, are VIEWS of the one `[rows, 3+T+M]` u32 matrix the
+    drain fetched and joined (`unpack_flush_rows`), in the memory order
+    the fetch gave it and never C-contiguous: from the CPU backend
+    row-major with the matrix's row stride (396 B for the flow schema),
+    from a TPU column-major (XLA lays the matrix out with the rows
+    minor: every tag and meter column is contiguous, a row is strided).
+    Index rows or columns and assume no strides. The consumer owns
+    them: the manager keeps no reference to that matrix, never writes
+    to it again and reuses it for no later drain, so a window handed on
+    stays as it was; a consumer that wants contiguous columns copies
+    (`storage/store.py` does)."""
 
     window_idx: int  # absolute window index (timestamp // interval)
     start_time: int  # window start in seconds
@@ -847,6 +926,16 @@ class WindowManager:
         self.flush_pages = 0
         self.flush_rows_fetched = 0
         self.flush_rows_live = 0
+        # the drains' host half: exact rows per window of the last drain
+        # and the memory order of its fetched pages (what the next one
+        # sizes and shapes its reserve from; 0 rows = no history), exact
+        # rows that were joined into a reserved destination, and the
+        # bytes of every host array the drains made after the fetch (a
+        # reserve, whole; a part's fresh join result)
+        self._drain_rows_per_window = 0
+        self._drain_order = "C"
+        self.flush_rows_reserved = 0
+        self.flush_host_write_bytes = 0
         # the sketch plane's share of the drains (zero with the plane
         # off): blocks handed over with their windows, the bytes of
         # packed block rows (`pend` pages, closed wide slots) the drains
@@ -947,6 +1036,26 @@ class WindowManager:
             return [part.join([next(got) for _ in part.pages]) for part in parts]
 
     # -- device→host drains ---------------------------------------------
+    def _reserve_rows(self, entry: "_FlushEntry", windows: int) -> np.ndarray | None:
+        """The host array this drain's exact rows will be joined into,
+        made and touched BEFORE the blocking scalar fetch, while the
+        device runs the fold and the range flush: sized from what the
+        manager knows without a fetch, the rows per window of its last
+        drain x the `windows` this entry can hold rows of, plus
+        `reserve_rows`' margin, never more than the stash, in the memory
+        order that drain's pages came in. None (the join allocates, as
+        before) with no history or where the expected rows fit one page,
+        which `join` hands on as a view of the fetched page with no
+        copy."""
+        size = entry.packed.shape[0]
+        rows = min(reserve_rows(self._drain_rows_per_window * windows), size)
+        if rows <= min(PAGE_ROWS, size):
+            return None
+        with self.tracer.span(SPAN_FLUSH_RESERVE):
+            dst = _touched_rows(rows, entry.packed.shape[1], self._drain_order)
+        self.flush_host_write_bytes += dst.nbytes
+        return dst
+
     def _drain_flush(self, entry: "_FlushEntry") -> list[FlushedWindow]:
         """Fetch ONE packed flush result and split it into windows.
 
@@ -959,7 +1068,17 @@ class WindowManager:
         window ids, tier rows per tier — each part paged at its own
         size and cut to its live rows on the host), so the ≤3-fetch
         budget is untouched (tests/test_perf_gate.py) and nothing
-        dispatched here has a shape that depends on a count."""
+        dispatched here has a shape that depends on a count.
+
+        Between the fetch and the hand-over a row is written to host
+        memory ONCE: the pages' live cuts are joined into a destination
+        that `_reserve_rows` made and touched under `flush.wait`, while
+        the device still ran the fold (a miss - no history, more rows
+        than reserved, pages of another memory order - joins into a
+        fresh array as before), and the split hands on views of that one
+        matrix. The matrix leaves with the windows: the manager keeps no
+        reference and never writes to it again, and no later drain
+        reuses it."""
         has_sketch = entry.pend is not None
         # pooled sketch memory (ISSUE 20): closed WIDE slots ride the
         # same two transfers. The scalar vector widens by one lane
@@ -983,6 +1102,11 @@ class WindowManager:
                 )
             scalars += [jnp.asarray(tf.total, jnp.int32) for tf in entry.tiers]
             n_wide = 0
+            # windows this entry can hold rows of: an advance's hi - lo,
+            # at most the open span (`flush_all` and a jump in time name
+            # a wider range)
+            windows = min(entry.hi - entry.lo, self.config.ring - 1)
+            reserved = self._reserve_rows(entry, windows)
             if len(scalars) == 1:
                 total, n_blocks, tier_totals = int(self._fetch(scalars[0])), 0, []
             else:
@@ -998,7 +1122,9 @@ class WindowManager:
         # range, and a tier window whose exact rows were all shed
         # (sketch-only coverage) still closes there
         with self.tracer.span(SPAN_FLUSH_ROWS):
-            parts = [_PagedRows(entry.packed, total)]
+            # only the exact rows are joined into a reserve: blocks, window
+            # ids and tier rows are small or page by the block
+            parts = [_PagedRows(entry.packed, total, dst=reserved)]
             sk_parts = []  # (packed block rows of this drain, how many it wants)
             if has_sketch:
                 # a page of ONE block: a drain that holds one closed
@@ -1019,6 +1145,12 @@ class WindowManager:
                 self.sketch_bytes_fetched += part.rows_fetched * part.row_bytes
                 self.sketch_bytes_live += wanted * part.row_bytes
             got = iter(self._fetch_parts(parts))
+            self.flush_host_write_bytes += sum(p.joined_bytes for p in parts)
+            exact = parts[0]
+            self._drain_rows_per_window = -(-total // windows)
+            self._drain_order = exact.order or self._drain_order
+            if exact.landed:
+                self.flush_rows_reserved += total
             rows = next(got)
             blocks = (next(got), next(got)) if has_sketch else None
             wide = (next(got), next(got)) if n_wide else None
@@ -1801,6 +1933,12 @@ class WindowManager:
             "flush_pages": self.flush_pages,
             "flush_rows_fetched": self.flush_rows_fetched,
             "flush_rows_live": self.flush_rows_live,
+            # the drains' host half: exact rows joined into a destination
+            # reserved under flush.wait, and the bytes of every host array
+            # the drains made after the fetch (reserves whole, fresh join
+            # results); the split copies nothing
+            "flush_rows_reserved": self.flush_rows_reserved,
+            "flush_host_write_bytes": self.flush_host_write_bytes,
             "sketch_blocks_closed": self.sketch_blocks_closed,
             "sketch_bytes_fetched": self.sketch_bytes_fetched,
             "sketch_bytes_live": self.sketch_bytes_live,

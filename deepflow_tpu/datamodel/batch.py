@@ -297,6 +297,14 @@ class DocBatch:
     meters:    [N, meter_schema.num_fields] f32
     timestamp: [N] u32 (seconds)
     valid:     [N] bool
+
+    A batch built from a flushed window (`L4Pipeline._to_docbatch`)
+    holds that window's `tags` and `meters` as they left the drain:
+    views of one fetched `[rows, 3+T+M]` u32 matrix in the fetch's
+    memory order (row-major with a 396 B row stride from the CPU
+    backend, column-major from a TPU), never C-contiguous. Index rows
+    or columns and assume no strides. The consumer owns them: the
+    window manager never writes to that matrix again.
     """
 
     tags: np.ndarray

@@ -45,9 +45,13 @@ tracer, `p` = the pipeline's / WindowManager's; a name lives on one):
           flush.drain            p  every ready flush entry
             flush.wait           p  scalar fetch: waits for the fold
                                     and range flush queued ahead
+              flush.reserve      p  the join's destination made and
+                                    touched while the device works (a
+                                    drain with history and over a page)
             flush.rows           p  page dispatches and the row fetch
               flush.fetch        p  the one device_get of the pages
-              flush.join         p  the host's cut and concatenate
+              flush.join         p  the host's cut, written into the
+                                    reserve (else concatenated)
             flush.split          p  unpack, per-window split, sketch
                                     and tier marrying
               flush.sketch       p  sketch plane on: the drain's packed
@@ -121,6 +125,12 @@ SPAN_FLUSH_SPLIT = "flush.split"
 # out of FLUSH_SPAN_NAMES (the page dispatches are flush.rows' own).
 SPAN_FLUSH_FETCH = "flush.fetch"
 SPAN_FLUSH_JOIN = "flush.join"
+# flush.wait's host work (PR 34): the array a drain's exact rows will be
+# joined into, allocated and touched before the blocking scalar fetch,
+# so its page faults are taken while the device runs the fold. Host
+# NumPy only (not in FLUSH_SPAN_NAMES); absent from a drain that has no
+# history to size it from or expects under one page.
+SPAN_FLUSH_RESERVE = "flush.reserve"
 # flush.split's sketch half (PR 33), where a closed block is tens of
 # megabytes: `unpack_drained` + `_hold_sketch_blocks` of one drain. Host
 # NumPy only, so it cannot compile and stays out of FLUSH_SPAN_NAMES;

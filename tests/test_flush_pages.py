@@ -277,3 +277,276 @@ def test_get_counters_carries_the_page_counters_fetch_free(monkeypatch):
     assert all(isinstance(c[k], int) for k in (
         "flush_pages", "flush_rows_fetched", "flush_rows_live"))
     wm.close()
+
+
+# ---------------------------------------------------------------------------
+# PR 34: a close writes its rows to host memory once. The meters leave
+# `unpack_flush_rows` as a view of the fetched matrix, and the pages are
+# joined into a destination reserved (and touched) under `flush.wait`, in
+# the memory order the pages come in (column-major from a TPU).
+
+W = 3 + TAG_SCHEMA.num_fields + FLOW_METER.num_fields  # words of a packed row
+
+
+def _packed_rows(kind: str) -> np.ndarray:
+    """A host `[n, W]` u32 matrix as a drain hands it to the split: the
+    join of several pages, one page's cut (a view into the page), or no
+    rows. The meter words hold float32 bit patterns of every kind."""
+    rng = np.random.default_rng(34)
+    page = rng.integers(0, 1 << 32, size=(PAGE, W), dtype=np.uint32)
+    page[:, -3:] = np.float32([np.nan, -0.0, 1e-42]).view(np.uint32)
+    if kind == "joined":
+        return np.concatenate([page, page[::-1], page[:5]])
+    if kind == "joined_column_major":  # as the pages of a TPU come and join
+        return np.asfortranarray(np.concatenate([page, page[::-1], page[:5]]))
+    if kind == "one_page":
+        return page[: PAGE - 3]
+    return np.zeros((0, W), np.uint32)
+
+
+@pytest.mark.parametrize("kind", ["joined", "joined_column_major", "one_page", "zero_rows"])
+def test_unpacked_meters_are_a_view_equal_to_the_contiguous_copy(kind):
+    rows = _packed_rows(kind)
+    T = TAG_SCHEMA.num_fields
+    win, key_hi, key_lo, tags, meters = unpack_flush_rows(rows, T)
+    old = np.ascontiguousarray(rows[:, 3 + T:]).view(np.float32)  # the copy that went
+    assert meters.dtype == np.float32 and meters.shape == old.shape == (
+        rows.shape[0], FLOW_METER.num_fields)
+    np.testing.assert_array_equal(_bits(meters), _bits(old))
+    np.testing.assert_array_equal(tags, rows[:, 3:3 + T])
+    if rows.shape[0]:
+        for out in (win, key_hi, key_lo, tags, meters):
+            assert np.shares_memory(out, rows)
+        # the matrix's own strides: row-major a row's meters are contiguous,
+        # column-major every column is
+        assert meters.strides == tags.strides == rows.strides
+        assert meters.strides == ((4, 4 * rows.shape[0]) if "column" in kind else (4 * W, 4))
+        assert not meters.flags.c_contiguous
+        rows[0, -1] ^= np.uint32(1)  # no copy in between: a write shows
+        assert _bits(meters)[0, -1] == rows[0, -1] != _bits(old)[0, -1]
+
+
+# (second after T0, live rows) with the seconds apart or together so that
+# each batch closes what the case is about. delay 2, interval 1: a batch
+# at second s closes every window before s - 2.
+RESERVE_STREAMS = {
+    # the first drain with rows has no history (the two before it were empty)
+    "first_close": ((0, 30), (1, 5), (2, 5), (3, 5), (4, 5)),
+    # 40 rows reserve 41: the next window's 41 land in the reserve
+    "fits": ((0, 40), (1, 41), (2, 30), (3, 5), (4, 5), (5, 5)),
+    # ... and 42 are one row over it: the reserve is wasted, the join is fresh
+    "one_row_over": ((0, 40), (1, 42), (2, 30), (3, 5), (4, 5), (5, 5)),
+    # second 7 closes the windows of seconds 2, 3 and 4 in one drain
+    "three_windows": ((0, 30), (1, 30), (2, 31), (3, 29), (4, 30), (7, 5), (8, 5)),
+    # after a jump the next second's drain closes a window nobody wrote to
+    "empty_drain": ((0, 30), (1, 30), (10, 30), (11, 30), (12, 5), (13, 5), (14, 5)),
+    "sketch": ((0, 40), (1, 41), (2, 40), (3, 43), (4, 40), (7, 5), (8, 5)),
+    "cascade": ((-60, 20), (-59, 20), (0, 40), (1, 41), (2, 40), (3, 43), (4, 40),
+                (7, 5), (8, 5)),
+}
+
+
+def _run_reserve_stream(monkeypatch, stream, config: dict, engage: bool,
+                        order: str = "C") -> dict:
+    """`stream` and `flush_all` through a WindowManager with pages of PAGE
+    rows. With `engage` false the manager's history is cleared before
+    every drain, so no drain reserves. With `order` "F" every fetched
+    matrix comes back column-major, as a TPU's do. Records, a drain: its
+    range, its exact rows, and what the three counters moved by."""
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", PAGE)
+    if order == "F":
+        real_fetch = window_mod.host_fetch
+
+        def column_major_fetch(x):
+            got = real_fetch(x)
+            if isinstance(got, list):
+                return [np.asfortranarray(a) if a.ndim == 2 else a for a in got]
+            return got
+
+        monkeypatch.setattr(window_mod, "host_fetch", column_major_fetch)
+    wm = WindowManager(WindowConfig(capacity=CAPACITY, **config))
+    drains, calls, tiers = [], [], []
+    real_drain = wm._drain_flush
+
+    def recording_drain(entry):
+        if not engage:
+            wm._drain_rows_per_window = 0
+        c0 = wm.get_counters()
+        out = real_drain(entry)
+        c1 = wm.get_counters()
+        drains.append({
+            "windows": min(entry.hi - entry.lo, wm.config.ring - 1),
+            "total": sum(f.count for f in out),
+            **{k: c1[k] - c0[k] for k in (
+                "flush_rows_reserved", "flush_rows_live", "flush_host_write_bytes")},
+        })
+        return out
+
+    monkeypatch.setattr(wm, "_drain_flush", recording_drain)
+    for second, live in stream:
+        calls.append(wm.ingest(*_batch(second, live)))
+        tiers.extend(wm.pop_tier_windows())
+    calls.append(wm.flush_all())
+    tiers.extend(wm.pop_tier_windows())
+    wm.close()
+    return {"calls": calls, "tiers": tiers, "drains": drains,
+            "counters": wm.get_counters(), "spans": wm.tracer.summary()}
+
+
+RESERVE_CASES = [(case, "C") for case in RESERVE_STREAMS] + [
+    ("fits", "F"), ("three_windows", "F"), ("sketch", "F")]
+
+
+def _assert_same_window(f, g) -> None:
+    assert (f.window_idx, f.count, f.tier, f.interval) == \
+           (g.window_idx, g.count, g.tier, g.interval)
+    for got, want in zip((f.key_hi, f.key_lo, f.tags, f.meters),
+                         (g.key_hi, g.key_lo, g.tags, g.meters)):
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert (f.sketches is None) == (g.sketches is None)
+    if f.sketches is not None:
+        fb, gb = _block_lanes(f.sketches), _block_lanes(g.sketches)
+        assert fb.keys() == gb.keys() and fb
+        for k in fb:
+            np.testing.assert_array_equal(fb[k], gb[k])
+
+
+def _host_half_model(drains: list) -> list:
+    """What the code says a drain of exact rows alone does to the
+    counters: (rows that landed in a reserve, bytes of host arrays made,
+    whether it reserved)."""
+    hint, out = 0, []
+    for d in drains:
+        reserve = min(window_mod.reserve_rows(hint * d["windows"]), CAPACITY)
+        reserves = reserve > PAGE  # under a page the join is a view: nothing to reserve
+        made = reserve * W * 4 if reserves else 0
+        landed = reserves and 0 < d["total"] <= reserve
+        if not landed and d["total"] > PAGE:  # several pages: a fresh concatenate
+            made += d["total"] * W * 4
+        out.append((d["total"] if landed else 0, made, reserves))
+        hint = -(-d["total"] // d["windows"])
+    return out
+
+
+@pytest.mark.parametrize("case,order", RESERVE_CASES)
+def test_reserved_join_hands_on_the_same_windows_by_the_same_call(monkeypatch, case, order):
+    config = CONFIGS.get(case, {})
+    stream = RESERVE_STREAMS[case]
+    on = _run_reserve_stream(monkeypatch, stream, config, engage=True, order=order)
+    off = _run_reserve_stream(monkeypatch, stream, config, engage=False, order=order)
+    # a window over a page is handed on in the order its pages came in
+    for f, g in zip((f for c in on["calls"] for f in c), (f for c in off["calls"] for f in c)):
+        if f.count > PAGE:
+            assert window_mod._memory_order(f.tags) == window_mod._memory_order(g.tags)
+            assert (f.tags.strides[0] == 4) == (order == "F")
+    # >= 4 closes; every call hands on the windows the other manager's does
+    assert len(on["drains"]) == len(off["drains"]) >= 4
+    assert [d["total"] for d in on["drains"]] == [d["total"] for d in off["drains"]]
+    for kind in ("calls", "tiers"):
+        got = on[kind] if kind == "tiers" else [f for c in on[kind] for f in c]
+        want = off[kind] if kind == "tiers" else [f for c in off[kind] for f in c]
+        assert len(got) == len(want)
+        for f, g in zip(got, want):
+            _assert_same_window(f, g)
+    assert [len(c) for c in on["calls"]] == [len(c) for c in off["calls"]]
+    if case == "cascade":
+        assert sum(t.count for t in on["tiers"]) > 0
+
+    # the manager that never reserves counts none and opens no span
+    assert off["counters"]["flush_rows_reserved"] == 0
+    assert "flush.reserve" not in off["spans"]
+    for d in on["drains"] + off["drains"]:
+        assert 0 <= d["flush_rows_reserved"] <= d["total"] <= d["flush_rows_live"]
+    reserved = [d["flush_rows_reserved"] for d in on["drains"]]
+    assert on["counters"]["flush_rows_reserved"] == sum(reserved) > 0
+    model = _host_half_model(on["drains"])
+    assert on["spans"]["flush.reserve"]["count"] == sum(r for _, _, r in model)
+    assert reserved == [landed for landed, _, _ in model]
+    if not config:
+        # the bytes as the code says, drain by drain (blocks, window ids
+        # and tier rows join too where a plane is on)
+        assert [d["flush_host_write_bytes"] for d in on["drains"]] == [
+            made for _, made, _ in model]
+        assert [d["flush_host_write_bytes"] for d in off["drains"]] == [
+            d["total"] * W * 4 if d["total"] > PAGE else 0 for d in off["drains"]]
+    totals = [d["total"] for d in on["drains"]]
+    if case == "first_close":
+        i = totals.index(30)
+        assert totals[:i] == [0] * i and reserved[i] == 0
+    elif case == "fits":
+        assert reserved[totals.index(41)] == 41 == window_mod.reserve_rows(40)
+    elif case == "one_row_over":
+        i = totals.index(42)
+        assert reserved[i] == 0 and on["drains"][i]["flush_host_write_bytes"] == (
+            window_mod.reserve_rows(40) + 42) * W * 4
+    elif case == "three_windows":
+        i = totals.index(31 + 29 + 30)
+        assert on["drains"][i]["windows"] == 3 and reserved[i] == 90
+    elif case == "empty_drain":
+        i = totals.index(0, 1)  # after the jump's drain, not the first
+        assert totals[i - 1] == 60 and on["drains"][i - 1]["windows"] == 3
+        assert on["drains"][i]["flush_host_write_bytes"] == \
+            window_mod.reserve_rows(20) * W * 4  # made for nothing
+        # ... and an empty drain leaves no history: the next rows miss
+        j = totals.index(30, i)
+        assert reserved[j] == 0 and reserved[j + 1] == 30
+
+
+@pytest.mark.parametrize("name,order", [("plain", "C"), ("sketch", "C"), ("plain", "F")])
+def test_a_window_handed_on_is_views_of_one_matrix_never_written_again(
+        monkeypatch, name, order):
+    """No reuse: what a drain hands on is the consumer's. A window's keys,
+    tags and meters are views of ONE matrix (the drain's joined rows, in
+    a reserve from the third close on), every window's bytes, copied when
+    it was handed on, are what it holds after every later close, and no
+    two drains' windows share memory."""
+    stream = tuple((s, 40 + s % 2) for s in range(10))  # 4 pages a close
+    run = _run_reserve_stream(monkeypatch, stream, CONFIGS[name], engage=True, order=order)
+    assert sum(d["flush_rows_reserved"] > 0 for d in run["drains"]) >= 5
+    arrays = lambda f: (f.key_hi, f.key_lo, f.tags, f.meters)
+    addr = lambda a: a.__array_interface__["data"][0]
+    wm = WindowManager(WindowConfig(capacity=CAPACITY, **CONFIGS[name]))
+    held = []  # (window, a copy of its arrays at the hand-over, which call)
+    for n, (second, live) in enumerate(stream):
+        for f in wm.ingest(*_batch(second, live)):
+            held.append((f, [np.array(a) for a in arrays(f)], n))
+    for f in wm.flush_all():
+        held.append((f, [np.array(a) for a in arrays(f)], len(stream)))
+    assert wm.get_counters()["flush_rows_reserved"] > 0
+    rowful = [(f, was, n) for f, was, n in held if f.count]
+    assert len(rowful) == len(stream) and rowful[-1][2] - rowful[0][2] >= 3
+    for f, was, _ in rowful:
+        for now, then in zip(arrays(f), was):
+            np.testing.assert_array_equal(_bits(now), _bits(then))
+        # one matrix: hi, lo, T tags, M meters are columns 1, 2, 3.., 3+T.. of it
+        col = f.tags.strides[1]
+        assert f.meters.strides == f.tags.strides and f.key_hi.strides == (f.tags.strides[0],)
+        assert addr(f.key_lo) - addr(f.key_hi) == col == addr(f.tags) - addr(f.key_lo)
+        assert addr(f.meters) - addr(f.tags) == col * TAG_SCHEMA.num_fields
+        assert (col == 4) == (order == "C")
+        assert f.meters.dtype == np.float32 and not f.meters.flags.c_contiguous
+    for (f, _, n), (g, _, m) in zip(rowful, rowful[1:]):
+        # the last call's drain hands on several windows of one matrix
+        assert n == m or not np.may_share_memory(f.meters, g.meters)
+    wm.close()
+
+
+@pytest.mark.parametrize("pages,dst,lands", [
+    ("C", "C", True), ("F", "F", True), ("F", "C", False), ("C", "F", False),
+    ("F", None, False), ("F", "short", False)])
+def test_join_writes_into_a_destination_of_the_pages_order_only(monkeypatch, pages, dst, lands):
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", PAGE)
+    host = np.arange(64 * 5, dtype=np.uint32).reshape(64, 5)
+    n = 3 * PAGE + 1
+    reserve = None if dst is None else (
+        np.full((n - 1, 5), 7, np.uint32, order="F") if dst == "short"
+        else np.full((n + 2, 5), 7, np.uint32, order=dst))
+    part = _PagedRows(jnp.asarray(host), n, dst=reserve)
+    fetched = [np.asarray(a, order=pages) for a in window_mod.host_fetch(part.pages)]
+    got = part.join(fetched)
+    np.testing.assert_array_equal(got, host[:n])
+    assert (part.order, part.landed) == (pages, lands)
+    assert window_mod._memory_order(got) == pages  # the pages' order either way
+    assert np.shares_memory(got, reserve) if lands else part.joined_bytes == got.nbytes
+    if lands:  # the tail past the live rows is the reserve's waste, untouched
+        assert part.joined_bytes == 0 and (reserve[n:] == 7).all()

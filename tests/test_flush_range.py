@@ -3,9 +3,12 @@ bit-exact versus the sequential per-window `stash_flush` oracle — same
 rows, same order, same counters — on both the single-device and sharded
 paths (ISSUE 2 acceptance)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deepflow_tpu.aggregator.stash import (
     stash_flush,
@@ -260,3 +263,72 @@ def test_async_drain_same_output_one_call_later():
     assert sync.drop_before_window == asy.drop_before_window
     assert sync.total_docs_in == asy.total_docs_in
     assert sync.total_flushed == asy.total_flushed
+
+
+# ---------------------------------------------------------------------------
+# PR 34: a flushed window's meters are a strided view of the fetched matrix
+# (as its tags always were). The consumers index rows or columns, so they
+# read the same from it as from a contiguous copy.
+
+
+def _flushed_docbatch_and_contiguous_twin():
+    from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
+    from deepflow_tpu.datamodel.batch import FlowBatch
+    from deepflow_tpu.ingest.replay import SyntheticFlowGen
+
+    pipe = L4Pipeline(PipelineConfig(batch_size=512))
+    gen = SyntheticFlowGen(num_tuples=25, seed=3)
+    docs = pipe.ingest(FlowBatch.from_records(gen.records(200, 1_700_000_000)))
+    docs += pipe.drain()
+    db = max(docs, key=lambda d: d.size)
+    assert db.size > 8
+    # as the drain handed it on: views of one matrix, its row stride
+    assert db.meters.strides[0] == db.tags.strides[0] > 4 * db.meters.shape[1]
+    assert not db.meters.flags.c_contiguous
+    addr = lambda a: a.__array_interface__["data"][0]
+    assert addr(db.meters) - addr(db.tags) == 4 * db.tags.shape[1]  # one row of one matrix
+    twin = dataclasses.replace(
+        db, tags=np.ascontiguousarray(db.tags), meters=np.ascontiguousarray(db.meters))
+    assert twin.meters.flags.c_contiguous
+    return pipe, db, twin
+
+
+@pytest.mark.parametrize("consumer", ["codec", "store"])
+def test_consumers_read_strided_meters_as_a_contiguous_copy(consumer):
+    pipe, db, twin = _flushed_docbatch_and_contiguous_twin()
+    if consumer == "codec":
+        from deepflow_tpu.ingest.codec import DocumentDecoder, encode_docbatch
+
+        wire, wire_twin = (encode_docbatch(d, flags=int(pipe.flags)) for d in (db, twin))
+        assert wire == wire_twin and len(wire) == db.size
+        (got,), (want,) = (list(DocumentDecoder().decode(w).values())
+                           for w in (wire, wire_twin))
+        for a, b in ((got.meters, want.meters), (got.tags, want.tags),
+                     (got.timestamp, want.timestamp)):
+            np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
+        # what came off the wire is what the window held
+        np.testing.assert_array_equal(
+            np.sort(got.to_docbatch().meter("byte_tx")), np.sort(db.meter("byte_tx")))
+    else:
+        from deepflow_tpu.storage.store import ColumnarStore, ColumnSpec, TableSchema
+
+        names = [f.name for f in db.meter_schema.fields]
+        schema = TableSchema(
+            "w", (ColumnSpec("time", "u4"), ColumnSpec("ip0_w3", "u4"))
+            + tuple(ColumnSpec(n, "f4") for n in names), partition_s=3600)
+        scans = []
+        for d in (db, twin):
+            store = ColumnarStore()
+            store.create_table("db", schema)
+            cols = {"time": d.timestamp, "ip0_w3": d.tag("ip0_w3"),
+                    **{n: d.meter(n) for n in names}}
+            assert store.insert("db", "w", cols) == d.size
+            scans.append(store.scan("db", "w"))
+        assert scans[0].keys() == scans[1].keys() >= {"time", "ip0_w3", *names}
+        for name in scans[0]:
+            np.testing.assert_array_equal(
+                scans[0][name].view(np.uint32), scans[1][name].view(np.uint32))
+            assert scans[0][name].flags.c_contiguous
+        np.testing.assert_array_equal(
+            scans[0]["byte_tx"].view(np.uint32),
+            np.ascontiguousarray(db.meter("byte_tx")).view(np.uint32))

@@ -29,6 +29,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deepflow_tpu.aggregator.fanout import FANOUT_LANES, FanoutConfig
 from deepflow_tpu.aggregator.pipeline import make_ingest_step
@@ -118,7 +119,8 @@ def test_prereduce_hot_path_bounds():
 SYNC_BUDGET = 3  # stats vector + flush row count + packed flush rows
 
 
-def test_window_ingest_host_sync_budget(monkeypatch):
+@pytest.mark.parametrize("page_rows", [None, 64], ids=["whole", "paged_reserve"])
+def test_window_ingest_host_sync_budget(monkeypatch, page_rows):
     import deepflow_tpu.aggregator.window as window_mod
     from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
     from deepflow_tpu.aggregator.window import WindowConfig
@@ -132,6 +134,10 @@ def test_window_ingest_host_sync_budget(monkeypatch):
         return real_fetch(x)
 
     monkeypatch.setattr(window_mod, "host_fetch", counting_fetch)
+    if page_rows:
+        # closes of several pages, so the drains join into a destination
+        # reserved under flush.wait (PR 34): a host array, no fetch
+        monkeypatch.setattr(window_mod, "PAGE_ROWS", page_rows)
 
     pipe = L4Pipeline(
         PipelineConfig(window=WindowConfig(capacity=1 << 12), batch_size=256)
@@ -154,6 +160,15 @@ def test_window_ingest_host_sync_budget(monkeypatch):
     assert many_close <= one_close  # budget must not scale with windows closed
     # batch size must not change the budget either
     assert fetches(16, t0 + 105) <= SYNC_BUDGET
+    if page_rows:
+        # second after second of one size: from the third close on the
+        # rows land in a reserve, inside the same budget
+        for k in range(6):
+            assert fetches(256, t0 + 106 + k) <= SYNC_BUDGET
+        c = pipe.get_counters()
+        assert c["flush_pages"] > c["window_advances"]
+        assert 0 < c["flush_rows_reserved"] <= c["flush_rows_live"]
+        assert pipe.tracer.summary()["flush.reserve"]["count"] >= 2
     # counters read scalar reductions, never the full valid plane — and
     # stay O(1) fetches
     before = counts["n"]

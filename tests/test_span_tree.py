@@ -28,6 +28,9 @@ from deepflow_tpu.utils.spans import (
     SPAN_FEEDER_DRAIN,
     SPAN_FEEDER_PUMP,
     SPAN_FLUSH_DRAIN,
+    SPAN_FLUSH_FETCH,
+    SPAN_FLUSH_JOIN,
+    SPAN_FLUSH_RESERVE,
     SPAN_FLUSH_ROWS,
     SPAN_FLUSH_SPLIT,
     SPAN_FLUSH_WAIT,
@@ -53,7 +56,13 @@ NEW_LAYERS = (
     # PR 33, the sketch cell's own (they carry a `workloads` list)
     "sketch.flush_ms_per_window", "sketch.fetched_bytes_per_block_byte",
     "sketch.rows_per_record",
+    # PR 34, the close's host half
+    "flush.join_ms_per_window", "flush.host_write_bytes_per_doc",
+    "flush.reserved_row_share",
 )
+# read from a run whose closes are several pages (`tiny_paged_run`): a
+# stash under one page is handed on as a view and reserves nothing
+PAGED_LAYERS = ("flush.host_write_bytes_per_doc", "flush.reserved_row_share")
 
 
 # ---------------------------------------------------------------------------
@@ -222,6 +231,17 @@ def tiny_run(chipbench_modules):
 
 
 @pytest.fixture(scope="module")
+def tiny_paged_run(chipbench_modules):
+    """The same run with pages of 64 rows, so that a close fetches several
+    and, from its third on, joins them into a reserve (PR 34)."""
+    import deepflow_tpu.aggregator.window as window_mod
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(window_mod, "PAGE_ROWS", 64)
+        yield from _tiny_run(chipbench_modules, chipbench_modules["tiny"].CONFIG)
+
+
+@pytest.fixture(scope="module")
 def tiny_sketch_run(chipbench_modules):
     """The same run with the sketch plane on, built as the sketch cell's
     configuration names it (`built_by`: chipbench/deployments/l4_sketch.py)."""
@@ -271,6 +291,31 @@ def test_children_fit_inside_their_parents(tiny_run):
             assert 0 <= agg["self_us"] <= agg["total_us"], name
 
 
+def test_the_reserve_is_host_work_inside_the_wait(tiny_run, tiny_paged_run):
+    """`flush.reserve` is a child of `flush.wait`: the three phases still
+    add up to `flush.drain`, and a manager that reserves nothing (every
+    close under one page) has no such span."""
+    assert SPAN_FLUSH_RESERVE not in tiny_run["pipe"]
+    p, c = tiny_paged_run["pipe"], tiny_paged_run["planes"]["counters"]
+    total = lambda *names: sum(p[n]["total_us"] for n in names)
+    assert p[SPAN_FLUSH_RESERVE]["total_us"] <= p[SPAN_FLUSH_WAIT]["total_us"]
+    assert total(SPAN_FLUSH_FETCH, SPAN_FLUSH_JOIN) <= p[SPAN_FLUSH_ROWS]["total_us"]
+    drain = p[SPAN_FLUSH_DRAIN]
+    phases = total(SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT)
+    assert phases <= drain["total_us"]
+    # the three phases are all of the drain's children: the reserve's
+    # time is inside the wait's, not a fourth phase beside them
+    assert drain["total_us"] - phases == drain["self_us"]
+    assert 0 < p[SPAN_FLUSH_RESERVE]["count"] < p[SPAN_FLUSH_WAIT]["count"]
+    assert p[SPAN_FLUSH_RESERVE]["compiles"] == 0
+    assert SPAN_FLUSH_RESERVE not in spans.FLUSH_SPAN_NAMES
+    by_id = {r.span_id: r for r in tiny_paged_run["records"]}
+    kids = [r for r in tiny_paged_run["records"] if r.name == SPAN_FLUSH_RESERVE]
+    assert kids and all(by_id[r.parent_span_id].name == SPAN_FLUSH_WAIT for r in kids)
+    assert 0 < c["pipeline.flush_rows_reserved"] <= c["pipeline.flush_rows_live"]
+    assert c["pipeline.flush_host_write_bytes"] >= 396 * c["pipeline.flush_rows_reserved"]
+
+
 def test_ring_records_name_their_parents(tiny_run):
     by_id = {r.span_id: r for r in tiny_run["records"]}
     assert len(by_id) == len(tiny_run["records"])  # ids are unique across tracers
@@ -314,7 +359,9 @@ def test_new_layer_file_reads_a_number_from_a_tiny_run(name, request, chipbench_
     assert {k: spec[k] for k in entry} == entry  # the file and its entry agree
     sketch = name.startswith("sketch.")
     assert ("workloads" in entry) == sketch  # only the sketch cell reports its own
-    run = request.getfixturevalue("tiny_sketch_run" if sketch else "tiny_run")
+    run = request.getfixturevalue(
+        "tiny_sketch_run" if sketch else
+        "tiny_paged_run" if name in PAGED_LAYERS else "tiny_run")
     value = layers.read_metric(spec, run["planes"])
     if sketch:
         # a run without the plane reads nothing (its `sketch_rows` lane
