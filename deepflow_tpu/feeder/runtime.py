@@ -361,15 +361,17 @@ class ShardedFeedSink(_FlowFrameCodec):
         self.swm = swm
         self.bucket_sizes = tuple(bucket_sizes)
         self.feeder_shed = 0  # sharded path has no device counter block
+        # one compile of the sharded step a bucket; more is a retrace
+        swm.expect_batch_shapes(len(self.bucket_sizes))
 
     def emit(self, chunks: list[FlowChunk], rows: int, bucket: int, shed: int) -> list:
         buf = self._assemble(chunks, rows, bucket)
         out = self.swm.ingest(buf.tag_columns(), buf.meters, buf.valid)
         # the sharded step has no per-batch sync and its outputs are
-        # donated to the next one, so the buffer waits on a one-column
-        # read of the ring the step wrote: ready once the step has run
-        if self.swm.acc is not None:
-            buf.dispatched(self.swm.acc.slot[:, :1])
+        # donated to the next one, so the buffer waits on the manager's
+        # handle for the step it just dispatched
+        if self.swm.step_done is not None:
+            buf.dispatched(self.swm.step_done)
         # only account the shed once the batch actually landed — on a
         # failed dispatch the runtime re-owns it
         self.feeder_shed += shed

@@ -17,6 +17,7 @@ precision=14 → 16384 registers/group → ~0.81% standard error, meeting the
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -55,18 +56,64 @@ def hll_update(state: jnp.ndarray, group_ids, hash_hi, hash_lo, valid) -> jnp.nd
     return state.at[gid, reg].max(rho, mode="drop")
 
 
+# rho is the leading-zero rank of a 32-bit hash word plus one, whatever
+# the precision: a register holds 0 (never hit) … HLL_Q + 1.
+HLL_Q = 32
+
+
+def _sigma(x: jnp.ndarray) -> jnp.ndarray:
+    """Ertl's sigma(x) = x + sum_k x^(2^k) 2^(k-1), elementwise: the
+    share of empty registers' contribution; sigma(1) is infinite."""
+    one = x == 1.0
+    x = jnp.where(one, 0.0, x)
+
+    def body(c):
+        x, y, z, _ = c
+        x = x * x
+        return x, y + y, z + x * y, z
+
+    _, _, z, _ = jax.lax.while_loop(
+        lambda c: jnp.any(c[2] != c[3]), body, (x, jnp.ones_like(x), x, x - 1.0)
+    )
+    return jnp.where(one, jnp.inf, z)
+
+
+def _tau(x: jnp.ndarray) -> jnp.ndarray:
+    """Ertl's tau(x), elementwise: the share of saturated registers'
+    contribution; tau(0) = tau(1) = 0."""
+    flat = (x == 0.0) | (x == 1.0)
+    x = jnp.where(flat, 0.25, x)
+
+    def body(c):
+        x, y, z, _ = c
+        x = jnp.sqrt(x)
+        y = 0.5 * y
+        return x, y, z - (1.0 - x) * (1.0 - x) * y, z
+
+    _, _, z, _ = jax.lax.while_loop(
+        lambda c: jnp.any(c[2] != c[3]), body,
+        (x, jnp.ones_like(x), 1.0 - x, 2.0 - x),
+    )
+    return jnp.where(flat, 0.0, z / 3.0)
+
+
 @jax.jit
 def hll_estimate(state: jnp.ndarray) -> jnp.ndarray:
-    """[num_groups] cardinality estimates (classic HLL with small-range
-    linear-counting correction)."""
+    """[num_groups] cardinality estimates: Ertl's improved raw estimator
+    (O. Ertl, "New cardinality estimation algorithms for HyperLogLog
+    sketches", arXiv:1702.01284, Algorithm 6). It reads the histogram
+    `C[k]` of register values and needs no empirical table and no switch
+    between estimators, so it has no bias bump where the classic
+    estimator left linear counting at 2.5 m (up to +2.5% in the mean
+    near n = 41,000 at p = 14). An empty row reads 0."""
     m = state.shape[1]
-    alpha = 0.7213 / (1.0 + 1.079 / m)
-    regs = state.astype(jnp.float32)
-    raw = alpha * m * m / jnp.sum(jnp.exp2(-regs), axis=1)
-    zeros = jnp.sum((state == 0).astype(jnp.float32), axis=1)
-    linear = m * jnp.log(m / jnp.maximum(zeros, 1.0))
-    use_linear = (raw <= 2.5 * m) & (zeros > 0)
-    return jnp.where(use_linear, linear, raw)
+    q = HLL_Q
+    c = [jnp.sum((state == k).astype(jnp.float32), axis=1) for k in range(q + 2)]
+    z = m * _tau(1.0 - c[q + 1] / m)
+    for k in range(q, 0, -1):
+        z = 0.5 * (z + c[k])
+    z = z + m * _sigma(c[0] / m)
+    return (m * m / (2.0 * math.log(2.0))) / z
 
 
 def hll_merge(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -77,19 +124,53 @@ def hll_merge(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 def hll_estimate_np(state) -> "np.ndarray":
     """Host-side estimate over a fetched register plane (np in/out) —
-    the same classic-HLL math as `hll_estimate`, for query paths that
-    must not touch the device (sketchplane.WindowSketchBlock)."""
+    the same estimator as `hll_estimate` in float64, for query paths
+    that must not touch the device (sketchplane.WindowSketchBlock)."""
     import numpy as np
 
     state = np.asarray(state)
     m = state.shape[1]
-    alpha = 0.7213 / (1.0 + 1.079 / m)
-    raw = alpha * m * m / np.sum(np.exp2(-state.astype(np.float64)), axis=1)
-    zeros = np.sum(state == 0, axis=1).astype(np.float64)
+    q = HLL_Q
+    # C[k]: registers equal to k, k = 0 … q + 1, a row
+    c = np.stack(
+        [np.count_nonzero(state == k, axis=1) for k in range(q + 2)], axis=1
+    ).astype(np.float64)
+    z = m * _tau_np(1.0 - c[:, q + 1] / m)
+    for k in range(q, 0, -1):
+        z = 0.5 * (z + c[:, k])
     with np.errstate(divide="ignore"):
-        linear = m * np.log(m / np.maximum(zeros, 1.0))
-    use_linear = (raw <= 2.5 * m) & (zeros > 0)
-    return np.where(use_linear, linear, raw)
+        z = z + m * _sigma_np(c[:, 0] / m)
+        return (m * m / (2.0 * math.log(2.0))) / z
+
+
+def _sigma_np(x):
+    import numpy as np
+
+    one = x == 1.0
+    x = np.where(one, 0.0, x)
+    y, z = np.ones_like(x), x.copy()
+    while True:
+        x = x * x
+        z_new = z + x * y
+        y = y + y
+        if np.array_equal(z_new, z):
+            return np.where(one, np.inf, z)
+        z = z_new
+
+
+def _tau_np(x):
+    import numpy as np
+
+    flat = (x == 0.0) | (x == 1.0)
+    x = np.where(flat, 0.25, x)
+    y, z = np.ones_like(x), 1.0 - x
+    while True:
+        x = np.sqrt(x)
+        y = 0.5 * y
+        z_new = z - (1.0 - x) ** 2 * y
+        if np.array_equal(z_new, z):
+            return np.where(flat, 0.0, z / 3.0)
+        z = z_new
 
 
 clz32 = _clz32  # per-register rank helper, shared with the window plane

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
@@ -55,11 +56,24 @@ from ..utils.retry import (
     retry_call,
 )
 from ..utils.spans import (
+    FLUSH_SPAN_NAMES,
     SPAN_FLUSH_DRAIN,
+    SPAN_FLUSH_FETCH,
+    SPAN_FLUSH_JOIN,
+    SPAN_FLUSH_RESERVE,
+    SPAN_FLUSH_ROWS,
+    SPAN_FLUSH_SKETCH,
+    SPAN_FLUSH_SKETCH_MERGE,
+    SPAN_FLUSH_SPLIT,
+    SPAN_FLUSH_WAIT,
     SPAN_INGEST_DISPATCH,
+    SPAN_INGEST_STAGE,
     SPAN_QUERY_SNAPSHOT,
+    SPAN_STATS_FETCH,
     SPAN_WINDOW_ADVANCE,
+    SPAN_WINDOW_CLOSE_COLLECTIVE,
     SPAN_WINDOW_FOLD,
+    JitCacheMonitor,
     SpanTracer,
 )
 from ..utils.stats import register_countable
@@ -75,6 +89,7 @@ from ..aggregator.stash import (
 )
 from ..datamodel.schema import FLOW_METER, TAG_SCHEMA
 from ..ops.histogram import LogHistSpec
+from ..ops.segment import SENTINEL_SLOT, out_blocks_total
 
 
 # ISSUE 8 unification: the span-global SketchPlanes (hll/cms/hist reset
@@ -88,6 +103,28 @@ from ..ops.histogram import LogHistSpec
 # advance (host-merged across devices — exactly the drain pattern the
 # exact rows already use).
 SketchPlanes = SketchState
+
+
+def _row_tiled(state: StashState) -> StashState:
+    """A device's stash with its two matrices held to the plain
+    `[rows, S]` layout before they are packed for a flush.
+
+    A mesh-sharded `[D, T, S]` array lies on its device as `[1, T, S]`,
+    and the TPU lays that out with the size-1 axis second-minor
+    (`{2,0,1:T(1,128)}`). Left to itself the compiler then feeds
+    `_pack_window_range`'s `[3+T+M, S]` concatenate five operands each
+    transposed on its own, every one padded to 128 lanes: 12.9 GB of
+    temporaries at 2^22 rows where the one-chip program takes 4.3, and
+    the program does not load beside the stash (PERF.md section 7 row 1,
+    PR 32's four-chip rehearsal). Held to `{1,0}` the concatenate is the
+    one-chip program's: 4.3 GB (compiled for a described v5e:2x2, PR 36).
+    The values are untouched; the CPU backend has one layout anyway."""
+    plain = Layout(major_to_minor=(0, 1))
+    return dataclasses.replace(
+        state,
+        tags=with_layout_constraint(state.tags, plain),
+        meters=with_layout_constraint(state.meters, plain),
+    )
 
 
 @jax.tree_util.register_dataclass
@@ -248,8 +285,14 @@ class ShardedPipeline:
 
         shared_sort = _use_shared_sort()
 
-        def device_step(stash, acc, offset, sk, tag_mat, meters, valid,
-                        start_window, close_below):
+        # the jitted programs' names are what a profile's "XLA Modules"
+        # line shows (`jit_<name>`): the step's, the fold's and the range
+        # flush's begin as their one-chip twins' do (`jit_step…`,
+        # `jit__fold…`, `jit__flush_range…`), so a reader that groups
+        # modules by prefix finds the sharded programs where it finds
+        # those (chipbench/trace_groups.json)
+        def step_sharded(stash, acc, offset, sk, tag_mat, meters, valid,
+                         start_window, close_below):
             # block shapes: stash [1, S, ...], tag_mat [1, T, n] — one
             # packed matrix, not a dict of columns: every pytree leaf is
             # a separate host→device upload with its own fixed cost, so
@@ -290,7 +333,7 @@ class ShardedPipeline:
 
         pspec = P(self.axes)
         mapped = shard_map(
-            device_step,
+            step_sharded,
             mesh=self.mesh,
             in_specs=(pspec, pspec, P(), pspec, pspec, pspec, pspec, P(), P()),
             out_specs=(pspec, pspec, pspec),
@@ -302,7 +345,7 @@ class ShardedPipeline:
         max_cols = tuple(int(i) for i in np.nonzero(FLOW_METER.max_mask)[0])
         merge = self.config.fold_mode == "merge"
 
-        def device_fold(stash, acc, hi_window):
+        def _fold_sharded(stash, acc, hi_window):
             stash1 = jax.tree.map(lambda x: x[0], stash)
             acc1 = jax.tree.map(lambda x: x[0], acc)
             if merge:
@@ -319,12 +362,12 @@ class ShardedPipeline:
             return (
                 jax.tree.map(expand, new_stash),
                 jax.tree.map(expand, new_acc),
-                lanes[:1],  # fold_rows; the trip count has no lane here
+                lanes[None, :2],  # [fold_rows, fold_blocks] a device
             )
 
         pspec = P(self.axes)
         mapped = shard_map(
-            device_fold,
+            _fold_sharded,
             mesh=self.mesh,
             in_specs=(pspec, pspec, P()),
             out_specs=(pspec, pspec, pspec),
@@ -343,39 +386,56 @@ class ShardedPipeline:
         this same dispatch (0 = close nothing). Callers whose batches
         span more than `sketch_ring` windows must pass them, or sketch
         slots may alias (the exact stash is unaffected either way)."""
-        d = self.n_devices
+        return self.step_staged(
+            stash, acc, offset, sketches, self.stage(tags, meters, valid),
+            start_window, close_below,
+        )
 
-        def shard_batch(x):
-            return x.reshape((d, -1) + x.shape[1:])
+    def stage(self, tags, meters, valid):
+        """A batch's three uploads, dealt over the mesh: (tag_mat
+        [D, T, n] u32, meters [D, n, M], valid [D, n]) for
+        `step_staged`."""
+        d = self.n_devices
+        spec = NamedSharding(self.mesh, P(self.axes))
+
+        def deal(x):
+            # each device's share goes from the host to that device; an
+            # array put on the default device first would reach the
+            # others through it
+            x = x if isinstance(x, jax.Array) else np.asarray(x)
+            return jax.device_put(x.reshape((d, -1) + x.shape[1:]), spec)
 
         if self._tag_names is None:
             self._tag_names = tuple(sorted(tags))
-        # pack the ~25 tag columns into ONE upload (see device_step)
+        # pack the ~25 tag columns into ONE upload (see step_sharded)
         mat = np.stack(
             [np.asarray(tags[k], dtype=np.uint32) for k in self._tag_names]
         )  # [T, D*n]
         t, total = mat.shape
-        tag_mat = jnp.asarray(
-            np.ascontiguousarray(mat.reshape(t, d, total // d).transpose(1, 0, 2))
+        tag_mat = jax.device_put(
+            np.ascontiguousarray(mat.reshape(t, d, total // d).transpose(1, 0, 2)),
+            spec,
         )  # [D, T, n]
-        meters = shard_batch(jnp.asarray(meters))
-        valid = shard_batch(jnp.asarray(valid))
+        return tag_mat, deal(meters), deal(valid)
+
+    def step_staged(self, stash, acc, offset, sketches, staged,
+                    start_window: int = 0, close_below: int = 0):
+        """Dispatch the step on what `stage` uploaded."""
         return self._step(
-            stash, acc, jnp.int32(offset), sketches, tag_mat, meters, valid,
+            stash, acc, jnp.int32(offset), sketches, *staged,
             jnp.uint32(start_window), jnp.uint32(close_below),
         )
 
     def fold(self, stash, acc, hi_window=None):
         """Amortized per-device fold of accumulated rows into the stash
         (host fires it at accum_batches cadence and before flushes).
-        Returns (stash, acc, fold_rows [D] u32 — rows each device's fold
-        keyed-sort touched). `hi_window` (fold_mode="merge" only)
-        span-bounds the fold to acc rows with slot < hi_window; the rest
-        stay accumulated — callers must NOT reset their fill cursor."""
+        Returns (stash, acc, lanes [D, 2] u32 — the rows each device's
+        fold keyed-sort touched and the trip count of its output loop).
+        `hi_window` (fold_mode="merge" only) span-bounds the fold to acc
+        rows with slot < hi_window; the rest stay accumulated — callers
+        must NOT reset their fill cursor."""
         if hi_window is not None and self.config.fold_mode != "merge":
             raise ValueError("span-bounded fold requires fold_mode='merge'")
-        from ..ops.segment import SENTINEL_SLOT
-
         hi = jnp.uint32(SENTINEL_SLOT if hi_window is None else hi_window)
         return self._fold(stash, acc, hi)
 
@@ -383,7 +443,7 @@ class ShardedPipeline:
     def _build_window_close(self):
         axes = self.axes
 
-        def close(sk: SketchState):
+        def window_close_sharded(sk: SketchState):
             sk1 = jax.tree.map(lambda x: x[0], sk)
             # fold the open ring (slot axis) first, then merge across
             # every chip in the pod — register max / counter add are
@@ -406,7 +466,7 @@ class ShardedPipeline:
 
         pspec = P(self.axes)
         mapped = shard_map(
-            close,
+            window_close_sharded,
             mesh=self.mesh,
             in_specs=(pspec,),
             out_specs=(pspec, pspec),
@@ -433,7 +493,7 @@ class ShardedPipeline:
         fetched by the manager bundled into the flush drain's existing
         transfers."""
 
-        def dr(sk, close_w):
+        def sketch_drain_sharded(sk, close_w):
             sk1 = jax.tree.map(lambda x: x[0], sk)
             new_sk, pend, pend_win, n, wide_rows, wide_wins = (
                 _sketch_drain_impl(sk1, close_w)
@@ -447,7 +507,7 @@ class ShardedPipeline:
 
         pspec = P(self.axes)
         mapped = shard_map(
-            dr,
+            sketch_drain_sharded,
             mesh=self.mesh,
             in_specs=(pspec, P()),
             out_specs=(pspec, pspec, pspec, pspec, pspec, pspec),
@@ -473,7 +533,7 @@ class ShardedPipeline:
         from ..aggregator.stash import _snapshot_range_impl
 
         def snap(stash, sk, lo):
-            stash1 = jax.tree.map(lambda x: x[0], stash)
+            stash1 = _row_tiled(jax.tree.map(lambda x: x[0], stash))
             sk1 = jax.tree.map(lambda x: x[0], sk)
             packed, total = _snapshot_range_impl(
                 stash1, lo, jnp.uint32(0xFFFFFFFF)
@@ -543,8 +603,8 @@ class ShardedPipeline:
         # stash keeps the canonical layout the rank-merge requires
         compact = self.config.fold_mode == "merge"
 
-        def fr(stash, lo, hi):
-            stash1 = jax.tree.map(lambda x: x[0], stash)
+        def _flush_range_sharded(stash, lo, hi):
+            stash1 = _row_tiled(jax.tree.map(lambda x: x[0], stash))
             new_state, packed, total = _flush_range_impl(
                 stash1, lo, hi, compact=compact
             )
@@ -553,7 +613,7 @@ class ShardedPipeline:
 
         pspec = P(self.axes)
         mapped = shard_map(
-            fr,
+            _flush_range_sharded,
             mesh=self.mesh,
             in_specs=(pspec, P(), P()),
             out_specs=(pspec, pspec, pspec),
@@ -714,7 +774,7 @@ class ShardedPipeline:
         from ..aggregator.stash import _flush_range_impl
 
         def fr(stash, lo, hi):
-            stash1 = jax.tree.map(lambda x: x[0], stash)
+            stash1 = _row_tiled(jax.tree.map(lambda x: x[0], stash))
             new_state, packed, total = _flush_range_impl(
                 stash1, lo, hi, compact=True
             )
@@ -731,6 +791,100 @@ class ShardedPipeline:
         fn = jax.jit(mapped, donate_argnums=(0,))
         self._tier_fold_cache["tier_flush"] = fn
         return fn
+
+
+class _DevicePages:
+    """The first `counts[d]` rows (along axis 1) of device d's slice of
+    the mesh-sharded `x [D, S, ...]`, for every device: what a sharded
+    drain fetches of one matrix.
+
+    The pages are `window._PagedRows`' (fixed shape along axis 1, one
+    `_take_page` program a matrix shape whatever the counts; `x` itself
+    where it is under one page), cut at the largest count. What is
+    fetched of a page is each device's OWN shard, and only where that
+    device still has rows in it: `handles` are those single-device
+    arrays, and the host gets each as it lies on its device. Fetching a
+    page as one `[D, page, ...]` array would have the runtime assemble
+    the shards into a fresh host array first, a copy (on a TPU a
+    transpose) of every row that `join_rows` then copies again."""
+
+    def __init__(self, x, counts, page_rows: int | None = None):
+        self.counts = [int(c) for c in counts]
+        paged = window_mod._PagedRows(
+            x, max(self.counts, default=0), axis=1, page_rows=page_rows
+        )
+        self.page, self.last_start = paged.page, paged.last_start
+        self.row_bytes = x.nbytes // max(x.shape[0] * x.shape[1], 1)
+        self.n_pages = len(paged.pages)
+        self._no_rows = ((0,) + x.shape[2:], x.dtype)
+        self.handles, self._where = [], []
+        for i, pg in enumerate(paged.pages):
+            shards = sorted(
+                pg.addressable_shards, key=lambda sh: sh.index[0].start or 0
+            )
+            for dev, sh in enumerate(shards):
+                if i * self.page < self.counts[dev]:
+                    self.handles.append(sh.data)
+                    self._where.append((dev, i))
+        # what the last join saw and did, as `_PagedRows` keeps them
+        self.order: str | None = None
+        self.landed = False
+        self.joined_bytes = 0
+
+    @property
+    def rows_fetched(self) -> int:
+        return len(self.handles) * self.page
+
+    @property
+    def rows_live(self) -> int:
+        return sum(self.counts)
+
+    def _cuts(self, fetched: list) -> list[list[np.ndarray]]:
+        """Device by device, the live rows of its fetched shards."""
+        cuts: list[list[np.ndarray]] = [[] for _ in self.counts]
+        for (dev, i), arr in zip(self._where, fetched):
+            s = i * self.page
+            at = min(s, self.last_start)  # where the page really starts
+            cuts[dev].append(arr[0][s - at : min(self.counts[dev], s + self.page) - at])
+        return cuts
+
+    def join(self, fetched: list) -> list[np.ndarray]:
+        """Each device's rows as one host array (a view of the fetched
+        shard where one page held them)."""
+        out = []
+        self.joined_bytes = 0
+        for cut in self._cuts(fetched):
+            if not cut:
+                out.append(np.zeros(*self._no_rows))
+            elif len(cut) == 1:
+                out.append(cut[0])
+            else:
+                out.append(np.concatenate(cut))
+                self.joined_bytes += out[-1].nbytes
+        return out
+
+    def join_rows(self, fetched: list, dst: np.ndarray | None = None) -> np.ndarray:
+        """Every device's rows as ONE host matrix, device-major (device
+        d's rows start at sum(counts[:d])), in the shards' memory order:
+        written into `dst` where that reserved destination holds them
+        and has their order (`window._PagedRows.join`'s rule), else a
+        fresh `np.concatenate`; a view where one shard held them all."""
+        cut = [c for dev in self._cuts(fetched) for c in dev]
+        self.order, self.landed, self.joined_bytes = None, False, 0
+        if not cut:
+            return np.zeros(*self._no_rows)
+        self.order = window_mod._memory_order(cut[0])
+        n = self.rows_live
+        if (dst is not None and n <= dst.shape[0]
+                and window_mod._memory_order(dst) == self.order):
+            self.landed = True
+            np.concatenate(cut, out=dst[:n])
+            return dst[:n]
+        if len(cut) == 1:
+            return cut[0]
+        out = np.concatenate(cut)
+        self.joined_bytes = out.nbytes
+        return out
 
 
 class ShardedWindowManager:
@@ -758,6 +912,7 @@ class ShardedWindowManager:
             )
         self.stash, self.sketches = pipe.init_state()
         self.acc = None  # per-device accumulator, sized on first batch
+        self.step_done = None  # a handle that is ready once the last step ran
         self.fill = 0  # host-tracked per-device accumulator rows
         self.start_window: int | None = None
         self.drop_before_window = 0
@@ -768,7 +923,53 @@ class ShardedWindowManager:
         # every fold, host mirror refreshed by the advance drain's
         # EXISTING totals fetch (bundled — no new steady-state sync)
         self.fold_rows = 0
-        self._fold_rows_dev = None
+        self._fold_rows_dev = None  # [D, 2]: fold_rows, fold_blocks
+        # what the one-chip manager reads from its per-batch counter
+        # block, mirrored here once a drain from the same bundled fetch
+        # and summed over devices: the share of the stashes' blocks the
+        # last fold's output loops ran, the stashes' live rows as the
+        # advance's fold left them (before the range flush takes the
+        # closing windows out), segments the stashes shed, rows the
+        # sketch planes took and shed, and the document rows the folds
+        # took out of the rings (`doc_in`: post-fanout, post-pre-reduce)
+        self.fold_blocks_run_sum = 0
+        self.fold_blocks_total_sum = 0
+        self._fold_blocks_total = pipe.n_devices * out_blocks_total(
+            pipe.config.capacity_per_device
+        )
+        self.stash_live_rows_sum = 0
+        self.stash_capacity_rows_sum = 0
+        self.stash_evictions = 0
+        self.sketch_rows = 0
+        self.sketch_shed = 0
+        self.doc_in = 0
+        # [D] u32, ring rows folded so far (it wraps; the drains add its
+        # steps), and what the last drain read of it
+        self._doc_rows_dev = None
+        self._doc_rows_seen = np.zeros((pipe.n_devices,), np.int64)
+        # the drains' paged row fetch and host half, under the one-chip
+        # manager's names (window.WindowManager.get_counters)
+        self.flush_pages = 0
+        self.flush_rows_fetched = 0
+        self.flush_rows_live = 0
+        self.flush_rows_reserved = 0
+        self.flush_host_write_bytes = 0
+        self.sketch_bytes_fetched = 0
+        self.sketch_bytes_live = 0
+        # exact rows handed over, every device's partial rows counted,
+        # and the devices that had rows for a drain, summed over drains
+        self.flush_partial_rows = 0
+        self.flush_devices_with_rows = 0
+        # (window, rows each device gave it), one entry a base-interval
+        # DocBatch handed over, oldest first: a DocBatch's rows are
+        # device-major, and this says where each device's partial rows
+        # begin. Bounded like `closed_sketches` (drop-oldest)
+        self.partial_row_counts: list = []
+        self._drain_rows_per_window = 0  # of the last drain, all devices
+        self._drain_order = "C"  # memory order its pages came in
+        # retrace gate for the sharded step: one expected compile a batch
+        # shape (a feeder sink names its buckets: `expect_batch_shapes`)
+        self._jit = JitCacheMonitor(pipe._step)
         # merged sketch views of the last closed window (None until one closes)
         self.global_view = None
         self.pod_1m = None
@@ -894,8 +1095,16 @@ class ShardedWindowManager:
         arr = retry_call(once, self.retry_policy, on_retry=on_retry,
                          rng=self._retry_rng)
         self.host_fetches += 1
-        self.bytes_fetched += arr.nbytes
+        self.bytes_fetched += (
+            sum(a.nbytes for a in arr) if isinstance(arr, list) else arr.nbytes
+        )
         return arr
+
+    def expect_batch_shapes(self, n: int) -> None:
+        """The batch shapes this manager will be fed (a feeder's
+        buckets): the step compiles once for each, and only a compile
+        beyond them counts as a retrace."""
+        self._jit.expected_compiles = max(1, int(n))
 
     def get_counters(self) -> dict:
         """Countable face — host ints only, safe from a ticking thread.
@@ -903,9 +1112,24 @@ class ShardedWindowManager:
         `flow_in` counts PRE-fanout flow rows (the sharded late gate
         runs on raw flows host-side); the single-chip `doc_in` counts
         post-fanout doc rows — deliberately different names so the two
-        planes cannot be misread as the same funnel stage."""
+        planes cannot be misread as the same funnel stage. `doc_in` is
+        here too since PR 36, counted where the rows exist: document
+        rows the folds took out of the devices' rings, as of the last
+        drain."""
+        xla_compiles, xla_compile_us = self.tracer.compile_lanes()
+        flush_compiles, flush_compile_us = self.tracer.compile_lanes(FLUSH_SPAN_NAMES)
         return {
+            # backend compiles charged to this manager's spans, all of
+            # them and those under flush.drain (a close that compiles
+            # for a document count shows here); jit_compiles /
+            # jit_retraces watch the sharded step alone
+            "xla_compiles": xla_compiles,
+            "xla_compile_us": xla_compile_us,
+            "flush_compiles": flush_compiles,
+            "flush_compile_us": flush_compile_us,
+            **self._jit.get_counters(),
             "flow_in": self.total_docs_in,
+            "doc_in": self.doc_in,
             "flushed_doc": self.total_flushed,
             "drop_before_window": self.drop_before_window,
             "acc_fill": self.fill,
@@ -916,9 +1140,27 @@ class ShardedWindowManager:
             # folds between advances update it at the next drain, never
             # with an extra fetch (fetch-free Countable contract).
             "fold_rows": self.fold_rows,
+            "fold_blocks_run_sum": self.fold_blocks_run_sum,
+            "fold_blocks_total_sum": self.fold_blocks_total_sum,
+            "stash_live_rows_sum": self.stash_live_rows_sum,
+            "stash_capacity_rows_sum": self.stash_capacity_rows_sum,
+            "stash_evictions": self.stash_evictions,
             "host_fetches": self.host_fetches,
             "bytes_fetched": self.bytes_fetched,
             "bytes_uploaded": self.bytes_uploaded,
+            # the drains' paged row fetch (fetched − live is the
+            # over-fetch, under one page a device a part) and host half
+            "flush_pages": self.flush_pages,
+            "flush_rows_fetched": self.flush_rows_fetched,
+            "flush_rows_live": self.flush_rows_live,
+            "flush_rows_reserved": self.flush_rows_reserved,
+            "flush_host_write_bytes": self.flush_host_write_bytes,
+            "flush_partial_rows": self.flush_partial_rows,
+            "flush_devices_with_rows": self.flush_devices_with_rows,
+            "sketch_bytes_fetched": self.sketch_bytes_fetched,
+            "sketch_bytes_live": self.sketch_bytes_live,
+            "sketch_rows": self.sketch_rows,
+            "sketch_shed": self.sketch_shed,
             "dispatch_retries": self.dispatch_retries,
             "fetch_retries": self.fetch_retries,
             # per-window sketch tier (ISSUE 8): closed blocks merged
@@ -959,6 +1201,12 @@ class ShardedWindowManager:
         out, self.closed_sketches = self.closed_sketches, []
         return out
 
+    def pop_partial_row_counts(self) -> list:
+        """Drain the (window, [D] partial rows a device) records of the
+        base-interval DocBatches handed over so far (window order)."""
+        out, self.partial_row_counts = self.partial_row_counts, []
+        return out
+
     def telemetry(self) -> dict:
         """JSON-able counters + span summary (bench snapshot shape) +
         the per-plane HBM byte record (ISSUE 12)."""
@@ -985,7 +1233,7 @@ class ShardedWindowManager:
         planes: dict[str, object] = {
             "stash": self.stash,
             "accumulator": self.acc,  # None until the first batch
-            "lanes": [self._fold_rows_dev],
+            "lanes": [self._fold_rows_dev, self._doc_rows_dev, self.step_done],
         }
         if _pool_mode(self.sketches):
             # pooled sketch memory (ISSUE 20): same four-way split as
@@ -1031,10 +1279,7 @@ class ShardedWindowManager:
         empties and the fill cursor resets."""
         if self.fill == 0 or self.acc is None:
             return
-        with self.tracer.span(SPAN_WINDOW_FOLD):
-            self.stash, self.acc, self._fold_rows_dev = self.pipe.fold(
-                self.stash, self.acc
-            )
+        self._dispatch_fold()
         self.fill = 0
 
     def _fold_span(self, hi_window: int):
@@ -1043,27 +1288,89 @@ class ShardedWindowManager:
         sentinel in place — the next full fold reclaims the ring)."""
         if self.fill == 0 or self.acc is None:
             return
+        self._dispatch_fold(np.uint32(hi_window))
+
+    def _ring_rows(self):
+        """[D] u32 document rows in the devices' rings (a dispatch, no
+        fetch): invalid rows are sentinel-keyed at append time."""
+        return jnp.sum(
+            self.acc.slot != jnp.uint32(SENTINEL_SLOT), axis=1, dtype=jnp.uint32
+        )
+
+    def _dispatch_fold(self, hi_window=None) -> None:
+        """The fold's dispatch, and the rows it took out of the rings
+        added to the device-side `doc_in` handle the next drain's bundled
+        fetch reads (a running sum, so that the add is a program of every
+        fold after the first and is compiled with the first close). A
+        full fold empties the rings; a span-bounded one leaves rows
+        behind."""
         with self.tracer.span(SPAN_WINDOW_FOLD):
+            took = self._ring_rows()
             self.stash, self.acc, self._fold_rows_dev = self.pipe.fold(
-                self.stash, self.acc, hi_window=np.uint32(hi_window)
+                self.stash, self.acc, hi_window=hi_window
             )
+            if hi_window is not None:
+                took = took - self._ring_rows()
+            self._doc_rows_dev = (
+                took if self._doc_rows_dev is None else self._doc_rows_dev + took
+            )
+
+    def _reserve_rows(self, packed, windows: int) -> np.ndarray | None:
+        """The host matrix this drain's exact rows, every device's, will
+        be joined into, made and touched BEFORE the blocking scalar
+        fetch, while the devices run the fold and the range flush:
+        `window.WindowManager._reserve_rows`' rule over the rows of all
+        devices (the last drain's rows a window x the `windows` this one
+        can hold rows of, plus `reserve_rows`' margin, never more than
+        the stashes, in the memory order that drain's shards came in).
+        None with no history or where the expected rows fit one page."""
+        d, size, cols = packed.shape
+        rows = min(window_mod.reserve_rows(self._drain_rows_per_window * windows),
+                   d * size)
+        if rows <= min(window_mod.PAGE_ROWS, size):
+            return None
+        with self.tracer.span(SPAN_FLUSH_RESERVE):
+            dst = window_mod._touched_rows(rows, cols, self._drain_order)
+        self.flush_host_write_bytes += dst.nbytes
+        return dst
+
+    def _fetch_parts(self, parts: "list[_DevicePages]", dst) -> list:
+        """Every shard of every page of every part of a drain in ONE
+        fetch. The first part is the exact rows: it comes back as one
+        device-major matrix (joined into `dst` where that holds it), the
+        others as a list of each device's rows. No pages, no fetch."""
+        handles = [h for part in parts for h in part.handles]
+        got = iter(())
+        if handles:
+            with self.tracer.span(SPAN_FLUSH_FETCH):
+                got = iter(self._fetch(handles))
+        with self.tracer.span(SPAN_FLUSH_JOIN):
+            take = lambda part: [next(got) for _ in part.handles]
+            return [parts[0].join_rows(take(parts[0]), dst)] + [
+                part.join(take(part)) for part in parts[1:]
+            ]
 
     def _drain_range(self, lo: int, hi: int):
         """Flush [lo, hi) from every device stash in one fused call and
         regroup the packed rows into per-window DocBatches; the sketch
         tier's closed blocks (ISSUE 8) drain in the SAME two transfers
-        (pend counts ride the bundled scalar vector, packed blocks +
-        window ids ride the row-block fetch as one concatenated u32
-        array) and are host-merged across devices by window into
+        and are host-merged across devices by window into
         `closed_sketches`.
 
-        Host pays: ONE [3D] scalar fetch + ONE concatenated block fetch
-        — independent of how many windows closed (previously: a full
-        slot+valid plane scan plus 3 plane fetches PER window)."""
+        Host pays: ONE scalar vector (`flush.wait`, the manager's one
+        counter sync: `stats.fetch`) + ONE list of fixed-size pages
+        (`flush.rows`: each matrix is read through `_DevicePages`, so
+        nothing dispatched here has a shape that depends on a count,
+        and each device's shard of a page comes to the host as it lies
+        on the device) — independent of how many windows closed. The
+        exact rows are written to host memory once, device-major, into
+        a destination reserved under the wait (`_reserve_rows`), and a
+        drain that closed one window hands that matrix on as views."""
         from ..aggregator.stash import unpack_flush_rows
-        from ..datamodel.batch import DocBatch
-        from ..datamodel.schema import FLOW_METER, TAG_SCHEMA
 
+        d = self.pipe.n_devices
+        # the stashes' live rows as the advance's fold left them
+        occ = jnp.sum(self.stash.valid, axis=1, dtype=jnp.uint32)
         self.stash, packed, totals = self.pipe.flush_range(
             self.stash, np.uint32(lo), np.uint32(hi)
         )
@@ -1071,136 +1378,84 @@ class ShardedWindowManager:
         # this drain even if its shard never saw the advancing timestamp
         (self.sketches, pend, pend_win, pend_n,
          wide_rows, wide_wins) = self.pipe.sketch_drain(self.sketches, hi)
-        d = self.pipe.n_devices
         # pooled wide slots (ISSUE 20): Pw > 0 only in pool mode; their
         # per-device close counts ride the scalar vector and the (tiny)
         # [D, Pw] arena joins the row fetch only when something closed
         has_wide = wide_rows.shape[1] > 0
-        # rollup cascade (ISSUE 9): fold this drain's packed flush rows
-        # into the per-device tier stashes and flush every tier window
-        # that closed — pure dispatches; outputs join the two bundled
-        # transfers below. Each entry: (tier idx, interval, packed
-        # [D, St, C], totals [D], lo_t, hi_t).
-        #
-        # TWIN CONTRACT with TierCascade.on_advance (cascade.py): this
-        # loop mirrors it over [D]-shaped state — lazy ring sizing with
-        # a pre-growth fold, tier_step, the hi_t <= watermark early
-        # break, the MANDATORY ring fold before every tier flush, and
-        # tier chaining. A semantic change to either loop must land in
-        # both (the kernels themselves are already shared).
-        tier_flushes = []
-        if self._tier_ratios:
-            src, src_total, src_hi = packed, totals, int(hi)
-            for i, ratio in enumerate(self._tier_ratios):
-                from ..aggregator.cascade import tier_ring_rows
-
-                child_rows = src.shape[1]
-                ring_rows = tier_ring_rows(child_rows)
-                if (self.tier_accs[i] is None
-                        or self.tier_accs[i].slot.shape[1] < ring_rows):
-                    if self.tier_accs[i] is not None:
-                        # fold pending rows before replacing the ring
-                        (self.tier_stashes[i], _old,
-                         self.cascade_lanes) = self.pipe.tier_ring_fold_fn()(
-                            self.tier_stashes[i], self.tier_accs[i],
-                            self.cascade_lanes,
-                        )
-                    self.tier_accs[i], self.tier_fills[i] = (
-                        self.pipe.init_tier_acc(ring_rows)
-                    )
-                step_fn = self.pipe.tier_step_fn(ratio)
-                (self.tier_stashes[i], self.tier_accs[i],
-                 self.tier_fills[i], self.cascade_lanes) = step_fn(
-                    self.tier_stashes[i], self.tier_accs[i],
-                    self.tier_fills[i], self.cascade_lanes,
-                    src, src_total, jnp.uint32(src_hi),
-                )
-                hi_t = src_hi // ratio
-                if hi_t <= self.tier_watermarks[i]:
-                    break  # nothing closed here → nothing deeper either
-                # flushed parents must see every appended child row
-                (self.tier_stashes[i], self.tier_accs[i],
-                 self.cascade_lanes) = self.pipe.tier_ring_fold_fn()(
-                    self.tier_stashes[i], self.tier_accs[i],
-                    self.cascade_lanes,
-                )
-                self.tier_fills[i] = jax.tree.map(
-                    jnp.zeros_like, self.tier_fills[i]
-                )
-                lo_t = self.tier_watermarks[i]
-                # always-compacting tier flush (ISSUE 20): keeps the
-                # canonical layout the shared-sort ring fold requires
-                self.tier_stashes[i], t_packed, t_totals = (
-                    self.pipe.tier_flush_range_fn()(
-                        self.tier_stashes[i],
-                        jnp.uint32(lo_t), jnp.uint32(hi_t),
-                    )
-                )
-                tier_flushes.append(
-                    (i, self._cascade_intervals[i], t_packed, t_totals,
-                     lo_t, hi_t)
-                )
-                self.tier_watermarks[i] = hi_t
-                src, src_total, src_hi = t_packed, t_totals, hi_t
-        # fold_rows + sketch pend counts + cascade lanes + tier totals
-        # ride the totals fetch — ONE scalar vector, zero additional
-        # host syncs regardless of tier count
-        fr_dev = self._fold_rows_dev
-        if fr_dev is None:
-            fr_dev = jnp.zeros((d,), jnp.uint32)
-        scal_parts = [totals, fr_dev.astype(jnp.int32),
-                      pend_n.astype(jnp.int32)]
-        if has_wide:
-            scal_parts.append(
-                jnp.sum(wide_wins != jnp.uint32(SENTINEL_WIN), axis=1)
-                .astype(jnp.int32)
-            )
-        if self._tier_ratios:
-            scal_parts.append(self.cascade_lanes.astype(jnp.int32).reshape(-1))
-        scal_parts += [tf[3] for tf in tier_flushes]
-        pool_on = _pool_mode(self.sketches)
-        if pool_on:
-            # pool telemetry lanes (ISSUE 20) ride the SAME bundled
-            # vector — the sharded mirror of the single-chip CB v7
-            # spill/occupancy/promotion lanes, fetch-free like the rest
-            occ = (
-                jnp.sum(self.sketches.slot_of != jnp.int32(-1), axis=-1)
-                + jnp.sum(
-                    self.sketches.wide_close != jnp.uint32(SENTINEL_WIN),
-                    axis=-1,
-                )
-            ).astype(jnp.int32)
-            scal_parts += [
-                self.sketches.pool_spill.astype(jnp.int32),
-                self.sketches.pool_promos.astype(jnp.int32),
-                occ,
+        tier_flushes = self._cascade_on_drain(packed, totals, int(hi))
+        # everything the host needs to know before it fetches a row rides
+        # ONE u32 vector of [D]-lanes: the counts, the fold's and the
+        # sketch plane's lanes, the stashes' live rows and sheds, the
+        # rings' folded rows — zero additional host syncs whatever is on
+        with self.tracer.span(SPAN_FLUSH_WAIT):
+            u32 = lambda x: x.astype(jnp.uint32).reshape(-1)
+            zeros = jnp.zeros((d,), jnp.uint32)
+            lanes = self._fold_rows_dev
+            lanes = jnp.zeros((d, 2), jnp.uint32) if lanes is None else lanes
+            doc_rows = self._doc_rows_dev
+            scal_parts = [
+                u32(totals), u32(lanes[:, 0]), u32(lanes[:, 1]), u32(pend_n),
+                occ, u32(self.stash.dropped_overflow),
+                u32(self.sketches.rows), u32(self.sketches.shed),
+                zeros if doc_rows is None else doc_rows,
             ]
-        bundled = self._fetch(jnp.concatenate(scal_parts))
-        if pool_on:
-            self.sketch_pool_spill = int(bundled[-3 * d : -2 * d].sum())
-            self.sketch_promotions = int(bundled[-2 * d : -d].sum())
-            self.sketch_pool_occ = int(bundled[-d:].sum())
-        totals_np = bundled[:d]
-        self.fold_rows = int(bundled[d : 2 * d].sum())
-        pend_np = bundled[2 * d : 3 * d]
-        o = 3 * d
-        if has_wide:
-            wide_np = bundled[o : o + d]
-            o += d
-        else:
-            wide_np = np.zeros((d,), np.int64)
-        n_wide = int(wide_np.sum())
-        if self._tier_ratios:
-            lanes_np = bundled[o : o + 2 * d].reshape(d, 2)
-            self.cascade_rows = int(lanes_np[:, 0].sum())
-            self.cascade_shed = int(lanes_np[:, 1].sum())
-            o += 2 * d
-        tier_totals_np = [bundled[o + j * d : o + (j + 1) * d]
-                          for j in range(len(tier_flushes))]
-        max_t = int(totals_np.max())
-        max_p = int(pend_np.max())
-        tier_max = [int(t.max()) for t in tier_totals_np]
-        if max_t == 0 and max_p == 0 and n_wide == 0 and not tier_flushes:
+            if has_wide:
+                scal_parts.append(u32(
+                    jnp.sum(wide_wins != jnp.uint32(SENTINEL_WIN), axis=1)
+                ))
+            if self._tier_ratios:
+                scal_parts.append(u32(self.cascade_lanes))
+            scal_parts += [u32(tf[3]) for tf in tier_flushes]
+            pool_on = _pool_mode(self.sketches)
+            if pool_on:
+                # pool telemetry lanes (ISSUE 20) ride the SAME bundled
+                # vector — the sharded mirror of the single-chip CB v7
+                # spill/occupancy/promotion lanes
+                scal_parts += [
+                    u32(self.sketches.pool_spill),
+                    u32(self.sketches.pool_promos),
+                    u32(
+                        jnp.sum(self.sketches.slot_of != jnp.int32(-1), axis=-1)
+                        + jnp.sum(
+                            self.sketches.wide_close != jnp.uint32(SENTINEL_WIN),
+                            axis=-1,
+                        )
+                    ),
+                ]
+            vec = jnp.concatenate(scal_parts)
+            # windows this drain can hold rows of: an advance's hi - lo,
+            # at most the open span (`drain` names a wider range)
+            windows = min(int(hi) - int(lo), self.delay // self.interval + 1)
+            reserved = self._reserve_rows(packed, windows)
+            with self.tracer.span(SPAN_STATS_FETCH):
+                bundled = self._fetch(vec).astype(np.int64)
+            lane = iter(bundled.reshape(-1, d))
+            totals_np = next(lane)
+            self.fold_rows = int(next(lane).sum())
+            self.fold_blocks_run_sum += int(next(lane).sum())
+            self.fold_blocks_total_sum += self._fold_blocks_total
+            pend_np = next(lane)
+            self.stash_live_rows_sum += int(next(lane).sum())
+            self.stash_capacity_rows_sum += d * packed.shape[1]
+            self.stash_evictions = int(next(lane).sum())
+            self.sketch_rows = int(next(lane).sum())
+            self.sketch_shed = int(next(lane).sum())
+            doc_rows_np = next(lane)
+            self.doc_in += int(((doc_rows_np - self._doc_rows_seen) % (1 << 32)).sum())
+            self._doc_rows_seen = doc_rows_np
+            n_wide = int(next(lane).sum()) if has_wide else 0
+            if self._tier_ratios:
+                lanes_np = np.concatenate([next(lane), next(lane)]).reshape(d, 2)
+                self.cascade_rows = int(lanes_np[:, 0].sum())
+                self.cascade_shed = int(lanes_np[:, 1].sum())
+            tier_totals_np = [next(lane) for _ in tier_flushes]
+            if pool_on:
+                self.sketch_pool_spill = int(next(lane).sum())
+                self.sketch_promotions = int(next(lane).sum())
+                self.sketch_pool_occ = int(next(lane).sum())
+        total = int(totals_np.sum())
+        if (total == 0 and not pend_np.any() and n_wide == 0
+                and not tier_flushes):
             # nothing flushed and no tier closed. With tier_flushes
             # non-empty the drain must continue even when every count
             # is zero: the watermarks already advanced, so a tier
@@ -1208,83 +1463,79 @@ class ShardedWindowManager:
             # coverage) must release its merged parent block NOW or it
             # leaks forever.
             return []
-        row_cols = packed.shape[2]
-        wide = pend.shape[2]
-        if max_t == 0 and max_p == 0 and n_wide == 0 and not any(tier_max):
-            flat = np.zeros((0,), np.uint32)  # nothing to transfer
-        else:
-            flat_parts = [
-                packed[:, :max_t].reshape(-1),
-                pend[:, :max_p].reshape(-1),
-                pend_win[:, :max_p].reshape(-1),
-            ]
+        with self.tracer.span(SPAN_FLUSH_ROWS):
+            exact = _DevicePages(packed, totals_np)
+            # a page of ONE block: a device that holds one closed block
+            # sends that block, not all of `pend`
+            blocks = _DevicePages(pend, pend_np, page_rows=1)
+            sk_parts = [(blocks, int(pend_np.sum()))]
+            parts = [exact, blocks, _DevicePages(pend_win, pend_np)]
             if n_wide:
                 # whole [D, Pw] arena — Pw is tiny, so shipping every
                 # row and filtering SENTINEL wins on host is cheaper
                 # than a device-side compaction dispatch
-                flat_parts += [wide_rows.reshape(-1), wide_wins.reshape(-1)]
-            for (_, _, t_packed, _, _, _), tm in zip(tier_flushes, tier_max):
-                flat_parts.append(t_packed[:, :tm].reshape(-1))
-            flat = self._fetch(jnp.concatenate(flat_parts))
-        nb = d * max_t * row_cols
-        npend = d * max_p * wide
-        block = flat[:nb].reshape(d, max_t, row_cols)
-        pend_rows = flat[nb : nb + npend].reshape(d, max_p, wide)
-        pend_wins = flat[nb + npend : nb + npend + d * max_p].reshape(d, max_p)
-        to = nb + npend + d * max_p
-        w_rows = w_wins = None
-        if n_wide:
-            pw, wide_w = wide_rows.shape[1], wide_rows.shape[2]
-            w_rows = flat[to : to + d * pw * wide_w].reshape(d, pw, wide_w)
-            to += d * pw * wide_w
-            w_wins = flat[to : to + d * pw].reshape(d, pw)
-            to += d * pw
-        tier_blocks = []
-        for tm in tier_max:
-            tier_blocks.append(
-                flat[to : to + d * tm * row_cols].reshape(d, tm, row_cols)
+                pw = [wide_rows.shape[1]] * d
+                sk_parts.append((_DevicePages(wide_rows, pw), n_wide))
+                parts += [sk_parts[-1][0], _DevicePages(wide_wins, pw)]
+            parts += [_DevicePages(tf[2], t)
+                      for tf, t in zip(tier_flushes, tier_totals_np)]
+            self.flush_pages += sum(p.n_pages for p in parts)
+            self.flush_rows_fetched += sum(p.rows_fetched for p in parts)
+            self.flush_rows_live += sum(p.rows_live for p in parts)
+            for part, wanted in sk_parts:
+                self.sketch_bytes_fetched += part.rows_fetched * part.row_bytes
+                self.sketch_bytes_live += wanted * part.row_bytes
+            got = iter(self._fetch_parts(parts, reserved))
+            self.flush_host_write_bytes += sum(p.joined_bytes for p in parts)
+            self._drain_rows_per_window = -(-total // max(windows, 1))
+            self._drain_order = exact.order or self._drain_order
+            if exact.landed:
+                self.flush_rows_reserved += total
+            self.flush_partial_rows += total
+            self.flush_devices_with_rows += int((totals_np > 0).sum())
+            rows = next(got)
+            pend_rows, pend_wins = next(got), next(got)
+            wide = (next(got), next(got)) if n_wide else None
+            tier_blocks = list(got)
+        with self.tracer.span(SPAN_FLUSH_SPLIT):
+            with self.tracer.span(SPAN_FLUSH_SKETCH):
+                per_dev = [
+                    unpack_drained(pend_rows[dev], pend_wins[dev], self._sk_cfg)
+                    for dev in range(d)
+                ]
+                if wide is not None:
+                    # drained wide pool slots (ISSUE 20) merge into the
+                    # same per-window dict — a window promoted on one
+                    # device and compact on another unifies here by the
+                    # r12 algebra
+                    for dev in range(d):
+                        keep = wide[1][dev] != np.uint32(SENTINEL_WIN)
+                        per_dev.append(unpack_drained(
+                            wide[0][dev][keep], wide[1][dev][keep], self._sk_cfg
+                        ))
+                merged: dict[int, object] = {}
+                with self.tracer.span(SPAN_FLUSH_SKETCH_MERGE):
+                    for blk in (b for blocks_ in per_dev for b in blocks_):
+                        have = merged.get(blk.window)
+                        merged[blk.window] = blk if have is None else have.merge(blk)
+                ordered = [merged[w] for w in sorted(merged)]
+                self.sketch_blocks_closed += len(ordered)
+                self.sketch_blocks_dropped += hold_blocks(
+                    self.closed_sketches, ordered, self.max_held_sketches
+                )
+            if self._tier_ratios:
+                # closed child blocks feed the parent merge BEFORE tier
+                # windows are built, so a parent closing in this same drain
+                # sees every child (merge order immaterial — r12 pins)
+                for blk in ordered:
+                    self._feed_tier_block(0, blk.window, blk)
+                self._take_tier_windows(tier_flushes, tier_blocks)
+            if total == 0:
+                return []
+            flushed = self._group_rows_by_window(
+                unpack_flush_rows(rows, TAG_SCHEMA.num_fields), totals_np,
+                self.interval,
             )
-            to += d * tm * row_cols
-        merged: dict[int, object] = {}
-        for dev in range(d):
-            n = int(pend_np[dev])
-            for blk in unpack_drained(
-                pend_rows[dev, :n], pend_wins[dev, :n], self._sk_cfg
-            ):
-                have = merged.get(blk.window)
-                merged[blk.window] = blk if have is None else have.merge(blk)
-        if n_wide:
-            # drained wide pool slots (ISSUE 20): merge into the same
-            # per-window dict — a window promoted on one device and
-            # compact on another unifies here by the r12 algebra
-            for dev in range(d):
-                keep = w_wins[dev] != np.uint32(SENTINEL_WIN)
-                for blk in unpack_drained(
-                    w_rows[dev][keep], w_wins[dev][keep], self._sk_cfg
-                ):
-                    have = merged.get(blk.window)
-                    merged[blk.window] = (
-                        blk if have is None else have.merge(blk)
-                    )
-        ordered = [merged[w] for w in sorted(merged)]
-        self.sketch_blocks_closed += len(ordered)
-        self.sketch_blocks_dropped += hold_blocks(
-            self.closed_sketches, ordered, self.max_held_sketches
-        )
-        if self._tier_ratios:
-            # closed child blocks feed the parent merge BEFORE tier
-            # windows are built, so a parent closing in this same drain
-            # sees every child (merge order immaterial — r12 pins)
-            for blk in ordered:
-                self._feed_tier_block(0, blk.window, blk)
-            self._take_tier_windows(tier_flushes, tier_totals_np, tier_blocks)
-        if max_t == 0:
-            return []
-        per_dev = [
-            unpack_flush_rows(block[d, : int(t)], TAG_SCHEMA.num_fields)
-            for d, t in enumerate(totals_np)
-        ]
-        flushed = self._group_rows_by_window(per_dev, self.interval)
         for db in flushed:
             self.total_flushed += db.size
         if self.lineage is not None and flushed:
@@ -1294,23 +1545,117 @@ class ShardedWindowManager:
             )
         return flushed
 
-    def _group_rows_by_window(self, per_dev, interval: int):
-        """Device-major regroup of unpacked flush rows into per-window
-        DocBatches — the same row order the per-window flush_window loop
-        produced. Shared by the tier-0 drain and the cascade tiers."""
-        from ..datamodel.batch import DocBatch
-        from ..datamodel.schema import FLOW_METER, TAG_SCHEMA
+    def _cascade_on_drain(self, packed, totals, hi: int) -> list:
+        """Rollup cascade (ISSUE 9): fold this drain's packed flush rows
+        into the per-device tier stashes and flush every tier window
+        that closed — pure dispatches; the outputs join the drain's two
+        transfers. Returns one entry a tier that flushed: (tier idx,
+        interval, packed [D, St, C], totals [D], lo_t, hi_t).
 
+        TWIN CONTRACT with TierCascade.on_advance (cascade.py): this
+        loop mirrors it over [D]-shaped state — lazy ring sizing with
+        a pre-growth fold, tier_step, the hi_t <= watermark early
+        break, the MANDATORY ring fold before every tier flush, and
+        tier chaining. A semantic change to either loop must land in
+        both (the kernels themselves are already shared)."""
+        tier_flushes = []
+        if not self._tier_ratios:
+            return tier_flushes
+        from ..aggregator.cascade import tier_ring_rows
+
+        src, src_total, src_hi = packed, totals, hi
+        for i, ratio in enumerate(self._tier_ratios):
+            child_rows = src.shape[1]
+            ring_rows = tier_ring_rows(child_rows)
+            if (self.tier_accs[i] is None
+                    or self.tier_accs[i].slot.shape[1] < ring_rows):
+                if self.tier_accs[i] is not None:
+                    # fold pending rows before replacing the ring
+                    (self.tier_stashes[i], _old,
+                     self.cascade_lanes) = self.pipe.tier_ring_fold_fn()(
+                        self.tier_stashes[i], self.tier_accs[i],
+                        self.cascade_lanes,
+                    )
+                self.tier_accs[i], self.tier_fills[i] = (
+                    self.pipe.init_tier_acc(ring_rows)
+                )
+            step_fn = self.pipe.tier_step_fn(ratio)
+            (self.tier_stashes[i], self.tier_accs[i],
+             self.tier_fills[i], self.cascade_lanes) = step_fn(
+                self.tier_stashes[i], self.tier_accs[i],
+                self.tier_fills[i], self.cascade_lanes,
+                src, src_total, jnp.uint32(src_hi),
+            )
+            hi_t = src_hi // ratio
+            if hi_t <= self.tier_watermarks[i]:
+                break  # nothing closed here → nothing deeper either
+            # flushed parents must see every appended child row
+            (self.tier_stashes[i], self.tier_accs[i],
+             self.cascade_lanes) = self.pipe.tier_ring_fold_fn()(
+                self.tier_stashes[i], self.tier_accs[i],
+                self.cascade_lanes,
+            )
+            self.tier_fills[i] = jax.tree.map(
+                jnp.zeros_like, self.tier_fills[i]
+            )
+            lo_t = self.tier_watermarks[i]
+            # always-compacting tier flush (ISSUE 20): keeps the
+            # canonical layout the shared-sort ring fold requires
+            self.tier_stashes[i], t_packed, t_totals = (
+                self.pipe.tier_flush_range_fn()(
+                    self.tier_stashes[i],
+                    jnp.uint32(lo_t), jnp.uint32(hi_t),
+                )
+            )
+            tier_flushes.append(
+                (i, self._cascade_intervals[i], t_packed, t_totals,
+                 lo_t, hi_t)
+            )
+            self.tier_watermarks[i] = hi_t
+            src, src_total, src_hi = t_packed, t_totals, hi_t
+        return tier_flushes
+
+    def _group_rows_by_window(self, unpacked, counts, interval: int):
+        """One device-major matrix of unpacked flush rows (device d's
+        `counts[d]` rows start at sum(counts[:d]), each device's in
+        (window, stash position) order) → per-window DocBatches whose
+        rows are device-major too: the order the per-window
+        flush_window loop produced. A window whose rows lie together in
+        the matrix (a drain that closed one window: all of it) is handed
+        on as views of it; one whose rows are a run a device is
+        concatenated, once. Shared by the tier-0 drain and the cascade
+        tiers."""
+        from ..datamodel.batch import DocBatch
+
+        win, _hi, _lo, tags, meters = unpacked
+        runs: dict[int, list[tuple[int, int]]] = {}
+        per_dev: dict[int, list[int]] = {}
+        off = 0
+        for dev, n in enumerate(int(c) for c in counts):
+            if n:
+                w = win[off : off + n]
+                bounds = np.flatnonzero(np.r_[True, w[1:] != w[:-1]]).tolist() + [n]
+                for a, b in zip(bounds, bounds[1:]):
+                    runs.setdefault(int(w[a]), []).append((off + a, off + b))
+                    per_dev.setdefault(int(w[a]), [0] * len(counts))[dev] += b - a
+            off += n
+        if interval == self.interval:
+            hold_blocks(self.partial_row_counts, sorted(per_dev.items()), 4096)
         flushed = []
-        for w in sorted({int(w) for win, *_ in per_dev for w in np.unique(win)}):
-            tag_parts = [tags[win == w] for win, _, _, tags, _ in per_dev]
-            met_parts = [met[win == w] for win, _, _, _, met in per_dev]
-            tags_out = np.concatenate(tag_parts)
+        for w in sorted(runs):
+            spans = runs[w]
+            if all(b == a2 for (_, b), (a2, _) in zip(spans, spans[1:])):
+                a, b = spans[0][0], spans[-1][1]
+                tags_out, meters_out = tags[a:b], meters[a:b]
+            else:
+                tags_out = np.concatenate([tags[a:b] for a, b in spans])
+                meters_out = np.concatenate([meters[a:b] for a, b in spans])
+                self.flush_host_write_bytes += tags_out.nbytes + meters_out.nbytes
             n = tags_out.shape[0]
             flushed.append(
                 DocBatch(
                     tags=tags_out,
-                    meters=np.concatenate(met_parts),
+                    meters=meters_out,
                     timestamp=np.full((n,), w * interval, dtype=np.uint32),
                     valid=np.ones((n,), dtype=bool),
                     tag_schema=TAG_SCHEMA,
@@ -1332,20 +1677,21 @@ class ShardedWindowManager:
             self._tier_ratios[tier], blk,
         )
 
-    def _take_tier_windows(self, tier_flushes, tier_totals_np, tier_blocks):
-        """Fetched tier rows → per-window tier DocBatches (host-merged
-        across devices, window order) + the parents' merged sketch
-        blocks; closed tier blocks cascade one level up."""
+    def _take_tier_windows(self, tier_flushes, tier_blocks):
+        """Fetched tier rows (each tier's a list of its devices' rows) →
+        per-window tier DocBatches (host-merged across devices, window
+        order) + the parents' merged sketch blocks; closed tier blocks
+        cascade one level up."""
         from ..aggregator.stash import unpack_flush_rows as _unpack
 
-        for (i, interval, _p, _t, lo_t, hi_t), t_np, rows in zip(
-            tier_flushes, tier_totals_np, tier_blocks
+        for (i, interval, _p, _t, lo_t, hi_t), rows in zip(
+            tier_flushes, tier_blocks
         ):
-            per_dev = [
-                _unpack(rows[dev, : int(t)], TAG_SCHEMA.num_fields)
-                for dev, t in enumerate(t_np)
-            ]
-            batches = self._group_rows_by_window(per_dev, interval)
+            counts = [r.shape[0] for r in rows]
+            joined = rows[0] if len(rows) == 1 else np.concatenate(rows)
+            batches = self._group_rows_by_window(
+                _unpack(joined, TAG_SCHEMA.num_fields), counts, interval
+            )
             self.tier_windows_flushed += len(batches)
             if self.lineage is not None and batches:
                 self.lineage.note_tier_windows(
@@ -1441,38 +1787,31 @@ class ShardedWindowManager:
         )
         d = self.pipe.n_devices
         totals_np = self._fetch(totals)
-        max_t = int(totals_np.max())
-        row_cols = packed.shape[2]
-        r, wide = blocks.shape[1], blocks.shape[2]
-        flat = self._fetch(
-            jnp.concatenate(
-                [
-                    packed[:, :max_t].reshape(-1),
-                    blocks.reshape(-1),
-                    wins.reshape(-1),
-                ]
-            )
+        # the open rows in fixed-size pages, as the drain reads them: no
+        # program here has a shape that depends on a row count
+        exact = _DevicePages(packed, totals_np)
+        got = self._fetch(exact.handles + [blocks, wins])
+        rows = exact.join_rows(got[:-2])
+        block_rows, win_np = got[-2], got[-1]
+        win, key_hi, key_lo, tags, meters = unpack_flush_rows(
+            rows, TAG_SCHEMA.num_fields
         )
-        nb = d * max_t * row_cols
-        rows = flat[:nb].reshape(d, max_t, row_cols)
-        block_rows = flat[nb : nb + d * r * wide].reshape(d, r, wide)
-        win_np = flat[nb + d * r * wide :].reshape(d, r)
-        per_dev = [
-            unpack_flush_rows(rows[dev, : int(t)], TAG_SCHEMA.num_fields)
-            for dev, t in enumerate(totals_np)
-        ]
+        # device by device: (offset, its rows' windows)
+        offs = np.concatenate([[0], np.cumsum(exact.counts)])
+        per_dev = [(int(offs[dev]), win[offs[dev] : offs[dev + 1]])
+                   for dev in range(d)]
         windows: list[FlushedWindow] = []
-        for w in sorted({int(w) for win, *_ in per_dev for w in np.unique(win)}):
-            hi = np.concatenate([h[win == w] for win, h, _, _, _ in per_dev])
-            lo = np.concatenate([l[win == w] for win, _, l, _, _ in per_dev])
-            tg = np.concatenate([t[win == w] for win, _, _, t, _ in per_dev])
-            mt = np.concatenate([m[win == w] for win, _, _, _, m in per_dev])
+        for w in sorted({int(w) for _, wd in per_dev for w in np.unique(wd)}):
+            at = np.concatenate(
+                [off + np.flatnonzero(wd == w) for off, wd in per_dev]
+            )
             windows.append(
                 FlushedWindow(
                     window_idx=w,
                     start_time=w * self.interval,
-                    key_hi=hi, key_lo=lo, tags=tg, meters=mt,
-                    count=int(tg.shape[0]), partial=True,
+                    key_hi=key_hi[at], key_lo=key_lo[at],
+                    tags=tags[at], meters=meters[at],
+                    count=int(at.shape[0]), partial=True,
                 )
             )
         # open sketch slots: host-merge per window across devices (the
@@ -1532,12 +1871,14 @@ class ShardedWindowManager:
             # the advance's work is split around the append (sketch close
             # BEFORE, fold AFTER) — measured here, emitted below as ONE
             # window.advance span so counts match `window_advances` and
-            # single-chip attribution
+            # single-chip attribution; the collective's dispatch is a
+            # span of its own besides
             adv_wall = time.time()
             t0 = time.perf_counter()
-            self.sketches, self.global_view, self.pod_1m = (
-                self.pipe.window_close(self.sketches)
-            )
+            with self.tracer.span(SPAN_WINDOW_CLOSE_COLLECTIVE):
+                self.sketches, self.global_view, self.pod_1m = (
+                    self.pipe.window_close(self.sketches)
+                )
             close_us = int((time.perf_counter() - t0) * 1e6)
 
         per_dev = int(ts_np.shape[0]) // self.pipe.n_devices
@@ -1567,13 +1908,15 @@ class ShardedWindowManager:
         self.bytes_uploaded += (
             sum(nb(v) for v in tags.values()) + nb(meters) + nb(valid)
         )
+        with self.tracer.span(SPAN_INGEST_STAGE):
+            staged = self.pipe.stage(tags, meters, valid)
+
         def dispatch_once():
             # chaos fires before the sharded step — donated stash/acc/
             # sketch buffers are untouched when a retried fault raises
             chaos.maybe_fail(chaos.SITE_DISPATCH)
-            return self.pipe.step(
-                self.stash, self.acc, self.fill, self.sketches, tags, meters,
-                valid,
+            return self.pipe.step_staged(
+                self.stash, self.acc, self.fill, self.sketches, staged,
                 # sketch-plane span bounds (ISSUE 8): the host's gate,
                 # and — when this batch advances — the new span start so
                 # the step closes the outgoing windows' sketch slots
@@ -1595,6 +1938,12 @@ class ShardedWindowManager:
                 dispatch_once, self.retry_policy, on_retry=on_retry,
                 rng=self._retry_rng, classify=is_dispatch_transient,
             )
+        self._jit.poll()
+        # ready once this step has run: a one-column read of the ring it
+        # wrote (its own outputs are donated to the next step). Taken
+        # here, on every path into the manager, so that the program
+        # behind it is compiled by whatever warms the step up
+        self.step_done = self.acc.slot[:, :1]
         if lin is not None:
             # bind this batch's window span (ts_np is already host —
             # the sharded gate computed it above, no transfer)
@@ -1671,8 +2020,6 @@ class ShardedWindowManager:
         """Flush every open window (shutdown path). Advances the open
         span past each drained window so a straggler ingest cannot
         re-open and re-emit it (same invariant as WindowManager.flush_all)."""
-        from ..ops.segment import SENTINEL_SLOT
-
         # shutdown fold stays OUTSIDE window.advance: the span count
         # must equal `window_advances` (cross-path attribution contract;
         # WindowManager.flush_all behaves the same)

@@ -56,10 +56,20 @@ tracer, `p` = the pipeline's / WindowManager's; a name lives on one):
                                     and tier marrying
               flush.sketch       p  sketch plane on: the drain's packed
                                     block rows unpacked and held
+                flush.sketch_merge  p  sharded only: the host's merge of
+                                    the devices' blocks into one a window
       feeder.dispatch            f  the pump's sub-bucket tail emit
     checkpoint.save, query.snapshot, query.cache   p  roots
     xla.compile                  a ring record (no aggregate of its
                                     own) under whichever span compiled
+
+The sharded manager (parallel/sharded.py) records the same tree under
+`feeder.dispatch`, with three differences: `window.close_collective`
+(the dispatch of the cross-mesh merge of the open sketch ring, once an
+advance, ahead of `ingest.stage`) is its own; its one counter sync is the
+close's bundled scalar fetch, so its `stats.fetch` is a child of
+`flush.wait`, once a drain and not once a batch; and `flush.sketch`
+holds `flush.sketch_merge`.
 
 A pump that drains nothing and emits nothing records no span (it counts
 `idle_pumps` on the feeder): a starved feeder pumps ~2,000 times a
@@ -136,6 +146,14 @@ SPAN_FLUSH_RESERVE = "flush.reserve"
 # NumPy only, so it cannot compile and stays out of FLUSH_SPAN_NAMES;
 # absent from a manager without the plane.
 SPAN_FLUSH_SKETCH = "flush.sketch"
+# what only the sharded close does (PR 36): the host's
+# `WindowSketchBlock.merge` of the D devices' blocks into one a window,
+# a child of flush.sketch (host NumPy only); and the dispatch of the
+# collective that merges the open sketch ring across the mesh on every
+# advance (`ShardedPipeline.window_close`: lax.pmax / lax.psum). Neither
+# is in PIPELINE_SPAN_NAMES: a one-chip manager has no such work.
+SPAN_FLUSH_SKETCH_MERGE = "flush.sketch_merge"
+SPAN_WINDOW_CLOSE_COLLECTIVE = "window.close_collective"
 FLUSH_SPAN_NAMES = (
     SPAN_FLUSH_DRAIN, SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT
 )

@@ -191,9 +191,9 @@ def test_sharded_window_ingest_host_sync_budget(monkeypatch):
     """The sharded twin of the budget gate: ShardedWindowManager
     ingest/drain under the same host_fetch shim — the per-ingest fetch
     count must stay ≤ SYNC_BUDGET regardless of device count (the
-    batched drain fetches ONE [D] totals vector + ONE [D, max_t] row
-    block, never per-shard transfers), and the transfer-byte counter
-    must account every fetched byte."""
+    batched drain fetches ONE bundled scalar vector + ONE list of the
+    devices' page shards, a single `device_get`), and the
+    transfer-byte counter must account every fetched byte."""
     import deepflow_tpu.aggregator.window as window_mod
     from deepflow_tpu.ops.histogram import LogHistSpec
     from deepflow_tpu.parallel.mesh import make_mesh
@@ -209,7 +209,9 @@ def test_sharded_window_ingest_host_sync_budget(monkeypatch):
     def counting_fetch(x):
         counts["n"] += 1
         arr = real_fetch(x)
-        counts["bytes"] += arr.nbytes
+        counts["bytes"] += (
+            sum(a.nbytes for a in arr) if isinstance(arr, list) else arr.nbytes
+        )
         return arr
 
     monkeypatch.setattr(window_mod, "host_fetch", counting_fetch)

@@ -1,5 +1,6 @@
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deepflow_tpu.ops.cms import cms_init, cms_merge, cms_query, cms_update
 from deepflow_tpu.ops.hashing import fingerprint64
@@ -10,7 +11,13 @@ from deepflow_tpu.ops.histogram import (
     loghist_quantiles,
     loghist_update,
 )
-from deepflow_tpu.ops.hll import hll_estimate, hll_init, hll_merge, hll_update
+from deepflow_tpu.ops.hll import (
+    hll_estimate,
+    hll_estimate_np,
+    hll_init,
+    hll_merge,
+    hll_update,
+)
 from deepflow_tpu.ops.tdigest import (
     tdigest_compress,
     tdigest_from_loghist,
@@ -71,6 +78,77 @@ class TestHLL:
         for g in (0, 1):
             expected = len(np.unique(ids[np.arange(2000) % 2 == g]))
             assert abs(e[g] - expected) / expected < 0.06
+
+
+HLL_P = 14
+HLL_SIZES = (1_000, 10_000, 36_000, 41_000, 45_000, 50_000, 250_000, 1_000_000)
+_PLANES: dict = {}
+
+
+def _register_planes(n: int, sketches: int = 40) -> np.ndarray:
+    """`sketches` seeded p = 14 register rows of `n` distinct random
+    64-bit hashes each: register = low 14 bits of the low word, rho =
+    leading zeros of the high word + 1, as `hll_update` takes them."""
+    if n not in _PLANES:
+        m = 1 << HLL_P
+        rng = np.random.default_rng([n, 0x411])
+        rows = np.zeros((sketches, m), np.int32)
+        for row in rows:
+            h = rng.integers(0, 2**64, n, dtype=np.uint64)
+            hi = (h >> np.uint64(32)).astype(np.uint32)
+            reg = (h & np.uint64(m - 1)).astype(np.int64)
+            rho = np.where(
+                hi == 0, 33, 32 - np.floor(np.log2(np.maximum(hi, 1))).astype(np.int64)
+            )
+            order = np.argsort(reg, kind="stable")
+            r, v = reg[order], rho[order]
+            starts = np.flatnonzero(np.r_[True, r[1:] != r[:-1]])
+            row[r[starts]] = np.maximum.reduceat(v, starts)
+        _PLANES[n] = rows
+    return _PLANES[n]
+
+
+def _classic_estimate(state: np.ndarray) -> np.ndarray:
+    """What `hll_estimate_np` was before PR 36: raw HyperLogLog with
+    linear counting up to 2.5 m, kept here as the control."""
+    m = state.shape[1]
+    alpha = 0.7213 / (1.0 + 1.079 / m)
+    raw = alpha * m * m / np.sum(np.exp2(-state.astype(np.float64)), axis=1)
+    zeros = np.sum(state == 0, axis=1).astype(np.float64)
+    linear = m * np.log(m / np.maximum(zeros, 1.0))
+    return np.where((raw <= 2.5 * m) & (zeros > 0), linear, raw)
+
+
+class TestHLLEstimator:
+    """Ertl's improved raw estimator has no bias bump at 2.5 m = 40,960
+    (p = 14), where the classic estimator leaves linear counting."""
+
+    SIGMA = 1.04 / np.sqrt(1 << HLL_P)
+
+    @pytest.mark.parametrize("n", HLL_SIZES)
+    def test_mean_and_worst_error(self, n):
+        est = hll_estimate_np(_register_planes(n))
+        assert abs(est.mean() / n - 1.0) <= 0.004
+        assert np.abs(est / n - 1.0).max() <= 3 * self.SIGMA
+
+    @pytest.mark.parametrize("n", HLL_SIZES)
+    def test_device_and_host_forms_agree(self, n):
+        planes = _register_planes(n)
+        dev = np.asarray(hll_estimate(jnp.asarray(planes)))
+        np.testing.assert_allclose(dev, hll_estimate_np(planes), rtol=1e-5)
+
+    @pytest.mark.parametrize("n", (41_000, 45_000))
+    def test_classic_estimator_is_biased_at_the_switch(self, n):
+        est = _classic_estimate(_register_planes(n))
+        assert est.mean() / n - 1.0 > 0.004
+
+    def test_empty_and_full_rows(self):
+        m = 1 << HLL_P
+        planes = np.stack([np.zeros(m, np.int32), np.full(m, 33, np.int32)])
+        host = hll_estimate_np(planes)
+        dev = np.asarray(hll_estimate(jnp.asarray(planes)))
+        assert host[0] == 0.0 and dev[0] == 0.0
+        assert np.isinf(host[1]) and np.isinf(dev[1])
 
 
 class TestCMS:

@@ -63,6 +63,14 @@ NEW_LAYERS = (
 # read from a run whose closes are several pages (`tiny_paged_run`): a
 # stash under one page is handed on as a view and reserves nothing
 PAGED_LAYERS = ("flush.host_write_bytes_per_doc", "flush.reserved_row_share")
+# PR 36, the four-chip cell's own (they list it alone): what only the
+# sharded close does
+SHARDED_CELL = "l4_4m_x4_sketch.saturate"
+SHARDED_LAYERS = (
+    "sharded_step_roofline", "close.collective_share_of_busy",
+    "close.collective_ms_per_window", "close.sketch_merge_ms_per_window",
+    "close.partial_rows_per_record",
+)
 
 
 # ---------------------------------------------------------------------------
@@ -194,8 +202,7 @@ def _tiny_run(m, config):
     try:
         source = m["gen"].FlowSource(schema, m["tiny"].CONFIG["population"], 5)
         c0, s0 = served.counters(), served.spans()
-        ring0 = {id(r) for tr in (served.feeder.tracer, served.pipe.tracer)
-                 for r in tr.recent()}
+        ring0 = {id(r) for tr in served.tracers() for r in tr.recent()}
         flushed, sent = [], 0
         with socket.create_connection(("127.0.0.1", served.port), timeout=30) as sock:
             for k in range(1, 6):
@@ -208,7 +215,7 @@ def _tiny_run(m, config):
         flushed += served.feeder.flush()
         served.block()
         c1, s1 = served.counters(), served.spans()
-        records = [r for tr in (served.feeder.tracer, served.pipe.tracer)
+        records = [r for tr in served.tracers()
                    for r in tr.recent() if id(r) not in ring0]
         spans_plane = {n: {f: s1[n][f] - s0.get(n, {}).get(f, 0) for f in s1[n]}
                        for n in s1}
@@ -216,7 +223,7 @@ def _tiny_run(m, config):
         yield {
             "records": records, "sent": sent,
             "feeder": served.feeder.tracer.summary(),
-            "pipe": served.pipe.tracer.summary(),
+            "pipe": served.tracers()[1].summary(),
             "planes": {"spans": spans_plane, "counters": counters,
                        "run": {"windows_closed": len(
                            {int(db.timestamp[0]) for db in flushed})}},
@@ -251,6 +258,27 @@ def tiny_sketch_run(chipbench_modules):
             "num_groups": 16, "hll_precision": 12, "cms_depth": 4, "cms_width": 4096,
             "hist_bins": 256, "hist_vmin": 1.0, "hist_gamma": 1.04,
             "topk_rows": 2, "topk_cols": 512, "pool": None, "pending": 4}}})
+
+
+@pytest.fixture(scope="module")
+def tiny_sharded_run(chipbench_modules):
+    """The same run through the sharded deployment the four-chip cell's
+    configuration names (`built_by`: chipbench/deployments/l4_sharded.py)
+    on four forced host devices, with pages of 64 rows."""
+    import deepflow_tpu.aggregator.window as window_mod
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four (forced host) devices")
+    tiny = chipbench_modules["tiny"].CONFIG
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(window_mod, "PAGE_ROWS", 64)
+        yield from _tiny_run(chipbench_modules, {
+            **tiny, "chips": 4, "built_by": "l4_sharded",
+            "pipeline": {**tiny["pipeline"], "accum_batches": 8, "sketch": {
+                "num_groups": 16, "hll_precision": 12, "cms_depth": 4,
+                "cms_width": 4096, "hist_bins": 256, "hist_vmin": 1.0,
+                "hist_gamma": 1.04, "topk_rows": 2, "topk_cols": 512,
+                "pool": None, "pending": 3}}})
 
 
 def test_served_path_emits_every_leaf_span_with_its_count(tiny_run):
@@ -378,12 +406,67 @@ def test_new_layer_file_reads_a_number_from_a_tiny_run(name, request, chipbench_
         "feeder.records_in": 1}, "run": {"windows_closed": 1}}) is None
 
 
+# a trace plane by hand: the device's share of a tiny run is not the CPU's to give
+_TRACE = {"trace": {"slice_records": 30_000, "busy_s": 0.5,
+                    "module_s": {"fused_step": 0.01, "sharded_window_close": 0.001}},
+          "schema": {"record_bytes": 396},
+          "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}}
+
+
+@pytest.mark.parametrize("name", SHARDED_LAYERS)
+def test_sharded_layer_file_reads_a_number_from_a_tiny_sharded_run(
+        name, tiny_run, tiny_sharded_run, chipbench_modules):
+    layers = chipbench_modules["layers"]
+    spec = layers.load_layer(name)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert {k: spec[k] for k in entry} == entry  # the file and its entry agree
+    assert entry["workloads"] == [SHARDED_CELL] and entry["moves"] == "records_per_s"
+    value = layers.read_metric(spec, {**tiny_sharded_run["planes"], **_TRACE})
+    assert isinstance(value, float) and value > 0.0
+    if name.endswith("roofline"):
+        assert value < 100.0
+    # the one-chip program has no such span, counter or module
+    plain = {**tiny_run["planes"], **_TRACE,
+             "trace": {**_TRACE["trace"], "module_s": {}}}
+    assert layers.read_metric(spec, plain) is None
+    assert layers.read_metric(spec, {"spans": {}, "counters": {
+        "feeder.records_in": 1}, "run": {"windows_closed": 1}}) is None
+
+
+def test_the_sharded_manager_has_every_span_and_counter_the_accepted_metrics_read(
+        tiny_sharded_run, chipbench_modules):
+    """A metric without a `workloads` list is every cell's, the four-chip
+    cell's too: whatever reads the program's spans and counters finds
+    them under the one-chip manager's names."""
+    layers = chipbench_modules["layers"]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        everyones = [m for m in json.load(f)["per_layer"] if "workloads" not in m
+                     and m["source"] in ("program_span", "program_counter")]
+    assert len(everyones) == 23
+    for m in everyones:
+        value = layers.read_metric(layers.load_layer(m["name"]), tiny_sharded_run["planes"])
+        assert isinstance(value, float), m["name"]
+        # 0.0 where no close compiled; and where none of this run's three
+        # closes fitted its reserve (tests/test_sharded_deployment.py has
+        # a run in which they do)
+        if m["name"] not in ("flush.compile_ms_per_window", "flush.reserved_row_share"):
+            assert value > 0.0, m["name"]
+    p = tiny_sharded_run["pipe"]
+    assert p["flush.sketch_merge"]["total_us"] <= p["flush.sketch"]["total_us"] \
+        <= p[SPAN_FLUSH_SPLIT]["total_us"]
+    assert p["stats.fetch"]["total_us"] + p[SPAN_FLUSH_RESERVE]["total_us"] \
+        <= p[SPAN_FLUSH_WAIT]["total_us"]
+    assert p["window.close_collective"]["count"] == p["window.advance"]["count"] > 0
+
+
 def test_layer_files_and_benchmark_entries_pair_up():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         names = [m["name"] for m in json.load(f)["per_layer"]]
     files = {os.path.basename(p)[:-5]
              for p in glob.glob(os.path.join(CHIPBENCH, "layers", "*.json"))}
-    assert set(names) == files and names[-len(NEW_LAYERS):] == list(NEW_LAYERS)
+    new = list(NEW_LAYERS) + list(SHARDED_LAYERS)
+    assert set(names) == files and names[-len(new):] == new
 
 
 # ---------------------------------------------------------------------------
