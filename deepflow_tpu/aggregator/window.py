@@ -62,6 +62,7 @@ from .sketchplane import (
     sketch_plane_step,
     unpack_drained,
 )
+from ..utils import hostpool
 from ..utils.retry import (
     RetryPolicy,
     decorrelated_rng,
@@ -154,16 +155,12 @@ def _memory_order(a: np.ndarray) -> str:
     return "F" if a.ndim > 1 and a.strides[0] < a.strides[-1] else "C"
 
 
-def _touched_rows(rows: int, width: int, order: str) -> np.ndarray:
-    """A fresh `[rows, width]` u32 array in memory order `order` whose
-    memory is already the process's: one word written every 4 KiB, so
-    every page is faulted in (and zeroed by the kernel) here and not
-    under the first copy into it. On the chip's host that is ~0.93 ms a
-    MB, nearly all of what a fresh `np.concatenate` costs (PERF.md §6,
-    PR 34); an allocation of this size is mapped anew every time."""
-    flat = np.empty(rows * width, np.uint32)
-    flat[::1024] = 0
-    return flat.reshape((rows, width), order=order)
+def _fresh_for(cut: list, axis: int = 0) -> np.ndarray:
+    """An uninitialised array that holds the cuts joined along `axis`,
+    in their memory order."""
+    shape = list(cut[0].shape)
+    shape[axis] = sum(c.shape[axis] for c in cut)
+    return np.empty(shape, cut[0].dtype, order=_memory_order(cut[0]))
 
 
 class _PagedRows:
@@ -211,16 +208,19 @@ class _PagedRows:
         there is one and nothing was reserved; the cuts written into
         `dst[:n]` when the reserved destination holds them and has the
         pages' order (each row is written once, into memory that was
-        touched before: the copy alone); else a fresh `np.concatenate`
-        of them, whose first-touch page faults cost several times the
-        copy. A destination of the other order would turn the copy into
-        a transpose, which costs as much as the faults: it is left."""
+        touched before: the copy alone); else into a fresh array, whose
+        first-touch page faults cost several times the copy. A
+        destination of the other order would turn the copy into a
+        transpose, which costs as much as the faults: it is left. The
+        copy is `hostpool.copy_cuts`: over a few threads where it is
+        large."""
         cut = []
         lead = (slice(None),) * self.axis
         for s, page in zip(range(0, self.n, self.page), fetched):
             at = min(s, self.last_start)  # where the page really starts
             cut.append(page[lead + (slice(s - at, min(self.n, s + self.page) - at),)])
         self.order, self.landed, self.joined_bytes = None, False, 0
+        self.copied_bytes = self.pooled_bytes = 0
         if not cut:
             return np.zeros(*self._no_rows)
         self.order = _memory_order(fetched[0])
@@ -229,12 +229,14 @@ class _PagedRows:
                 and _memory_order(dst) == self.order):
             self.landed = True
             out = dst[: self.n]
-            np.concatenate(cut, axis=self.axis, out=out)
-            return out
-        if len(cut) == 1:
+        elif len(cut) == 1:
             return cut[0]
-        out = np.concatenate(cut, axis=self.axis)
-        self.joined_bytes = out.nbytes
+        else:
+            out = _fresh_for(cut, self.axis)
+            self.joined_bytes = out.nbytes
+        self.copied_bytes = out.nbytes
+        if hostpool.copy_cuts(cut, out, self.axis) > 1:
+            self.pooled_bytes = out.nbytes
         return out
 
 
@@ -929,13 +931,17 @@ class WindowManager:
         # the drains' host half: exact rows per window of the last drain
         # and the memory order of its fetched pages (what the next one
         # sizes and shapes its reserve from; 0 rows = no history), exact
-        # rows that were joined into a reserved destination, and the
-        # bytes of every host array the drains made after the fetch (a
-        # reserve, whole; a part's fresh join result)
+        # rows that were joined into a reserved destination, the bytes of
+        # every host array the drains made after the fetch (a reserve,
+        # whole; a part's fresh join result), the bytes of their passes
+        # over host memory (a reserve's touch, a join's copy) and those
+        # of them `hostpool` divided over more than one thread
         self._drain_rows_per_window = 0
         self._drain_order = "C"
         self.flush_rows_reserved = 0
         self.flush_host_write_bytes = 0
+        self.flush_host_pass_bytes = 0
+        self.flush_pooled_bytes = 0
         # the sketch plane's share of the drains (zero with the plane
         # off): blocks handed over with their windows, the bytes of
         # packed block rows (`pend` pages, closed wide slots) the drains
@@ -1052,8 +1058,11 @@ class WindowManager:
         if rows <= min(PAGE_ROWS, size):
             return None
         with self.tracer.span(SPAN_FLUSH_RESERVE):
-            dst = _touched_rows(rows, entry.packed.shape[1], self._drain_order)
+            dst, workers = hostpool.touched_rows(
+                rows, entry.packed.shape[1], self._drain_order)
         self.flush_host_write_bytes += dst.nbytes
+        self.flush_host_pass_bytes += dst.nbytes
+        self.flush_pooled_bytes += dst.nbytes * (workers > 1)
         return dst
 
     def _drain_flush(self, entry: "_FlushEntry") -> list[FlushedWindow]:
@@ -1146,6 +1155,8 @@ class WindowManager:
                 self.sketch_bytes_live += wanted * part.row_bytes
             got = iter(self._fetch_parts(parts))
             self.flush_host_write_bytes += sum(p.joined_bytes for p in parts)
+            self.flush_host_pass_bytes += sum(p.copied_bytes for p in parts)
+            self.flush_pooled_bytes += sum(p.pooled_bytes for p in parts)
             exact = parts[0]
             self._drain_rows_per_window = -(-total // windows)
             self._drain_order = exact.order or self._drain_order
@@ -1936,9 +1947,13 @@ class WindowManager:
             # the drains' host half: exact rows joined into a destination
             # reserved under flush.wait, and the bytes of every host array
             # the drains made after the fetch (reserves whole, fresh join
-            # results); the split copies nothing
+            # results); the split copies nothing. Every byte a reserve
+            # touched or a join copied, and those of them whose pass was
+            # divided over the pool's threads (utils/hostpool.py)
             "flush_rows_reserved": self.flush_rows_reserved,
             "flush_host_write_bytes": self.flush_host_write_bytes,
+            "flush_host_pass_bytes": self.flush_host_pass_bytes,
+            "flush_pooled_bytes": self.flush_pooled_bytes,
             "sketch_blocks_closed": self.sketch_blocks_closed,
             "sketch_bytes_fetched": self.sketch_bytes_fetched,
             "sketch_bytes_live": self.sketch_bytes_live,

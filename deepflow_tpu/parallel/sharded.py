@@ -49,6 +49,7 @@ from ..aggregator.sketchplane import (
     unpack_drained,
 )
 from ..aggregator.window import sketch_inputs_from_columns
+from ..utils import hostpool
 from ..utils.retry import (
     RetryPolicy,
     decorrelated_rng,
@@ -829,7 +830,7 @@ class _DevicePages:
         # what the last join saw and did, as `_PagedRows` keeps them
         self.order: str | None = None
         self.landed = False
-        self.joined_bytes = 0
+        self.joined_bytes = self.copied_bytes = self.pooled_bytes = 0
 
     @property
     def rows_fetched(self) -> int:
@@ -848,29 +849,43 @@ class _DevicePages:
             cuts[dev].append(arr[0][s - at : min(self.counts[dev], s + self.page) - at])
         return cuts
 
+    def _copy(self, cut: list, out: np.ndarray) -> np.ndarray:
+        """The cuts written into `out` (`hostpool.copy_cuts`: over a few
+        threads where the copy is large), counted."""
+        self.copied_bytes += out.nbytes
+        if hostpool.copy_cuts(cut, out) > 1:
+            self.pooled_bytes += out.nbytes
+        return out
+
+    def _fresh(self, cut: list) -> np.ndarray:
+        """The cuts joined into a fresh array of their memory order."""
+        out = window_mod._fresh_for(cut)
+        self.joined_bytes += out.nbytes
+        return self._copy(cut, out)
+
     def join(self, fetched: list) -> list[np.ndarray]:
         """Each device's rows as one host array (a view of the fetched
         shard where one page held them)."""
         out = []
-        self.joined_bytes = 0
+        self.joined_bytes = self.copied_bytes = self.pooled_bytes = 0
         for cut in self._cuts(fetched):
             if not cut:
                 out.append(np.zeros(*self._no_rows))
             elif len(cut) == 1:
                 out.append(cut[0])
             else:
-                out.append(np.concatenate(cut))
-                self.joined_bytes += out[-1].nbytes
+                out.append(self._fresh(cut))
         return out
 
     def join_rows(self, fetched: list, dst: np.ndarray | None = None) -> np.ndarray:
         """Every device's rows as ONE host matrix, device-major (device
         d's rows start at sum(counts[:d])), in the shards' memory order:
         written into `dst` where that reserved destination holds them
-        and has their order (`window._PagedRows.join`'s rule), else a
-        fresh `np.concatenate`; a view where one shard held them all."""
+        and has their order (`window._PagedRows.join`'s rule), else into
+        a fresh array; a view where one shard held them all."""
         cut = [c for dev in self._cuts(fetched) for c in dev]
         self.order, self.landed, self.joined_bytes = None, False, 0
+        self.copied_bytes = self.pooled_bytes = 0
         if not cut:
             return np.zeros(*self._no_rows)
         self.order = window_mod._memory_order(cut[0])
@@ -878,13 +893,10 @@ class _DevicePages:
         if (dst is not None and n <= dst.shape[0]
                 and window_mod._memory_order(dst) == self.order):
             self.landed = True
-            np.concatenate(cut, out=dst[:n])
-            return dst[:n]
+            return self._copy(cut, dst[:n])
         if len(cut) == 1:
             return cut[0]
-        out = np.concatenate(cut)
-        self.joined_bytes = out.nbytes
-        return out
+        return self._fresh(cut)
 
 
 class ShardedWindowManager:
@@ -954,6 +966,8 @@ class ShardedWindowManager:
         self.flush_rows_live = 0
         self.flush_rows_reserved = 0
         self.flush_host_write_bytes = 0
+        self.flush_host_pass_bytes = 0
+        self.flush_pooled_bytes = 0
         self.sketch_bytes_fetched = 0
         self.sketch_bytes_live = 0
         # exact rows handed over, every device's partial rows counted,
@@ -1155,6 +1169,8 @@ class ShardedWindowManager:
             "flush_rows_live": self.flush_rows_live,
             "flush_rows_reserved": self.flush_rows_reserved,
             "flush_host_write_bytes": self.flush_host_write_bytes,
+            "flush_host_pass_bytes": self.flush_host_pass_bytes,
+            "flush_pooled_bytes": self.flush_pooled_bytes,
             "flush_partial_rows": self.flush_partial_rows,
             "flush_devices_with_rows": self.flush_devices_with_rows,
             "sketch_bytes_fetched": self.sketch_bytes_fetched,
@@ -1330,8 +1346,10 @@ class ShardedWindowManager:
         if rows <= min(window_mod.PAGE_ROWS, size):
             return None
         with self.tracer.span(SPAN_FLUSH_RESERVE):
-            dst = window_mod._touched_rows(rows, cols, self._drain_order)
+            dst, workers = hostpool.touched_rows(rows, cols, self._drain_order)
         self.flush_host_write_bytes += dst.nbytes
+        self.flush_host_pass_bytes += dst.nbytes
+        self.flush_pooled_bytes += dst.nbytes * (workers > 1)
         return dst
 
     def _fetch_parts(self, parts: "list[_DevicePages]", dst) -> list:
@@ -1487,6 +1505,8 @@ class ShardedWindowManager:
                 self.sketch_bytes_live += wanted * part.row_bytes
             got = iter(self._fetch_parts(parts, reserved))
             self.flush_host_write_bytes += sum(p.joined_bytes for p in parts)
+            self.flush_host_pass_bytes += sum(p.copied_bytes for p in parts)
+            self.flush_pooled_bytes += sum(p.pooled_bytes for p in parts)
             self._drain_rows_per_window = -(-total // max(windows, 1))
             self._drain_order = exact.order or self._drain_order
             if exact.landed:

@@ -22,6 +22,7 @@ from deepflow_tpu.aggregator.stash import (
 from deepflow_tpu.aggregator.window import WindowConfig, WindowManager, _PagedRows
 from deepflow_tpu.datamodel.schema import FLOW_METER, TAG_SCHEMA
 from deepflow_tpu.ops.histogram import LogHistSpec
+from deepflow_tpu.utils import hostpool
 from deepflow_tpu.utils.spans import FLUSH_SPAN_NAMES, SPAN_QUERY_SNAPSHOT
 
 PAGE = 12  # does not divide the 128-row stash: the last page is clamped
@@ -550,3 +551,215 @@ def test_join_writes_into_a_destination_of_the_pages_order_only(monkeypatch, pag
     assert np.shares_memory(got, reserve) if lands else part.joined_bytes == got.nbytes
     if lands:  # the tail past the live rows is the reserve's waste, untouched
         assert part.joined_bytes == 0 and (reserve[n:] == 7).all()
+
+
+# ---------------------------------------------------------------------------
+# the close's two host passes over a small pool of threads (PR 37)
+
+
+def _force_pool(monkeypatch, workers: int, min_bytes: int = 0, cores: int = 64) -> list:
+    """The helper's two constants and the core count as this test wants
+    them (the tier-1 machine may have one core); returns the list that
+    records every divided pass's number of pieces."""
+    divided = []
+    real_run = hostpool._run
+
+    def recording_run(pieces):
+        divided.append(len(pieces))
+        real_run(pieces)
+
+    monkeypatch.setattr(hostpool, "WORKERS", workers)
+    monkeypatch.setattr(hostpool, "POOL_MIN_BYTES", min_bytes)
+    monkeypatch.setattr(hostpool, "_cores", lambda: cores)
+    monkeypatch.setattr(hostpool, "_run", recording_run)
+    return divided
+
+
+@pytest.mark.parametrize("workers", [1, 2, 4, 7])
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("order", ["C", "F"])
+def test_pooled_copy_equals_concatenate_bit_for_bit(monkeypatch, order, axis, workers):
+    divided = _force_pool(monkeypatch, workers)
+    rng = np.random.default_rng(7)
+    pages = [np.asarray(rng.integers(0, 1 << 32, (PAGE, 9), dtype=np.uint32), order=order)
+             for _ in range(11)]
+    short = (slice(None),) * axis + (slice(0, 5),)
+    cuts = pages[:-1] + [pages[-1][short]]  # a short last cut
+    want = np.concatenate(cuts, axis=axis)
+    shape = list(want.shape)
+    shape[axis] += 3  # a destination longer than the rows
+    dst = np.full(shape, 7, np.uint32, order=order)
+    live = (slice(None),) * axis + (slice(0, want.shape[axis]),)
+    used = hostpool.copy_cuts(cuts, dst[live], axis)
+    assert used == workers and divided == ([workers] if workers > 1 else [])
+    np.testing.assert_array_equal(dst[live], want)
+    tail = (slice(None),) * axis + (slice(want.shape[axis], None),)
+    assert (dst[tail] == 7).all()  # nothing is written past the rows
+    # every cut landed at the offset concatenate gives it
+    at = 0
+    for c in cuts:
+        here = (slice(None),) * axis + (slice(at, at + c.shape[axis]),)
+        np.testing.assert_array_equal(dst[here], c)
+        at += c.shape[axis]
+
+
+@pytest.mark.parametrize("workers", [1, 2, 4, 7])
+@pytest.mark.parametrize("order", ["C", "F"])
+def test_pooled_touch_writes_one_word_of_every_page(monkeypatch, order, workers):
+    divided = _force_pool(monkeypatch, workers)
+    rows, width, sentinel = 1000, 99, 0xA5A5A5A5
+    monkeypatch.setattr(  # the helper's allocation, filled so that a write shows
+        hostpool.np, "empty", lambda n, dtype: np.full(n, sentinel, dtype))
+    got, used = hostpool.touched_rows(rows, width, order)
+    monkeypatch.undo()
+    want = np.zeros(rows * width, np.uint32).reshape((rows, width), order=order)
+    assert (got.shape, got.strides, got.dtype) == (want.shape, want.strides, want.dtype)
+    assert used == workers and divided == ([workers] if workers > 1 else [])
+    flat = got.reshape(-1, order=order)
+    assert np.shares_memory(flat, got)
+    written = np.flatnonzero(flat != sentinel)
+    # exactly the words `flat[::1024] = 0` writes: one every 4 KiB
+    np.testing.assert_array_equal(written, np.arange(0, rows * width, 1024))
+    assert (flat[written] == 0).all()
+
+
+@pytest.mark.parametrize("cores", [1, 64])
+def test_a_pass_under_the_threshold_or_on_one_core_is_the_callers(monkeypatch, cores):
+    """Small passes stay inline by what the code can see, the bytes of
+    the pass; with one core nothing divides and no thread is started."""
+    rows = 4 * PAGE
+    nbytes = rows * 99 * 4
+    divided = _force_pool(monkeypatch, 4, min_bytes=nbytes, cores=cores)
+    started = len(hostpool._threads)
+    pages = [np.full((PAGE, 99), i, np.uint32) for i in range(4)]
+    want = 4 if cores > 1 else 1
+    for n, shares in ((rows, want), (rows - 1, 1)):  # at the threshold, one row under it
+        cuts = pages[:-1] + [pages[-1][: PAGE - (rows - n)]]
+        out = np.empty((n, 99), np.uint32)
+        assert hostpool.copy_cuts(cuts, out) == shares
+        np.testing.assert_array_equal(out, np.concatenate(cuts))
+        assert hostpool.touched_rows(n, 99, "C")[1] == shares
+    assert divided == ([4, 4] if cores > 1 else [])
+    if cores == 1:
+        assert len(hostpool._threads) == started
+
+
+def test_a_piece_that_raises_fails_the_pass_after_every_piece_has_run(monkeypatch):
+    import threading
+
+    ran, me = [], threading.get_ident()
+
+    def piece(i):
+        def run():
+            ran.append((i, threading.get_ident() == me))
+            if i == 2:
+                raise RuntimeError("piece 2")
+        return run
+
+    with pytest.raises(RuntimeError, match="piece 2"):
+        hostpool._run([piece(i) for i in range(5)])
+    # the caller ran the first piece itself, pool threads the others, and
+    # the failure was raised only once all five had finished
+    assert sorted(ran) == [(0, True), (1, False), (2, False), (3, False), (4, False)]
+    assert all(t.daemon and t.name.startswith("hostpool-") for t in hostpool._threads)
+    assert len(hostpool._threads) >= 4
+
+
+def _pooled_counters(run: dict) -> tuple[int, int]:
+    c = run["counters"]
+    return c["flush_host_pass_bytes"], c["flush_pooled_bytes"]
+
+
+@pytest.mark.parametrize("case,order", [
+    ("fits", "C"), ("three_windows", "F"), ("sketch", "F"), ("cascade", "C")])
+def test_pooled_closes_hand_on_the_windows_the_unpooled_closes_do(monkeypatch, case, order):
+    """The windows `test_reserved_join_hands_on_the_same_windows_by_the_same_call`
+    pins, with every pass of the engaged manager divided over four
+    threads and none of the other's."""
+    config, stream = CONFIGS.get(case, {}), RESERVE_STREAMS[case]
+    off = _run_reserve_stream(monkeypatch, stream, config, engage=False, order=order)
+    # as the constants stand a test's passes are far under the threshold:
+    # the calling thread runs them, the way the 10k deployments' closes go
+    passed, pooled = _pooled_counters(off)
+    assert passed > 0 and pooled == 0
+    divided = _force_pool(monkeypatch, 4)
+    on = _run_reserve_stream(monkeypatch, stream, config, engage=True, order=order)
+    passed, pooled = _pooled_counters(on)
+    # a pass with one cut (a window id vector, one block) stays the caller's
+    assert 0 < pooled <= passed and len(divided) >= 4 and set(divided) <= {2, 3, 4}
+    assert on["counters"]["flush_rows_reserved"] > 0
+    assert [len(c) for c in on["calls"]] == [len(c) for c in off["calls"]]
+    for kind in ("calls", "tiers"):
+        got = on[kind] if kind == "tiers" else [f for c in on[kind] for f in c]
+        want = off[kind] if kind == "tiers" else [f for c in off[kind] for f in c]
+        assert len(got) == len(want) > 0 or kind == "tiers"
+        for f, g in zip(got, want):
+            _assert_same_window(f, g)
+            if f.count > PAGE:
+                assert window_mod._memory_order(f.tags) == window_mod._memory_order(g.tags)
+    # a reserve counts whole, a join the rows it copied
+    assert passed >= on["counters"]["flush_host_write_bytes"]
+
+
+def test_a_worker_that_raises_fails_the_drain_and_hands_nothing_on(monkeypatch):
+    _force_pool(monkeypatch, 4)
+    real_run, armed = hostpool._run, []
+
+    def run_with_a_failing_worker(pieces):
+        def boom():
+            raise RuntimeError("worker")
+        real_run(pieces[:-1] + [boom] if armed else pieces)
+
+    monkeypatch.setattr(hostpool, "_run", run_with_a_failing_worker)
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", PAGE)
+    wm = WindowManager(WindowConfig(capacity=CAPACITY))
+    handed = []
+    for second in range(4):
+        handed += wm.ingest(*_batch(second, 40))
+    assert [f.count for f in handed] == [40] and wm.get_counters()["flush_pooled_bytes"] > 0
+    armed.append(True)
+    with pytest.raises(RuntimeError, match="worker"):
+        handed += wm.ingest(*_batch(4, 40))
+    assert [f.count for f in handed] == [40]
+    wm.close()
+
+
+@pytest.mark.parametrize("dst", [None, "C", "F", "short"])
+def test_sharded_join_rows_pooled_equals_unpooled_device_major(monkeypatch, dst):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepflow_tpu.parallel.sharded import _DevicePages
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four (forced host) devices")
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", PAGE)
+    host = np.random.default_rng(3).integers(0, 1 << 32, (4, 64, 5), dtype=np.uint32)
+    x = jax.device_put(host, NamedSharding(Mesh(np.array(jax.devices()[:4]), ("d",)), P("d")))
+    counts = [3 * PAGE, 0, 2 * PAGE + 5, 64]  # whole pages, none, a short tail, all
+    want = np.concatenate([host[d, :c] for d, c in enumerate(counts)])
+    n = sum(counts)
+
+    def joined(workers):
+        divided = _force_pool(monkeypatch, workers)
+        part = _DevicePages(x, counts)
+        fetched = window_mod.host_fetch(part.handles)
+        reserve = None if dst is None else (
+            np.full((n - 1, 5), 7, np.uint32) if dst == "short"
+            else np.full((n + 2, 5), 7, np.uint32, order=dst))
+        return part, part.join_rows(fetched, reserve), reserve, divided
+
+    for workers in (1, 4):
+        part, got, reserve, divided = joined(workers)
+        np.testing.assert_array_equal(got, want)
+        lands = dst == part.order
+        assert part.landed == lands and part.copied_bytes == want.nbytes
+        assert part.pooled_bytes == (want.nbytes if workers > 1 else 0)
+        assert divided == ([4] if workers > 1 else [])
+        if lands:
+            assert np.shares_memory(got, reserve) and (reserve[n:] == 7).all()
+            assert part.joined_bytes == 0
+        else:
+            assert part.joined_bytes == want.nbytes
+        # the other parts' join: each device's rows, pooled the same way
+        each = part.join(window_mod.host_fetch(part.handles))
+        for d, c in enumerate(counts):
+            np.testing.assert_array_equal(each[d], host[d, :c])

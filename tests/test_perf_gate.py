@@ -119,9 +119,11 @@ def test_prereduce_hot_path_bounds():
 SYNC_BUDGET = 3  # stats vector + flush row count + packed flush rows
 
 
-@pytest.mark.parametrize("page_rows", [None, 64], ids=["whole", "paged_reserve"])
-def test_window_ingest_host_sync_budget(monkeypatch, page_rows):
+@pytest.mark.parametrize("page_rows,pooled", [(None, False), (64, False), (64, True)],
+                         ids=["whole", "paged_reserve", "paged_reserve_pooled"])
+def test_window_ingest_host_sync_budget(monkeypatch, page_rows, pooled):
     import deepflow_tpu.aggregator.window as window_mod
+    from deepflow_tpu.utils import hostpool
     from deepflow_tpu.aggregator.pipeline import L4Pipeline, PipelineConfig
     from deepflow_tpu.aggregator.window import WindowConfig
     from deepflow_tpu.datamodel.batch import FlowBatch
@@ -138,6 +140,12 @@ def test_window_ingest_host_sync_budget(monkeypatch, page_rows):
         # closes of several pages, so the drains join into a destination
         # reserved under flush.wait (PR 34): a host array, no fetch
         monkeypatch.setattr(window_mod, "PAGE_ROWS", page_rows)
+    if pooled:
+        # the reserve's touch and the join's copy over four threads
+        # (PR 37): host memory only, no fetch
+        monkeypatch.setattr(hostpool, "WORKERS", 4)
+        monkeypatch.setattr(hostpool, "POOL_MIN_BYTES", 0)
+        monkeypatch.setattr(hostpool, "_cores", lambda: 64)
 
     pipe = L4Pipeline(
         PipelineConfig(window=WindowConfig(capacity=1 << 12), batch_size=256)
@@ -169,6 +177,8 @@ def test_window_ingest_host_sync_budget(monkeypatch, page_rows):
         assert c["flush_pages"] > c["window_advances"]
         assert 0 < c["flush_rows_reserved"] <= c["flush_rows_live"]
         assert pipe.tracer.summary()["flush.reserve"]["count"] >= 2
+        assert c["flush_host_pass_bytes"] >= c["flush_host_write_bytes"] > 0
+        assert (c["flush_pooled_bytes"] > 0) == pooled
     # counters read scalar reductions, never the full valid plane — and
     # stay O(1) fetches
     before = counts["n"]
@@ -181,7 +191,8 @@ def test_window_ingest_host_sync_budget(monkeypatch, page_rows):
     c = pipe.get_counters()
     assert counts["n"] - before == 0
     for key in ("stash_occupancy", "stash_evictions", "excess_word_hits",
-                "host_fetches", "bytes_fetched", "bytes_uploaded"):
+                "host_fetches", "bytes_fetched", "bytes_uploaded",
+                "flush_host_pass_bytes", "flush_pooled_bytes"):
         assert key in c
     assert c["host_fetches"] > 0 and c["bytes_fetched"] > 0
     assert c["bytes_uploaded"] > 0
@@ -240,10 +251,14 @@ def test_sharded_window_ingest_host_sync_budget(monkeypatch):
         assert counts["n"] - before <= SYNC_BUDGET
         # transfer accounting: the manager's counters mirror exactly what
         # the shim saw for this manager (count AND bytes)
+        before = counts["n"]
         c = wm.get_counters()
+        assert counts["n"] == before  # the Countable face fetches nothing
         assert c["host_fetches"] == counts["n"] - n0
         assert c["bytes_fetched"] == counts["bytes"] - b0
         assert c["bytes_uploaded"] > 0
+        # the close's host passes (PR 37), under the one-chip names
+        assert c["flush_pooled_bytes"] <= c["flush_host_pass_bytes"]
     # the budget must not scale with shard count
     assert max(per_ingest[4]) <= max(per_ingest[1]) + 0
 

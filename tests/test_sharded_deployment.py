@@ -406,11 +406,41 @@ def test_the_closes_counters_count(run):
     assert fetched - live < 3 * CHIPS * 256 * (c["pipeline.window_advances"] + 1)
     assert 0 < c["pipeline.flush_rows_reserved"] <= c["pipeline.flush_partial_rows"]
     assert c["pipeline.flush_host_write_bytes"] >= 396 * c["pipeline.flush_rows_reserved"]
+    # every reserve and every copied row is a host pass; at these sizes none divides
+    assert c["pipeline.flush_host_pass_bytes"] >= c["pipeline.flush_host_write_bytes"]
+    assert c["pipeline.flush_pooled_bytes"] == 0
     assert 0 < c["pipeline.stash_live_rows_sum"] <= c["pipeline.stash_capacity_rows_sum"]
     assert 0 < c["pipeline.fold_blocks_run_sum"] <= c["pipeline.fold_blocks_total_sum"]
     assert c["pipeline.doc_in"] >= c["pipeline.flushed_doc"] > 0
     assert c["pipeline.flow_in"] == c["feeder.records_in"] == sum(RECORDS)
     assert (c["pipeline.jit_retraces"], c["pipeline.stash_evictions"]) == (0, 0)
+
+
+def test_closes_through_the_pool_hand_on_the_same_documents(run, m, monkeypatch):
+    """The same deployment with every pass of the close's host half
+    divided over four threads (PR 37; as the constants stand these sizes
+    stay inline): every window's documents bit for bit, the same rows
+    reserved and written."""
+    from deepflow_tpu.utils import hostpool
+
+    monkeypatch.setattr(window_mod, "PAGE_ROWS", 256)
+    monkeypatch.setattr(hostpool, "WORKERS", 4)
+    monkeypatch.setattr(hostpool, "POOL_MIN_BYTES", 0)
+    monkeypatch.setattr(hostpool, "_cores", lambda: 64)
+    pooled = served_run(m, sharded_config(m), RECORDS)
+    assert pooled["guarantees_broken"] == set()
+    c, was = pooled["counters"], run["counters"]
+    assert 0 < c["pipeline.flush_pooled_bytes"] <= c["pipeline.flush_host_pass_bytes"]
+    for k in ("pipeline.flush_rows_reserved", "pipeline.flush_partial_rows",
+              "pipeline.flush_host_write_bytes", "pipeline.flush_host_pass_bytes"):
+        assert c[k] == was[k], k
+    assert sorted(pooled["ctx"]["got"]) == sorted(run["ctx"]["got"])
+    for w, (tags, meters) in run["ctx"]["got"].items():
+        got_tags, got_meters = pooled["ctx"]["got"][w]
+        np.testing.assert_array_equal(got_tags, tags)
+        np.testing.assert_array_equal(np.asarray(got_meters).view(np.uint32),
+                                      np.asarray(meters).view(np.uint32))
+    assert set(pooled["spans"]) == set(run["spans"])
 
 
 def test_a_forced_retrace_is_counted(m):

@@ -71,6 +71,8 @@ SHARDED_LAYERS = (
     "close.collective_ms_per_window", "close.sketch_merge_ms_per_window",
     "close.partial_rows_per_record",
 )
+# PR 37, the close's two host passes over the pool's threads (every cell's)
+POOLED_LAYERS = ("flush.pooled_byte_share", "flush.reserve_ms_per_window")
 
 
 # ---------------------------------------------------------------------------
@@ -249,6 +251,22 @@ def tiny_paged_run(chipbench_modules):
 
 
 @pytest.fixture(scope="module")
+def tiny_pooled_run(chipbench_modules):
+    """`tiny_paged_run` with every pass of the close's host half divided
+    over four threads (PR 37: `utils/hostpool.py`; as its constants stand
+    a tiny run's passes are far under the threshold and stay inline)."""
+    import deepflow_tpu.aggregator.window as window_mod
+    from deepflow_tpu.utils import hostpool
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(window_mod, "PAGE_ROWS", 64)
+        mp.setattr(hostpool, "WORKERS", 4)
+        mp.setattr(hostpool, "POOL_MIN_BYTES", 0)
+        mp.setattr(hostpool, "_cores", lambda: 64)
+        yield from _tiny_run(chipbench_modules, chipbench_modules["tiny"].CONFIG)
+
+
+@pytest.fixture(scope="module")
 def tiny_sketch_run(chipbench_modules):
     """The same run with the sketch plane on, built as the sketch cell's
     configuration names it (`built_by`: chipbench/deployments/l4_sketch.py)."""
@@ -342,6 +360,46 @@ def test_the_reserve_is_host_work_inside_the_wait(tiny_run, tiny_paged_run):
     assert kids and all(by_id[r.parent_span_id].name == SPAN_FLUSH_WAIT for r in kids)
     assert 0 < c["pipeline.flush_rows_reserved"] <= c["pipeline.flush_rows_live"]
     assert c["pipeline.flush_host_write_bytes"] >= 396 * c["pipeline.flush_rows_reserved"]
+    # every reserve and every copied row is a host pass; none of this size divides
+    assert c["pipeline.flush_host_pass_bytes"] >= c["pipeline.flush_host_write_bytes"]
+    assert c["pipeline.flush_pooled_bytes"] == 0
+
+
+def test_pool_threads_open_no_span_and_the_tree_keeps_its_shape(
+        tiny_paged_run, tiny_pooled_run):
+    """The same run with the reserve's touch and the join's copy divided
+    over threads records the same spans under the same parents: the feed
+    thread opens and closes `flush.reserve` and `flush.join`, a worker
+    opens nothing (a span opened on another thread would be a root)."""
+    import threading
+
+    from deepflow_tpu.utils import hostpool
+
+    one, many = tiny_paged_run, tiny_pooled_run
+    c = many["planes"]["counters"]
+    assert 0 < c["pipeline.flush_pooled_bytes"] <= c["pipeline.flush_host_pass_bytes"]
+    assert len(hostpool._threads) >= 3
+    assert all(t.daemon for t in hostpool._threads)
+    assert {t.name for t in threading.enumerate()} >= {t.name for t in hostpool._threads}
+    assert set(many["pipe"]) == set(one["pipe"]) and set(many["feeder"]) == set(one["feeder"])
+    for name in (SPAN_FLUSH_RESERVE, SPAN_FLUSH_JOIN, SPAN_FLUSH_FETCH,
+                 SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT):
+        assert many["pipe"][name]["count"] == one["pipe"][name]["count"], name
+    parents = {SPAN_FLUSH_RESERVE: SPAN_FLUSH_WAIT, SPAN_FLUSH_JOIN: SPAN_FLUSH_ROWS,
+               SPAN_FLUSH_FETCH: SPAN_FLUSH_ROWS, SPAN_FLUSH_WAIT: SPAN_FLUSH_DRAIN,
+               SPAN_FLUSH_ROWS: SPAN_FLUSH_DRAIN, SPAN_FLUSH_SPLIT: SPAN_FLUSH_DRAIN}
+    by_id = {r.span_id: r for r in many["records"]}
+    seen = set()
+    for r in many["records"]:
+        if r.name in parents and r.parent_span_id in by_id:
+            assert by_id[r.parent_span_id].name == parents[r.name], r
+            seen.add(r.name)
+    assert seen == set(parents)
+    # the counters that say what a close wrote do not move with the pool
+    for k in ("pipeline.flush_rows_reserved", "pipeline.flush_rows_live",
+              "pipeline.flush_host_write_bytes", "pipeline.flush_host_pass_bytes",
+              "pipeline.flush_pages", "pipeline.host_fetches"):
+        assert c[k] == one["planes"]["counters"][k], k
 
 
 def test_ring_records_name_their_parents(tiny_run):
@@ -406,6 +464,30 @@ def test_new_layer_file_reads_a_number_from_a_tiny_run(name, request, chipbench_
         "feeder.records_in": 1}, "run": {"windows_closed": 1}}) is None
 
 
+@pytest.mark.parametrize("name", POOLED_LAYERS)
+def test_pooled_layer_file_reads_a_number_where_the_close_reserves(
+        name, tiny_run, tiny_paged_run, tiny_pooled_run, chipbench_modules):
+    layers = chipbench_modules["layers"]
+    spec = layers.load_layer(name)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert {k: spec[k] for k in entry} == entry and "workloads" not in entry
+    assert (entry["layer"], entry["moves"]) == ("window close / flush", "records_per_s")
+    pooled = layers.read_metric(spec, tiny_pooled_run["planes"])
+    inline = layers.read_metric(spec, tiny_paged_run["planes"])
+    assert isinstance(pooled, float) and isinstance(inline, float)
+    if name == "flush.pooled_byte_share":
+        # passes under the threshold read 0, not nothing: the 10k cells' line
+        assert 0.0 == inline < pooled <= 100.0
+    else:
+        assert inline > 0.0 and pooled > 0.0
+        # a run whose closes fit one page reserves nothing: no span, no number
+        assert layers.read_metric(spec, tiny_run["planes"]) is None
+    # a program without the span or counter (the parent commit) reads nothing
+    assert layers.read_metric(spec, {"spans": {}, "counters": {
+        "feeder.records_in": 1}, "run": {"windows_closed": 1}}) is None
+
+
 # a trace plane by hand: the device's share of a tiny run is not the CPU's to give
 _TRACE = {"trace": {"slice_records": 30_000, "busy_s": 0.5,
                     "module_s": {"fused_step": 0.01, "sharded_window_close": 0.001}},
@@ -443,7 +525,7 @@ def test_the_sharded_manager_has_every_span_and_counter_the_accepted_metrics_rea
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         everyones = [m for m in json.load(f)["per_layer"] if "workloads" not in m
                      and m["source"] in ("program_span", "program_counter")]
-    assert len(everyones) == 23
+    assert len(everyones) == 25  # 23 until PR 37 added the pooled share and the reserve
     for m in everyones:
         value = layers.read_metric(layers.load_layer(m["name"]), tiny_sharded_run["planes"])
         assert isinstance(value, float), m["name"]
@@ -465,7 +547,7 @@ def test_layer_files_and_benchmark_entries_pair_up():
         names = [m["name"] for m in json.load(f)["per_layer"]]
     files = {os.path.basename(p)[:-5]
              for p in glob.glob(os.path.join(CHIPBENCH, "layers", "*.json"))}
-    new = list(NEW_LAYERS) + list(SHARDED_LAYERS)
+    new = list(NEW_LAYERS) + list(SHARDED_LAYERS) + list(POOLED_LAYERS)
     assert set(names) == files and names[-len(new):] == new
 
 
