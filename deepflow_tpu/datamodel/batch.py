@@ -160,7 +160,7 @@ class StagingBuffer:
     [T, B] u32 with rows in `names` order, `meters` [B, M] f32, `valid`
     [B] bool. `write` appends a chunk's rows, `finish` zeroes what an
     earlier, longer fill left past them; between the two every byte of a
-    record is written once. Reused: see StagingRing.acquire for when."""
+    record is written once. Reused: see StagingRing.settle for when."""
 
     def __init__(self, bucket: int, names: tuple[str, ...], n_meters: int):
         self.names = names
@@ -269,11 +269,18 @@ class StagingRing:
         self.waits = 0  # acquires that found their buffer still in flight
 
     def acquire(self, bucket: int, names: tuple[str, ...] = STAGED_TAG_ORDER) -> StagingBuffer:
-        """The least recently used buffer of this shape, empty. A buffer
-        is written again only when the batch staged from it has been
-        dispatched and the device has read it (`wait`, counted when it
-        blocks); one whose staged batch is still held undispatched is
-        passed over, and the ring grows only if every buffer is."""
+        """The least recently used buffer of this shape, empty: `offer`,
+        then `settle`. A writer that times the wait makes the two calls
+        itself (the ring keeps no clock and no tracer)."""
+        buf = self.offer(bucket, names)
+        self.settle(buf)
+        return buf
+
+    def offer(self, bucket: int, names: tuple[str, ...] = STAGED_TAG_ORDER) -> StagingBuffer:
+        """The least recently used buffer of this shape, which the device
+        may still be reading: `settle` it before the first write. One
+        whose staged batch is still held undispatched is passed over, and
+        the ring grows only if every buffer is."""
         bufs = self._rings.setdefault((bucket, names), collections.deque())
         buf = None
         if len(bufs) >= STAGING_RING_LEN:
@@ -284,9 +291,17 @@ class StagingRing:
         else:
             bufs.remove(buf)
         bufs.append(buf)
-        self.waits += buf.wait()
-        buf.rows = buf.n_valid = 0
         return buf
+
+    def settle(self, buf: StagingBuffer) -> bool:
+        """Empty an offered buffer for its next fill. A buffer is written
+        again only when the batch staged from it has been dispatched and
+        the device has read it: `wait`, counted when it blocks → whether
+        it did."""
+        blocked = buf.wait()
+        self.waits += blocked
+        buf.rows = buf.n_valid = 0
+        return blocked
 
 
 @dataclasses.dataclass

@@ -87,6 +87,7 @@ from ..utils.spans import (
     SPAN_FEEDER_DISPATCH,
     SPAN_FEEDER_DRAIN,
     SPAN_FEEDER_PUMP,
+    SPAN_FEEDER_STAGING_WAIT,
     SpanTracer,
 )
 from ..utils.retry import RetryPolicy, decorrelated_rng
@@ -94,6 +95,16 @@ from ..utils.stats import register_countable
 from .flowframe import decode_flowframe_matrices, peek_rows
 
 _log = logging.getLogger(__name__)
+
+# span name → the counter FeederRuntime.get_counters() republishes its CPU
+# lane under (`feeder.pump` → `pump_cpu_us`): the six that are read through
+# the harness's counters plane (`feeder.staging_wait`'s lane stays on the
+# tracer's own face)
+_CPU_LANE_COUNTERS = {
+    name: f"{name.removeprefix('feeder.')}_cpu_us"
+    for name in (SPAN_FEEDER_PUMP, SPAN_FEEDER_DRAIN, SPAN_FEEDER_COALESCE,
+                 SPAN_FEEDER_DECODE, SPAN_FEEDER_DISPATCH, SPAN_FEEDER_ASSEMBLE)
+}
 
 # ---------------------------------------------------------------------------
 # record chunks — what decoded frames become inside the pending buffer
@@ -182,13 +193,15 @@ class _FlowFrameCodec(FrameCodecBase):
     it has one, else one made here); `host_copy_bytes` counts every byte
     the feed writes to host memory between a decoded frame and the
     upload, zero fill included, `staging_waits` the times a writer
-    found the ring's next buffer still in flight (both reach the
-    feeder's get_counters())."""
+    found the ring's next buffer still in flight and `staging_wait_us`
+    how long it then waited for the device to have read it (all three
+    reach the feeder's get_counters())."""
 
     def __init__(self, staging: StagingRing | None = None):
         super().__init__()
         self.staging = staging or StagingRing(FLOW_METER.num_fields)
         self.host_copy_bytes = 0
+        self.staging_wait_us = 0
 
     @property
     def staging_waits(self) -> int:
@@ -221,9 +234,17 @@ class _FlowFrameCodec(FrameCodecBase):
     def _assemble(self, chunks: list[FlowChunk], rows: int, bucket: int) -> StagingBuffer:
         """One batch's chunks → one staging buffer of `bucket` rows in
         the upload's layout (datamodel/batch.StagingBuffer): each chunk
-        written once where the upload reads it, the stale tail zeroed."""
+        written once where the upload reads it, the stale tail zeroed.
+        The wait for a buffer the device is still reading is a span of
+        its own, kept only when it blocked."""
         with self.tracer.span(SPAN_FEEDER_ASSEMBLE):
-            buf = self.staging.acquire(bucket)
+            buf = self.staging.offer(bucket)
+            with self.tracer.span(SPAN_FEEDER_STAGING_WAIT) as wait:
+                blocked = self.staging.settle(buf)
+                if not blocked:
+                    wait.discard()
+            if blocked:
+                self.staging_wait_us += wait.duration_us
             copied = sum(buf.write(c.tags, c.meters) for c in chunks)
             assert buf.rows == rows
             self.host_copy_bytes += copied + buf.finish()
@@ -603,12 +624,21 @@ class FeederRuntime:
             "events_published": 0,
             # pumps that drained nothing and emitted nothing: they
             # record no span (a starved feeder pumps ~2,000 times a
-            # second and would turn the span ring over in one)
+            # second and would turn the span ring over in one); their
+            # wall is kept: in a closed loop it is the round trip
+            # sender → Receiver → queue that the feeder sat out
             "idle_pumps": 0,
+            "idle_pump_us": 0,
+            # Σ len(q) over the visits and the visits: their ratio is the
+            # mean number of frames waiting when the feeder comes for them
+            "queue_depth_sum": 0,
+            "queue_visits": 0,
         }
-        # decode_frame time of the round under way (feeder.decode is ONE
-        # record a round, not one a frame: see _record_decode)
+        # decode_frame time of the round under way, wall and CPU
+        # (feeder.decode is ONE record a round, not one a frame: see
+        # _record_decode)
         self._decode_us = 0
+        self._decode_cpu_ns = 0
         self._decode_frames = 0
         self._pump_count = 0
         self.last_snapshot = None  # most recent scheduled OpenSnapshot
@@ -638,6 +668,13 @@ class FeederRuntime:
         out["decode_errors"] = int(getattr(self.sink, "decode_errors", 0))
         out["host_copy_bytes"] = int(getattr(self.sink, "host_copy_bytes", 0))
         out["staging_waits"] = int(getattr(self.sink, "staging_waits", 0))
+        out["staging_wait_us"] = int(getattr(self.sink, "staging_wait_us", 0))
+        # the feeder spans' CPU lanes as `<stage>_cpu_us`. They repeat
+        # `<stage>.cpu_us` of the tracer's own Countable face on purpose:
+        # chipbench's `spans` plane passes count / total_us only, and a
+        # benchmark PR that widens it retires these (ROADMAP)
+        for name, cpu_us in self.tracer.cpu_us(tuple(_CPU_LANE_COUNTERS)).items():
+            out[_CPU_LANE_COUNTERS[name]] = cpu_us
         if self._journal is not None:
             for k, v in self._journal.get_counters().items():
                 out[f"journal_{k}"] = v
@@ -723,8 +760,11 @@ class FeederRuntime:
             # it can keep up — the watermark shed upstream does the rest
             budget = max(1, budget // 2)
         cap = int(getattr(q, "capacity", 0) or 0)
+        depth = len(q)
+        with self._lock:
+            self.counters["queue_depth_sum"] += depth
+            self.counters["queue_visits"] += 1
         if cap:
-            depth = len(q)
             if not self._pressure[i] and depth >= self.config.high_watermark * cap:
                 self._pressure[i] = True
                 self._count("pressure_events")
@@ -827,7 +867,7 @@ class FeederRuntime:
         it — the single path pump() and replay_journal() share, so
         recovery exercises no special-case decode code."""
         errs0 = int(getattr(self.sink, "decode_errors", 0))
-        t0 = time.perf_counter()
+        t0, cpu0 = time.perf_counter(), time.thread_time_ns()
         try:
             chunk = self.sink.decode_frame(raw)
         except Exception:
@@ -837,6 +877,7 @@ class FeederRuntime:
             self._drop_admit_stamp()
             return
         finally:
+            self._decode_cpu_ns += time.thread_time_ns() - cpu0
             self._decode_us += int((time.perf_counter() - t0) * 1e6)
             self._decode_frames += 1
         if int(getattr(self.sink, "decode_errors", 0)) > errs0:
@@ -862,8 +903,9 @@ class FeederRuntime:
         would push the spans a profile needs out of the ring. `start_s`
         is where the first of them began."""
         if self._decode_frames:
-            self.tracer.record(SPAN_FEEDER_DECODE, self._decode_us, start_s=start_s)
-            self._decode_us = self._decode_frames = 0
+            self.tracer.record(SPAN_FEEDER_DECODE, self._decode_us, start_s=start_s,
+                               cpu_us=self._decode_cpu_ns // 1000)
+            self._decode_us = self._decode_cpu_ns = self._decode_frames = 0
 
     # -- the pump --------------------------------------------------------
     def pump(self) -> list:
@@ -880,8 +922,11 @@ class FeederRuntime:
             out, busy = self._pump_spanned()
             if not busy:
                 pump_span.discard()
-                self._count("idle_pumps")
-            return out
+        if not busy:
+            with self._lock:
+                self.counters["idle_pumps"] += 1
+                self.counters["idle_pump_us"] += pump_span.duration_us
+        return out
 
     def _pump_spanned(self) -> tuple[list, bool]:
         """The pump under its feeder.pump span → (outputs, whether it

@@ -94,6 +94,23 @@ class Receiver:
             "frames_held_dropped": 0,
             "frames_redelivered": 0,
         }
+        # the intake threads' clocks, `busy_us` and `cpu_us` of
+        # get_counters(). `_busy_ns`: the wall from a recv that returned
+        # data to the last frame of that chunk routed (reassembly,
+        # _dispatch, _route_frame, the queue puts), summed over the
+        # connection threads and the UDP thread; the wait for the GIL
+        # right after a recv comes before the first read and is not in
+        # it. `_cpu_ns`: those threads' CPU time, WHOLE (the recv calls'
+        # kernel copy too, which busy leaves out, so neither bounds the
+        # other): the thread's CPU clock is a system call (0.3 us on a
+        # plain kernel, 5.5 us on the chip's sandboxed host: PERF.md §6,
+        # PR 38), so a thread reads it once a frame and folds in what it
+        # used since its last frame. The deltas telescope: over a run the
+        # sum is right to one tick of that clock a thread. No span a chunk
+        # or a frame: ~16,000 recvs and ~400 frames a second would turn a
+        # span ring over in seconds
+        self._busy_ns = 0
+        self._cpu_ns = 0
         # bounded (msg_type, group, raw_frame, addr) hold ring — sized
         # for the re-route window of one rebalance, not a durability
         # buffer (the journal is; this only bridges the flip)
@@ -137,6 +154,8 @@ class Receiver:
         with self._stats_lock:
             out = dict(self.counters)
             out["agents_seen"] = len(self.agents)
+            out["busy_us"] = self._busy_ns // 1000
+            out["cpu_us"] = self._cpu_ns // 1000
         return out
 
     # -- key-hash fan-in routing (ISSUE 14) ------------------------------
@@ -250,6 +269,11 @@ class Receiver:
         # thread all dispatch concurrently
         with self._stats_lock:
             self.counters[key] += n
+
+    def _count_clocks(self, busy_ns: int, cpu_ns: int) -> None:
+        with self._stats_lock:
+            self._busy_ns += busy_ns
+            self._cpu_ns += cpu_ns
 
     def _dispatch(self, header: FlowHeader, raw_frame: bytes, addr) -> None:
         if header.encoder != ENCODER_RAW:
@@ -444,6 +468,8 @@ class Receiver:
     def _conn_loop(self, conn: socket.socket, addr) -> None:
         asm = FrameReassembler()
         seen_bad = 0
+        busy_ns = 0  # this thread's stretches since it last folded them in
+        cpu0 = time.thread_time_ns()  # its CPU clock as of then
         try:
             while self._running:
                 try:
@@ -452,14 +478,22 @@ class Receiver:
                     continue
                 if not chunk:
                     return
-                for header, body in asm.feed(chunk):
+                t0 = time.perf_counter_ns()
+                frames = asm.feed(chunk)
+                for header, body in frames:
                     self._dispatch(header, header.encode() + body, addr)
                 if asm.bad_frames != seen_bad:
                     self._count("bad_frames", asm.bad_frames - seen_bad)
                     seen_bad = asm.bad_frames
+                busy_ns += time.perf_counter_ns() - t0
+                if frames:
+                    cpu1 = time.thread_time_ns()
+                    self._count_clocks(busy_ns, cpu1 - cpu0)
+                    busy_ns, cpu0 = 0, cpu1
         except OSError:
             return
         finally:
+            self._count_clocks(busy_ns, time.thread_time_ns() - cpu0)
             with self._lock:
                 self._conns.discard(conn)
             try:
@@ -469,6 +503,7 @@ class Receiver:
 
     # -- UDP (one frame per datagram, receiver.go UDP path) -------------
     def _udp_loop(self) -> None:
+        cpu0 = time.thread_time_ns()
         while self._running:
             try:
                 data, addr = self._udp_sock.recvfrom(1 << 16)
@@ -476,16 +511,24 @@ class Receiver:
                 continue
             except OSError:
                 return
-            self._count("udp_frames")
-            if len(data) < HEADER_LEN:
-                self._count("bad_frames")
-                continue
-            try:
-                header = FlowHeader.parse(data[:HEADER_LEN])
-            except ValueError:
-                self._count("bad_frames")
-                continue
-            if header.frame_size != len(data):
-                self._count("bad_frames")
-                continue
-            self._dispatch(header, data, addr)
+            t0 = time.perf_counter_ns()
+            self._udp_datagram(data, addr)
+            busy_ns = time.perf_counter_ns() - t0
+            cpu1 = time.thread_time_ns()
+            self._count_clocks(busy_ns, cpu1 - cpu0)
+            cpu0 = cpu1
+
+    def _udp_datagram(self, data: bytes, addr) -> None:
+        self._count("udp_frames")
+        if len(data) < HEADER_LEN:
+            self._count("bad_frames")
+            return
+        try:
+            header = FlowHeader.parse(data[:HEADER_LEN])
+        except ValueError:
+            self._count("bad_frames")
+            return
+        if header.frame_size != len(data):
+            self._count("bad_frames")
+            return
+        self._dispatch(header, data, addr)

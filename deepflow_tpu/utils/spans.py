@@ -7,7 +7,7 @@ a *host-driven* batch spends its wall time. This module is that seam: a
 monotonic-clock span recorder with a fixed vocabulary of stage names,
 cheap enough to stay always-on, exposing three faces:
 
-  * `summary()` — per-stage count / total / self / max / last
+  * `summary()` — per-stage count / total / self / cpu / max / last
     aggregates and the compile lanes, for bench JSON snapshots;
   * `get_counters()` — a flat Countable field map so the tracer
     registers on `utils/stats.StatsCollector` like any component and
@@ -26,6 +26,18 @@ adds its duration to its parent's child time, and the aggregate keeps
 measured by the caller, split over non-contiguous sections) nests the
 same way under whatever span is open on the calling thread.
 
+**The CPU lane.** A span also reads its thread's CPU clock
+(`time.thread_time_ns()`) where it reads the wall's, and the aggregate
+keeps `cpu_us`: the CPU time of the span's thread between enter and
+exit, children included, as `total_us` is. `total_us` − `cpu_us` is the
+time the thread was not running: blocked on the device, on a lock, on
+the GIL, or descheduled. Kernel time on the thread (a first-touch page
+fault) is CPU: work. It is ONE thread's clock: where a pass is divided
+over `utils/hostpool.py`'s workers, the span's lane holds the calling
+thread's own share only (the workers open no span). `record()` takes the
+lane from its caller, who measured the wall too; `cpu_us(names)` is the
+one reader for whoever republishes lanes as counters.
+
 The served path's vocabulary, as a tree (`f` = the FeederRuntime's
 tracer, `p` = the pipeline's / WindowManager's; a name lives on one):
 
@@ -36,6 +48,8 @@ tracer, `p` = the pipeline's / WindowManager's; a name lives on one):
                                     summed, ONE record a round
         feeder.dispatch          f  sink.emit of one bucket batch
           feeder.assemble        f  the chunks' writes into the staging buffer
+            feeder.staging_wait  f  only when it blocked: the wait for the
+                                    device to have read that buffer
           ingest.stage           p  three uploads of the staging buffer
           window.fold            p  fold dispatch when the ring is full
           ingest.dispatch        p  the fused step's dispatch
@@ -72,8 +86,9 @@ close's bundled scalar fetch, so its `stats.fetch` is a child of
 holds `flush.sketch_merge`.
 
 A pump that drains nothing and emits nothing records no span (it counts
-`idle_pumps` on the feeder): a starved feeder pumps ~2,000 times a
-second, which would turn the 4,096-record ring over in two.
+itself and its wall on the feeder: `idle_pumps`, `idle_pump_us`): a
+starved feeder pumps ~2,000 times a second, which would turn the
+4,096-record ring over in two.
 
 **Compiles.** One process-wide listener on JAX's
 `/jax/core/compile/backend_compile_duration` event (registered the
@@ -176,6 +191,12 @@ SPAN_FEEDER_COALESCE = "feeder.coalesce"  # journal + decode + bucket assembly
 SPAN_FEEDER_DECODE = "feeder.decode"  # a round's decode_frame calls, summed
 SPAN_FEEDER_DISPATCH = "feeder.dispatch"  # staged batch → sink ingest
 SPAN_FEEDER_ASSEMBLE = "feeder.assemble"  # a batch's chunks written into its staging buffer, the stale tail zeroed
+# feeder.assemble's wait for the step that read the buffer it is about to
+# write (PR 38): recorded only when it blocked, so its count is the
+# feeder's `staging_waits`. On one chip the per-batch stats.fetch syncs
+# first and it never blocks; the sharded manager has no per-batch sync,
+# so there it is the feed's backpressure.
+SPAN_FEEDER_STAGING_WAIT = "feeder.staging_wait"
 FEEDER_SPAN_NAMES = (
     SPAN_FEEDER_PUMP,
     SPAN_FEEDER_DRAIN,
@@ -183,6 +204,7 @@ FEEDER_SPAN_NAMES = (
     SPAN_FEEDER_DECODE,
     SPAN_FEEDER_DISPATCH,
     SPAN_FEEDER_ASSEMBLE,
+    SPAN_FEEDER_STAGING_WAIT,
 )
 
 # Push query plane (ISSUE 11) — emitted by querier/subscribe.py and
@@ -228,6 +250,9 @@ class SpanRecord:
     span_id: str = ""
     parent_span_id: str = ""
     window: str = ""
+    # the recording thread's CPU time inside the span (module docstring);
+    # 0 on a pre-measured record whose caller gave none, and on xla.compile
+    cpu_us: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -290,7 +315,7 @@ SPAN_QUANTILES = (0.5, 0.95, 0.99)
 
 
 class _Agg:
-    __slots__ = ("count", "total_us", "self_us", "max_us", "last_us",
+    __slots__ = ("count", "total_us", "self_us", "cpu_us", "max_us", "last_us",
                  "compiles", "compile_us", "hist")
 
     def __init__(self, bins: int):
@@ -299,6 +324,8 @@ class _Agg:
         # duration less what child records covered (nested spans,
         # record()s made while the span was open, compiles)
         self.self_us = 0
+        # the recording threads' CPU time inside the spans, children included
+        self.cpu_us = 0
         self.max_us = 0
         self.last_us = 0
         # backend compiles charged to this span name (innermost rule)
@@ -310,10 +337,11 @@ class _Agg:
         # concurrent feeder-pump + query threads
         self.hist = np.zeros(bins, np.int64)
 
-    def add(self, dur_us: int, self_us: int, bin_idx: int) -> None:
+    def add(self, dur_us: int, self_us: int, cpu_us: int, bin_idx: int) -> None:
         self.count += 1
         self.total_us += dur_us
         self.self_us += self_us
+        self.cpu_us += cpu_us
         self.last_us = dur_us
         if dur_us > self.max_us:
             self.max_us = dur_us
@@ -395,7 +423,8 @@ class _Span:
     the entry on its thread's stack."""
 
     __slots__ = ("tracer", "name", "window", "span_id", "trace_id",
-                 "parent_id", "child_us", "wall", "t0", "ann", "discarded")
+                 "parent_id", "child_us", "wall", "t0", "cpu0", "ann", "discarded",
+                 "duration_us")
 
     def __init__(self, tracer: "SpanTracer", name: str, window: str):
         self.tracer = tracer
@@ -403,6 +432,7 @@ class _Span:
         self.window = window
         self.child_us = 0
         self.discarded = False
+        self.duration_us = 0  # set on exit, for a caller that counts a discarded span's time
 
     def discard(self) -> None:
         """Leave no record and no aggregate of this span when it closes
@@ -422,10 +452,15 @@ class _Span:
             self.ann.__enter__()
         self.wall = time.time()
         self.t0 = time.perf_counter()
+        # the CPU clock is read inside the wall's two reads, so a span's
+        # cpu_us cannot pass its duration by more than a clock's tick
+        self.cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc) -> None:
-        dur = int((time.perf_counter() - self.t0) * 1e6)
+        # (a discarded span keeps no lane: it saves the second read, a system call)
+        cpu = 0 if self.discarded else (time.thread_time_ns() - self.cpu0) // 1000
+        dur = self.duration_us = int((time.perf_counter() - self.t0) * 1e6)
         if self.ann is not None:
             self.ann.__exit__(*exc)
         stack = _stack()
@@ -440,7 +475,7 @@ class _Span:
         self.tracer._commit(
             SpanRecord(self.name, self.wall, dur, trace_id=self.trace_id,
                        span_id=self.span_id, parent_span_id=self.parent_id,
-                       window=self.window),
+                       window=self.window, cpu_us=cpu),
             max(dur - self.child_us, 0),
         )
 
@@ -470,13 +505,14 @@ class SpanTracer:
         return _Span(self, name, window)
 
     def record(self, name: str, duration_us: int, start_s: float | None = None,
-               *, trace_id: str = "", span_id: str = "",
+               *, cpu_us: int = 0, trace_id: str = "", span_id: str = "",
                parent_span_id: str = "", window: str = ""):
         """Record a pre-measured span — for stages whose work is split
         across non-contiguous host sections (e.g. the sharded advance:
         sketch close before the append, fold after; the feeder's
         per-frame decode) that must count as ONE logical span so
-        cross-path stage attribution compares. With no ids given it
+        cross-path stage attribution compares. `cpu_us` is the CPU lane
+        of those sections, where the caller measured it. With no ids given it
         nests like span(): child of the span open on this thread, whose
         child time it joins. Explicit trace/parent ids + the per-window
         correlation key ride into the export ring untouched (ISSUE 13:
@@ -490,7 +526,8 @@ class SpanTracer:
         self._commit(
             SpanRecord(name, time.time() if start_s is None else start_s,
                        duration_us, trace_id=trace_id, span_id=span_id,
-                       parent_span_id=parent_span_id, window=window),
+                       parent_span_id=parent_span_id, window=window,
+                       cpu_us=int(cpu_us)),
             duration_us,
         )
 
@@ -510,7 +547,7 @@ class SpanTracer:
         bin_idx = self.hist_spec.bin(rec.duration_us)
         with self._lock:
             self._ring.append(rec)
-            self._agg_of(rec.name).add(rec.duration_us, self_us, bin_idx)
+            self._agg_of(rec.name).add(rec.duration_us, self_us, rec.cpu_us, bin_idx)
 
     def _charge_compile(self, span: _Span, us: int, start_s: float) -> None:
         """One backend compile under `span` (still open): the ring gets
@@ -533,6 +570,13 @@ class SpanTracer:
             aggs = [a for n, a in self._agg.items() if names is None or n in names]
             return sum(a.compiles for a in aggs), sum(a.compile_us for a in aggs)
 
+    def cpu_us(self, names: tuple[str, ...]) -> dict[str, int]:
+        """name → the CPU lane of that span name's aggregate, 0 for one
+        that never ran: a lock and a look-up a name, for a Countable that
+        republishes lanes (FeederRuntime.get_counters)."""
+        with self._lock:
+            return {n: a.cpu_us if (a := self._agg.get(n)) else 0 for n in names}
+
     # -- read faces -----------------------------------------------------
     def summary(self) -> dict[str, dict]:
         """Per-stage aggregates, JSON-able (the bench snapshot shape) —
@@ -546,6 +590,7 @@ class SpanTracer:
                     "count": a.count,
                     "total_us": a.total_us,
                     "self_us": a.self_us,
+                    "cpu_us": a.cpu_us,
                     "avg_us": round(a.total_us / a.count, 1) if a.count else 0.0,
                     "max_us": a.max_us,
                     "last_us": a.last_us,
@@ -584,33 +629,9 @@ class SpanTracer:
             return None
         return loghist_quantiles_np(hist, self.hist_spec, qs)
 
-    def tdigest(self, name: str, compression: int = 64):
-        """(means, weights) centroid export of one stage's latency
-        histogram — the same loghist→t-digest compression the r12
-        sketch blocks use (ops/tdigest.tdigest_from_loghist). Dispatches
-        the jitted compressor on a tiny fixed-size array: OFF the
-        Countable face, for wire/bench export only. None when the stage
-        never ran."""
-        with self._lock:
-            a = self._agg.get(name)
-            hist = None if a is None else a.hist.copy()
-        if hist is None:
-            return None
-        import jax.numpy as jnp  # lazy: the tracer itself stays jax-free
-
-        from ..ops.histogram import LogHistSpec
-        from ..ops.tdigest import tdigest_from_loghist
-
-        spec = LogHistSpec(bins=self.hist_spec.bins, vmin=self.hist_spec.vmin,
-                           gamma=self.hist_spec.gamma)
-        m, w = tdigest_from_loghist(
-            jnp.asarray(hist[None, :], jnp.int32), spec, compression=compression
-        )
-        return np.asarray(m[0]), np.asarray(w[0])
-
     def get_counters(self) -> dict[str, int | float]:
-        """Countable face: flat `<stage>.count/.total_us/.self_us/.max_us`
-        fields, the compile lanes `<stage>.compiles/.compile_us`, plus
+        """Countable face: flat `<stage>.count/.total_us/.self_us/.cpu_us/
+        .max_us` fields, the compile lanes `<stage>.compiles/.compile_us`, plus
         the log-histogram p50/p95/p99 lanes (ISSUE 12) — dogfooded
         via integration/dfstats into deepflow_system, where
         `ingest.dispatch.p99_us` becomes the
@@ -618,14 +639,16 @@ class SpanTracer:
         alert rule keys on. Pure numpy, fetch-free, safe from a ticking
         collector thread."""
         with self._lock:
-            aggs = [(name, a.count, a.total_us, a.self_us, a.max_us,
+            aggs = [(name, a.count, a.total_us, a.self_us, a.cpu_us, a.max_us,
                      a.compiles, a.compile_us, a.hist.copy())
                     for name, a in sorted(self._agg.items())]
         out: dict[str, int | float] = {}
-        for name, count, total_us, self_us, max_us, compiles, compile_us, hist in aggs:
+        for (name, count, total_us, self_us, cpu_us, max_us, compiles,
+             compile_us, hist) in aggs:
             out[f"{name}.count"] = count
             out[f"{name}.total_us"] = total_us
             out[f"{name}.self_us"] = self_us
+            out[f"{name}.cpu_us"] = cpu_us
             out[f"{name}.max_us"] = max_us
             out[f"{name}.compiles"] = compiles
             out[f"{name}.compile_us"] = compile_us

@@ -557,7 +557,9 @@ def test_feeder_shed_deterministic_and_accounted():
 
     fc1, pc1 = run()
     fc2, pc2 = run()
-    assert fc1 == fc2
+    # counts, not clocks: the `*_us` lanes (CPU lanes, waits) are times
+    counts = lambda c: {k: v for k, v in c.items() if not k.endswith("_us")}
+    assert counts(fc1) == counts(fc2) and len(counts(fc1)) < len(fc1)
     assert fc1["shed_frames"] > 0 and fc1["pressure_events"] > 0
     # whole frames only: shed records are a multiple of the frame size
     assert fc1["shed_records"] % 10 == 0

@@ -272,10 +272,7 @@ def test_span_hist_quantiles_match_exact_percentiles():
     c = tr.get_counters()
     assert c["stage.x.p50_us"] == pytest.approx(qv[0], rel=1e-3)  # 0.1µs rounding
     assert "p99_us" in tr.summary()["stage.x"]
-    # t-digest export reuses the r12 loghist→centroid compression
-    m, w = tr.tdigest("stage.x")
-    assert w.sum() == pytest.approx(len(durs))
-    assert tr.quantiles("never.ran") is None and tr.tdigest("never.ran") is None
+    assert tr.quantiles("never.ran") is None
 
 
 def test_span_tracer_threaded_stress():

@@ -1,7 +1,9 @@
 """ISSUE 26: the span tree (nesting and self time on one per-thread stack
 shared by every tracer), the served path's leaf spans, the idle pump, the
 compile lanes, the stage names on the device programs, and the per-layer
-metric files that read the new spans and counters."""
+metric files that read the new spans and counters. ISSUE 38: the CPU lane
+of every span, the feeder's two waits and queue depth, the Receiver's
+clocks, and the nine layer files that read them."""
 
 import glob
 import json
@@ -27,6 +29,7 @@ from deepflow_tpu.utils.spans import (
     SPAN_FEEDER_DISPATCH,
     SPAN_FEEDER_DRAIN,
     SPAN_FEEDER_PUMP,
+    SPAN_FEEDER_STAGING_WAIT,
     SPAN_FLUSH_DRAIN,
     SPAN_FLUSH_FETCH,
     SPAN_FLUSH_JOIN,
@@ -73,6 +76,20 @@ SHARDED_LAYERS = (
 )
 # PR 37, the close's two host passes over the pool's threads (every cell's)
 POOLED_LAYERS = ("flush.pooled_byte_share", "flush.reserve_ms_per_window")
+# PR 38, the host's time by owner: CPU lanes, the feeder's two waits, queue
+# depth, the Receiver's clocks (every cell's)
+HOST_TIME_LAYERS = (
+    "feeder.pump_cpu_share", "feeder.starved_ms_per_mrec",
+    "feeder.staging_wait_ms_per_mrec", "feeder.assemble_cpu_ms_per_mrec",
+    "feeder.decode_cpu_ms_per_mrec", "feeder.drain_cpu_ms_per_mrec",
+    "feeder.queue_depth_frames", "receiver.busy_ms_per_mrec",
+    "receiver.cpu_ms_per_mrec",
+)
+# 0.0 is a reading: no acquire of a tiny run need block (one chip: the
+# per-batch stats.fetch syncs first; the CPU's sharded step may have run by
+# the time its buffer comes round), and its pumps need not find the queues
+# empty (the socket may always be ahead of the feeder)
+MAY_READ_ZERO = ("feeder.staging_wait_ms_per_mrec", "feeder.starved_ms_per_mrec")
 
 
 # ---------------------------------------------------------------------------
@@ -161,6 +178,99 @@ def test_hist_bin_matches_the_closed_form():
             int(math.floor(math.log(v / 1.0) / math.log(1.02))), 511)
         assert spec.bin(v) == want, v
     assert SpanHistSpec() == SpanHistSpec()  # the cached log is no part of it
+
+
+# ---------------------------------------------------------------------------
+# (1b) the CPU lane (ISSUE 38). Lanes of one run are compared with each
+# other; no wall clock is held to a bound.
+
+
+def _spin_cpu(ns: int) -> None:
+    """Burn `ns` of this thread's own CPU time."""
+    end = time.thread_time_ns() + ns
+    while time.thread_time_ns() < end:
+        pass
+
+
+def test_cpu_lane_tells_a_sleeping_span_from_a_spinning_one():
+    tr = SpanTracer()
+    with tr.span("sleeps"):
+        time.sleep(0.05)
+    with tr.span("spins"):
+        _spin_cpu(20_000_000)
+    sleeps, spins = tr.summary()["sleeps"], tr.summary()["spins"]
+    assert 0 <= sleeps["cpu_us"] < sleeps["total_us"] // 5
+    # the lane holds what the thread burned and cannot pass the wall. How
+    # near the wall it comes is the machine's to say (on a loaded one a
+    # spinning thread is descheduled: that is wall, not CPU), so the two
+    # spans' shares are held against each other and not against a bound
+    assert 20_000 <= spins["cpu_us"] <= spins["total_us"] + 1
+    assert spins["cpu_us"] * sleeps["total_us"] > 10 * sleeps["cpu_us"] * spins["total_us"]
+    rec, = tr.recent("sleeps")
+    assert rec.cpu_us == sleeps["cpu_us"] and rec.duration_us == sleeps["total_us"]
+
+
+def test_a_parents_cpu_lane_holds_its_childs_and_every_face_carries_it():
+    feeder, pipe = SpanTracer(service="f"), SpanTracer(service="p")
+    with feeder.span("outer"):
+        time.sleep(0.01)
+        with pipe.span("inner"):
+            _spin_cpu(10_000_000)
+    outer, inner = feeder.summary()["outer"], pipe.summary()["inner"]
+    # children included, as total_us is; the sleep adds wall, not CPU
+    assert outer["cpu_us"] >= inner["cpu_us"] >= 10_000
+    assert outer["total_us"] - outer["cpu_us"] >= inner["total_us"] - inner["cpu_us"]
+    assert feeder.get_counters()["outer.cpu_us"] == outer["cpu_us"]
+    assert feeder.recent("outer")[0].cpu_us == outer["cpu_us"]
+    assert pipe.cpu_us(("inner", "never.ran")) == {"inner": inner["cpu_us"], "never.ran": 0}
+    # a discarded span leaves no lane, and says how long it took
+    with pipe.span("idle") as idle:
+        idle.discard()
+        time.sleep(0.002)
+    assert idle.duration_us >= 2_000 and "idle" not in pipe.summary()
+
+
+def test_record_takes_its_cpu_lane_from_the_caller():
+    tr = SpanTracer()
+    with tr.span("round"):
+        tr.record("decode", 900, cpu_us=700)
+        tr.record("decode", 300, cpu_us=250)
+        tr.record("hop", 40)  # a caller that measured no CPU
+    s = tr.summary()
+    assert (s["decode"]["count"], s["decode"]["total_us"], s["decode"]["cpu_us"]) == (2, 1200, 950)
+    assert s["hop"]["cpu_us"] == 0
+    assert [r.cpu_us for r in tr.recent("decode")] == [700, 250]
+    assert tr.get_counters()["decode.cpu_us"] == 950
+    assert tr.cpu_us(("decode",)) == {"decode": 950}
+
+
+def test_a_spinning_neighbour_adds_nothing_to_a_sleeping_spans_cpu():
+    """The lane is ONE thread's clock: while this thread sleeps under its
+    span another spins through the same wall, and waits for the GIL it
+    holds are wall too, not CPU."""
+    tr = SpanTracer()
+    started, stop = threading.Event(), threading.Event()
+
+    def neighbour():
+        with tr.span("spins"):
+            started.set()
+            while not stop.is_set():
+                _spin_cpu(1_000_000)
+
+    t = threading.Thread(target=neighbour)
+    t.start()
+    try:
+        assert started.wait(10)
+        with tr.span("sleeps"):
+            time.sleep(0.08)
+    finally:
+        stop.set()
+        t.join(10)
+    assert not t.is_alive()
+    s = tr.summary()
+    assert s["spins"]["total_us"] >= s["sleeps"]["total_us"] >= 80_000
+    assert s["sleeps"]["cpu_us"] < s["sleeps"]["total_us"] // 5
+    assert s["sleeps"]["cpu_us"] < s["spins"]["cpu_us"] // 4
 
 
 # ---------------------------------------------------------------------------
@@ -282,21 +392,32 @@ def tiny_sketch_run(chipbench_modules):
 def tiny_sharded_run(chipbench_modules):
     """The same run through the sharded deployment the four-chip cell's
     configuration names (`built_by`: chipbench/deployments/l4_sharded.py)
-    on four forced host devices, with pages of 64 rows."""
+    on four forced host devices, with pages of 64 rows and every pass of
+    the close's host half divided over four threads (as `tiny_pooled_run`:
+    the accepted `flush.pooled_byte_share` reads above 0 only so). The
+    patches are on while the run is built and driven and off before a test
+    reads it, whichever fixture was built before or comes after."""
     import deepflow_tpu.aggregator.window as window_mod
+    from deepflow_tpu.utils import hostpool
 
     if len(jax.devices()) < 4:
         pytest.skip("needs four (forced host) devices")
     tiny = chipbench_modules["tiny"].CONFIG
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(window_mod, "PAGE_ROWS", 64)
-        yield from _tiny_run(chipbench_modules, {
+        mp.setattr(hostpool, "WORKERS", 4)
+        mp.setattr(hostpool, "POOL_MIN_BYTES", 0)
+        mp.setattr(hostpool, "_cores", lambda: 64)
+        driven = _tiny_run(chipbench_modules, {
             **tiny, "chips": 4, "built_by": "l4_sharded",
             "pipeline": {**tiny["pipeline"], "accum_batches": 8, "sketch": {
                 "num_groups": 16, "hll_precision": 12, "cms_depth": 4,
                 "cms_width": 4096, "hist_bins": 256, "hist_vmin": 1.0,
                 "hist_gamma": 1.04, "topk_rows": 2, "topk_cols": 512,
                 "pool": None, "pending": 3}}})
+        run = next(driven)
+    yield run
+    driven.close()  # the run's `finally`: the served path closes
 
 
 def test_served_path_emits_every_leaf_span_with_its_count(tiny_run):
@@ -304,7 +425,10 @@ def test_served_path_emits_every_leaf_span_with_its_count(tiny_run):
     assert c["feeder.records_in"] == tiny_run["sent"]
     assert tiny_run["planes"]["run"]["windows_closed"] >= 2
     # a name lives on one tracer: feeder.* on the feeder's, the rest on the pipeline's
-    assert set(FEEDER_SPAN_NAMES) <= set(f) and not set(FEEDER_SPAN_NAMES) & set(p)
+    # (but for the staging wait, which is recorded only when it blocked)
+    assert set(FEEDER_SPAN_NAMES) - {SPAN_FEEDER_STAGING_WAIT} <= set(f)
+    assert not set(FEEDER_SPAN_NAMES) & set(p)
+    assert f.get(SPAN_FEEDER_STAGING_WAIT, {"count": 0})["count"] == c["feeder.staging_waits"]
     for name in (SPAN_INGEST_STAGE, SPAN_INGEST_DISPATCH, SPAN_FLUSH_DRAIN,
                  SPAN_FLUSH_WAIT, SPAN_FLUSH_ROWS, SPAN_FLUSH_SPLIT):
         assert name in p and name not in f and name in PIPELINE_SPAN_NAMES
@@ -335,6 +459,34 @@ def test_children_fit_inside_their_parents(tiny_run):
     for s in (f, p):
         for name, agg in s.items():
             assert 0 <= agg["self_us"] <= agg["total_us"], name
+
+
+@pytest.mark.parametrize("run", ["tiny_run", "tiny_sharded_run"])
+def test_cpu_lanes_fit_inside_their_walls_on_the_served_path(run, request):
+    run = request.getfixturevalue(run)
+    f, p, c = run["feeder"], run["pipe"], run["planes"]["counters"]
+    for s in (f, p):
+        for name, agg in s.items():
+            # the CPU clock is read inside the wall's reads; a microsecond a
+            # span for the two truncations
+            assert 0 <= agg["cpu_us"] <= agg["total_us"] + agg["count"], name
+    # a parent's lane holds its children's, across the two tracers
+    assert f[SPAN_FEEDER_ASSEMBLE]["cpu_us"] + p[SPAN_INGEST_STAGE]["cpu_us"] \
+        <= f[SPAN_FEEDER_DISPATCH]["cpu_us"] + 2 * f[SPAN_FEEDER_DISPATCH]["count"]
+    assert f[SPAN_FEEDER_DECODE]["cpu_us"] <= f[SPAN_FEEDER_COALESCE]["cpu_us"] \
+        + f[SPAN_FEEDER_COALESCE]["count"]
+    assert f[SPAN_FEEDER_PUMP]["cpu_us"] > 0 and f[SPAN_FEEDER_DECODE]["cpu_us"] > 0
+    # the feeder's counters are those lanes (the window's share of them)
+    for name in set(FEEDER_SPAN_NAMES) - {SPAN_FEEDER_STAGING_WAIT}:
+        assert 0 <= c[f"{name}_cpu_us"] <= f[name]["cpu_us"], name
+    assert f"{SPAN_FEEDER_STAGING_WAIT}_cpu_us" not in c  # that lane stays on the tracer
+    # the Receiver's threads: both clocks advanced (the CPU is the threads'
+    # whole, the recv calls' too: neither bounds the other)
+    assert c["receiver.cpu_us"] > 0 and c["receiver.busy_us"] > 0
+    # the feeder looked at its queues and found frames waiting there
+    assert 0 < c["feeder.queue_depth_sum"] and c["feeder.queue_visits"] >= 4
+    assert c["feeder.idle_pump_us"] >= 0 and c["feeder.staging_wait_us"] >= 0
+    assert (c["feeder.staging_wait_us"] > 0) == (c["feeder.staging_waits"] > 0)
 
 
 def test_the_reserve_is_host_work_inside_the_wait(tiny_run, tiny_paged_run):
@@ -488,6 +640,33 @@ def test_pooled_layer_file_reads_a_number_where_the_close_reserves(
         "feeder.records_in": 1}, "run": {"windows_closed": 1}}) is None
 
 
+@pytest.mark.parametrize("name", HOST_TIME_LAYERS)
+def test_host_time_layer_file_reads_a_number_from_both_tiny_runs(
+        name, tiny_run, tiny_sharded_run, chipbench_modules):
+    layers = chipbench_modules["layers"]
+    spec = layers.load_layer(name)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert {k: spec[k] for k in entry} == entry and "workloads" not in entry
+    assert (entry["layer"], entry["moves"]) == ("wire in + feeder", "records_per_s")
+    assert entry["source"] == (
+        "program_span" if name == "feeder.pump_cpu_share" else "program_counter")
+    for run in (tiny_run, tiny_sharded_run):
+        value = layers.read_metric(spec, run["planes"])
+        assert isinstance(value, float) and value >= 0.0
+        if name not in MAY_READ_ZERO:
+            assert value > 0.0
+        if name == "feeder.pump_cpu_share":
+            assert value <= 100.0 + 1e-3
+    # a program without the counters (the parent commit) reads nothing, not
+    # 0: its spans and its other counters are there
+    parent = {"spans": {"feeder.pump": {"count": 3, "total_us": 900}},
+              "counters": {"feeder.records_in": 1, "feeder.staging_waits": 0,
+                           "feeder.idle_pumps": 7, "receiver.rx_frames": 2},
+              "run": {"windows_closed": 1}}
+    assert layers.read_metric(spec, parent) is None
+
+
 # a trace plane by hand: the device's share of a tiny run is not the CPU's to give
 _TRACE = {"trace": {"slice_records": 30_000, "busy_s": 0.5,
                     "module_s": {"fused_step": 0.01, "sharded_window_close": 0.001}},
@@ -525,14 +704,17 @@ def test_the_sharded_manager_has_every_span_and_counter_the_accepted_metrics_rea
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         everyones = [m for m in json.load(f)["per_layer"] if "workloads" not in m
                      and m["source"] in ("program_span", "program_counter")]
-    assert len(everyones) == 25  # 23 until PR 37 added the pooled share and the reserve
+    # 23 until PR 37 added the pooled share and the reserve, 25 until PR 38
+    # added the host's time by owner
+    assert len(everyones) == 34
     for m in everyones:
         value = layers.read_metric(layers.load_layer(m["name"]), tiny_sharded_run["planes"])
         assert isinstance(value, float), m["name"]
-        # 0.0 where no close compiled; and where none of this run's three
+        # 0.0 where no close compiled; where none of this run's three
         # closes fitted its reserve (tests/test_sharded_deployment.py has
-        # a run in which they do)
-        if m["name"] not in ("flush.compile_ms_per_window", "flush.reserved_row_share"):
+        # a run in which they do); and MAY_READ_ZERO's two
+        if m["name"] not in ("flush.compile_ms_per_window", "flush.reserved_row_share",
+                             *MAY_READ_ZERO):
             assert value > 0.0, m["name"]
     p = tiny_sharded_run["pipe"]
     assert p["flush.sketch_merge"]["total_us"] <= p["flush.sketch"]["total_us"] \
@@ -547,7 +729,8 @@ def test_layer_files_and_benchmark_entries_pair_up():
         names = [m["name"] for m in json.load(f)["per_layer"]]
     files = {os.path.basename(p)[:-5]
              for p in glob.glob(os.path.join(CHIPBENCH, "layers", "*.json"))}
-    new = list(NEW_LAYERS) + list(SHARDED_LAYERS) + list(POOLED_LAYERS)
+    new = (list(NEW_LAYERS) + list(SHARDED_LAYERS) + list(POOLED_LAYERS)
+           + list(HOST_TIME_LAYERS))
     assert set(names) == files and names[-len(new):] == new
 
 
@@ -564,10 +747,188 @@ def test_idle_pump_records_no_span_and_counts_itself():
 
     feeder = FeederRuntime([new_queue(16), new_queue(16)], Sink(), FeederConfig(),
                            name="idle")
+    assert feeder.get_counters()["idle_pump_us"] == 0
     for _ in range(3):
         assert feeder.pump() == []
     assert feeder.tracer.recent() == [] and feeder.tracer.summary() == {}
-    assert feeder.get_counters()["idle_pumps"] == 3
+    c = feeder.get_counters()
+    assert c["idle_pumps"] == 3
+    # their wall is kept (three pumps of two queue visits each cannot take
+    # under a microsecond), and still no span, no aggregate, no CPU lane
+    assert c["idle_pump_us"] > 0
+    assert all(v == 0 for k, v in c.items() if k.endswith("_cpu_us"))
+    assert (c["queue_visits"], c["queue_depth_sum"]) == (6, 0)
+
+
+def test_queue_depth_is_read_where_the_feeder_looks():
+    """`queue_depth_sum` adds up `len(q)` as each visit finds it, before
+    the visit drains the queue; `queue_visits` counts the visits."""
+    from deepflow_tpu.feeder import FeederConfig, FeederRuntime
+    from deepflow_tpu.ingest.queues import new_queue
+
+    class Sink:
+        bucket_sizes = (8,)
+
+        def decode_frame(self, raw):
+            return None  # a frame of no rows: taken off the queue, nothing to emit
+
+        def count_records(self, raw):
+            return 0
+
+    queues = [new_queue(16), new_queue(16)]
+    feeder = FeederRuntime(queues, Sink(), FeederConfig(frames_per_queue=2, rounds_per_pump=1),
+                           name="depth")
+    for _ in range(3):
+        queues[0].put(b"frame")
+    queues[1].put(b"frame")
+    feeder.pump()  # finds 3 and 1, takes 2 and 1
+    c = feeder.get_counters()
+    assert (c["queue_visits"], c["queue_depth_sum"], c["frames_in"]) == (2, 4, 3)
+    feeder.pump()  # finds 1 and 0
+    feeder.pump()  # finds 0 and 0: idle
+    c = feeder.get_counters()
+    assert (c["queue_visits"], c["queue_depth_sum"], c["frames_in"]) == (6, 5, 4)
+    assert c["idle_pumps"] == 1 and c["idle_pump_us"] > 0
+    # the CPU lanes the feeder republishes are its tracer's own
+    lanes = feeder.tracer.summary()
+    assert c["pump_cpu_us"] == lanes[SPAN_FEEDER_PUMP]["cpu_us"]
+    assert c["drain_cpu_us"] == lanes[SPAN_FEEDER_DRAIN]["cpu_us"]
+    assert c["decode_cpu_us"] == lanes[SPAN_FEEDER_DECODE]["cpu_us"]
+    assert c["staging_wait_us"] == 0
+    # six lanes, the ones a layer file or the traced run's deltas read
+    assert sorted(k for k in c if k.endswith("_cpu_us")) == [
+        "assemble_cpu_us", "coalesce_cpu_us", "decode_cpu_us", "dispatch_cpu_us",
+        "drain_cpu_us", "pump_cpu_us"]
+
+
+# ---------------------------------------------------------------------------
+# (3b) the Receiver's clocks (ISSUE 38)
+
+
+def _receiver_with_queue():
+    from deepflow_tpu.ingest.framing import MessageType
+    from deepflow_tpu.ingest.queues import new_queue
+    from deepflow_tpu.ingest.receiver import Receiver
+
+    rx, q = Receiver(), new_queue(64)
+    rx.register_handler(MessageType.METRICS, [q])
+    rx.start()
+    return rx, q
+
+
+def _metrics_frame(agent_id: int, size: int) -> bytes:
+    from deepflow_tpu.ingest.framing import FlowHeader, MessageType, encode_frame
+
+    return encode_frame(FlowHeader(msg_type=int(MessageType.METRICS), agent_id=agent_id),
+                        [bytes(size)])
+
+
+def _wait_for(cond, what: str) -> None:
+    deadline = time.monotonic() + 30
+    while not cond():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.002)
+
+
+def test_receiver_threads_clock_their_own_work_through_a_real_socket():
+    rx, q = _receiver_with_queue()
+    try:
+        with socket.create_connection(("127.0.0.1", rx.tcp_port), timeout=30) as sock:
+            _wait_for(lambda: rx.get_counters()["tcp_conns"] == 1, "no connection")
+            c = rx.get_counters()
+            assert (c["busy_us"], c["cpu_us"], c["rx_frames"]) == (0, 0, 0)
+            # frames of several recvs each: most stretches only reassemble
+            for i in range(6):
+                sock.sendall(_metrics_frame(i, 200_000))
+            _wait_for(lambda: rx.get_counters()["rx_frames"] == 6, "frames not routed")
+            c = rx.get_counters()
+            # the CPU is the thread's whole since it started, the recv calls'
+            # kernel copy too; busy the stretches after a recv: neither
+            # bounds the other
+            assert len(q) == 6 and c["busy_us"] > 0 and c["cpu_us"] > 0
+        # a connection that closes folds its last stretch in and stops the clocks
+        _wait_for(lambda: not rx._conns, "connection not closed")
+        after = rx.get_counters()
+        assert after["busy_us"] >= c["busy_us"] and after["cpu_us"] >= c["cpu_us"]
+        time.sleep(0.02)
+        again = rx.get_counters()
+        assert (again["busy_us"], again["cpu_us"]) == (after["busy_us"], after["cpu_us"])
+    finally:
+        rx.stop()
+
+
+def test_receiver_reads_the_cpu_clock_once_a_frame_not_once_a_recv(monkeypatch):
+    """The thread's CPU clock is a system call (5.5 us on the chip's host):
+    a connection thread reads it when it starts, once a frame routed and
+    when it ends, and folds in what it used since the read before; the wall
+    clock twice a recv. The lane is the thread's own: a neighbour that
+    spins through the same wall adds nothing to it."""
+    reads = {"cpu": 0, "wall": 0}
+    cpu_clock, wall_clock = time.thread_time_ns, time.perf_counter_ns
+
+    def counted(kind, clock):
+        def read():
+            if threading.current_thread().name == "rx-under-test":
+                reads[kind] += 1
+            return clock()
+        return read
+
+    monkeypatch.setattr(time, "thread_time_ns", counted("cpu", cpu_clock))
+    monkeypatch.setattr(time, "perf_counter_ns", counted("wall", wall_clock))
+    rx, q = _receiver_with_queue()
+    stop = threading.Event()
+    spun = []
+
+    def neighbour():
+        t0 = cpu_clock()
+        while not stop.is_set():
+            _spin_cpu(1_000_000)
+        spun.append(cpu_clock() - t0)
+
+    spinner = threading.Thread(target=neighbour, daemon=True)
+    try:
+        server, client = socket.socketpair()
+        t = threading.Thread(target=rx._conn_loop, args=(server, ("pair", 0)),
+                             name="rx-under-test", daemon=True)
+        server.settimeout(0.5)
+        t.start()
+        spinner.start()
+        for i in range(5):
+            raw = _metrics_frame(i, 150_000)
+            for off in range(0, len(raw), 10_000):  # fifteen and a bit recvs a frame at least
+                client.sendall(raw[off:off + 10_000])
+                time.sleep(0.0005)
+        _wait_for(lambda: rx.get_counters()["rx_frames"] == 5, "frames not routed")
+        client.close()
+        t.join(10)
+        stop.set()
+        spinner.join(10)
+        assert not t.is_alive() and not spinner.is_alive() and len(q) == 5
+        # start, a frame (five), end; the wall twice a recv
+        assert reads["cpu"] == 1 + 5 + 1 and reads["wall"] >= 2 * 20
+        c = rx.get_counters()
+        # the connection thread slept between the sender's pieces while the
+        # neighbour spun: lanes of one run against each other, no wall bound
+        assert 0 < c["cpu_us"] * 1000 < spun[0] and c["busy_us"] > 0
+    finally:
+        stop.set()
+        rx.stop()
+
+
+def test_receiver_udp_thread_clocks_its_datagrams():
+    rx, q = _receiver_with_queue()
+    try:
+        assert rx.get_counters()["busy_us"] == 0
+        with socket.socket(socket.AF_INET, socket.SOCK_DGRAM) as sock:
+            for i in range(4):
+                sock.sendto(_metrics_frame(i, 1_000), ("127.0.0.1", rx.udp_port))
+            sock.sendto(b"short", ("127.0.0.1", rx.udp_port))  # counted, clocked too
+        _wait_for(lambda: rx.get_counters()["udp_frames"] == 5, "datagrams not taken")
+        c = rx.get_counters()
+        assert (c["rx_frames"], c["bad_frames"], len(q)) == (4, 1, 4)
+        assert c["busy_us"] > 0 and c["cpu_us"] >= 0
+    finally:
+        rx.stop()
 
 
 # ---------------------------------------------------------------------------

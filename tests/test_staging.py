@@ -7,8 +7,9 @@ chip's asynchronous transfer makes.
 (a) 3 x the ring's length of batches with distinct contents through
 FeederRuntime -> PipelineFeedSink -> L4Pipeline at chipbench/tests/tiny.py's
 shapes: every flushed window equals the NumPy group-by; (b) a buffer held
-in flight makes the writer wait, and the wait is counted; staged batches
-held undispatched are never written over; (c) the failure paths of
+in flight makes the writer wait, and the wait is counted and (ISSUE 38)
+timed under a span of its own; staged batches held undispatched are never
+written over; (c) the failure paths of
 tests/test_chaos.py keep their counts and leave the ring as it was; (d)
 [count] `bytes_uploaded` a batch and the one span each a batch.
 """
@@ -17,6 +18,7 @@ from __future__ import annotations
 
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -40,7 +42,11 @@ from deepflow_tpu.feeder import (
 )
 from deepflow_tpu.ingest.queues import PyOverwriteQueue
 from deepflow_tpu.ingest.replay import SyntheticFlowGen
-from deepflow_tpu.utils.spans import SPAN_FEEDER_ASSEMBLE, SPAN_INGEST_STAGE
+from deepflow_tpu.utils.spans import (
+    SPAN_FEEDER_ASSEMBLE,
+    SPAN_FEEDER_STAGING_WAIT,
+    SPAN_INGEST_STAGE,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHIPBENCH = os.path.join(ROOT, "chipbench")
@@ -206,6 +212,60 @@ def test_writer_waits_for_a_buffer_in_flight_and_counts_it():
         batch(11)
         assert feeder.get_counters()["staging_waits"] == 1
         assert pipe.staging.allocated == STAGING_RING_LEN
+    finally:
+        pipe.close()
+
+
+class _SlowHandle(_Handle):
+    """In flight for `delay_s` more once somebody waits for it; it keeps
+    how long that wait took on the clock the spans use."""
+
+    def __init__(self, delay_s: float):
+        super().__init__()
+        self.delay_s = delay_s
+        self.blocked_us = 0
+
+    def block_until_ready(self):
+        t0 = time.perf_counter()
+        time.sleep(self.delay_s)
+        super().block_until_ready()
+        self.blocked_us = int((time.perf_counter() - t0) * 1e6)
+
+
+@pytest.mark.parametrize("ready", [False, True])
+def test_staging_wait_is_a_span_of_its_own_only_when_it_blocked(ready):
+    pipe = _small_pipe()
+    q = PyOverwriteQueue(64)
+    feeder = FeederRuntime([q], PipelineFeedSink(pipe), FeederConfig())
+    gen = SyntheticFlowGen(num_tuples=40, seed=7)
+    try:
+        for i in range(2 * STAGING_RING_LEN + 1):
+            if i == 2 * STAGING_RING_LEN:
+                # the next buffer out: the step that read it has, or has not, run
+                bufs, = pipe.staging._rings.values()
+                h = _SlowHandle(0.02)
+                h.waited = int(ready)
+                bufs[0].dispatched(h)
+            for fr in encode_flowbatch_frames(gen.flow_batch(40, T0 + i)):
+                q.put(fr)
+            feeder.pump()
+        c, tr = feeder.get_counters(), feeder.tracer
+        waits = tr.recent(SPAN_FEEDER_STAGING_WAIT)
+        if ready:
+            assert waits == [] and SPAN_FEEDER_STAGING_WAIT not in tr.summary()
+            assert (c["staging_waits"], c["staging_wait_us"], h.blocked_us) == (0, 0, 0)
+            return
+        wait, = waits
+        assemble = {r.span_id: r for r in tr.recent(SPAN_FEEDER_ASSEMBLE)}
+        # a child of the batch's feeder.assemble, whose time holds it
+        assert assemble[wait.parent_span_id].duration_us >= wait.duration_us
+        assert c["staging_waits"] == 1 == tr.summary()[SPAN_FEEDER_STAGING_WAIT]["count"]
+        assert c["staging_wait_us"] == wait.duration_us >= h.blocked_us >= 20_000
+        # the thread slept through it: wall, not CPU (the lane is the span's
+        # own: the feeder republishes six lanes as counters, not this one)
+        assert wait.cpu_us < wait.duration_us // 4 and "staging_wait_cpu_us" not in c
+        assert tr.get_counters()[f"{SPAN_FEEDER_STAGING_WAIT}.cpu_us"] == wait.cpu_us
+        assert c["assemble_cpu_us"] < tr.summary()[SPAN_FEEDER_ASSEMBLE]["total_us"]
     finally:
         pipe.close()
 
