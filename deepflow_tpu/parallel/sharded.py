@@ -40,6 +40,7 @@ from ..aggregator.sketchplane import (
     SENTINEL_WIN,
     SketchConfig,
     SketchState,
+    WindowSketchBlock,
     _drain_impl as _sketch_drain_impl,
     _flatten_open,
     _pool_mode,
@@ -101,8 +102,8 @@ from ..ops.segment import SENTINEL_SLOT, out_blocks_total
 # a leading [R] ring dim), and `window_close` still returns the merged
 # cross-mesh view, so existing consumers keep working; per-window
 # blocks additionally drain through `ShardedWindowManager` at every
-# advance (host-merged across devices — exactly the drain pattern the
-# exact rows already use).
+# advance (merged across devices by window, on the devices with the
+# pool off, on the host with it on: `_merged_block_slots`).
 SketchPlanes = SketchState
 
 
@@ -126,6 +127,101 @@ def _row_tiled(state: StashState) -> StashState:
         tags=with_layout_constraint(state.tags, plain),
         meters=with_layout_constraint(state.meters, plain),
     )
+
+
+def _merged_block_slots(config: "ShardedConfig", n_devices: int) -> int:
+    """Windows a sketch drain merges on the devices (0: none, the host
+    merges each device's blocks).
+
+    Only full-width pend rows merge there: a compact pool row packs four
+    HLL registers to a word, so a max over its words is not the max of
+    its registers, and a window can be compact on one device and wide on
+    another; with the pool on every block goes to the host as before.
+    Otherwise a drain closes only windows of its open span [S, S + ring)
+    (`sketch_plane_step` sheds rows past the ring, the host gate those
+    before S), and a device at most `sketch_pending` of them: so many
+    slots always hold every window of the drain."""
+    if config.sketch_pool is not None:
+        return 0
+    return min(n_devices * config.sketch_pending, config.sketch_ring)
+
+
+def _merge_closed_blocks(pend, pend_win, pend_n, cfg: SketchConfig, axes,
+                         slots: int):
+    """One device's drained blocks merged with every other device's,
+    by window, inside the drain's shard_map body: `WindowSketchBlock.merge`'s
+    algebra on the devices, so that the host fetches one block a window.
+
+    The windows are the sorted union of the devices' live `pend_win`
+    (a device that had no row for a window holds no block of it, and
+    may hold its others at other positions); each device lines its own
+    blocks up under them, an absent one as zeros: registers 0, counters
+    0, no candidates. Then, across the mesh: HLL registers `pmax` (as
+    int32, the host's type; registers are never negative, so 0 is the
+    identity); `n_updates`, count-min and histogram counters summed as
+    their 16-bit halves apart, since the u32 words of four devices can
+    add up past 2^32 where the host adds them as int64; the top-K lanes
+    gathered in device order, for the host's candidate union.
+
+    Returns (rows [slots, W] u32, wins [slots] u32 ascending, SENTINEL
+    past the last, n i32), the same on every device. A row is the max
+    registers, the low halves' sums and the high halves' sums of
+    [n_updates ‖ count-min ‖ histogram], then each device's five top-K
+    lanes (`_merged_planes` and `_merged_candidates` unpack it)."""
+    p = pend.shape[0]
+    gm = cfg.num_groups * cfg.hll_m
+    counted = 1 + gm + cfg.cms_depth * cfg.cms_width + cfg.num_groups * cfg.hist.bins
+    sentinel = jnp.uint32(SENTINEL_WIN)
+    mine = jnp.where(jnp.arange(p) < pend_n, pend_win, sentinel)
+    every = jnp.sort(lax.all_gather(mine, axes, tiled=True))
+    first = jnp.concatenate([every[:1] != sentinel,
+                             (every[1:] != every[:-1]) & (every[1:] != sentinel)])
+    at = jnp.where(first, jnp.cumsum(first) - 1, slots)
+    wins = jnp.full((slots,), sentinel).at[at].set(every, mode="drop")
+    match = (mine[None, :] == wins[:, None]) & (wins[:, None] != sentinel)
+    rows = jnp.where(jnp.any(match, axis=1)[:, None],
+                     pend[jnp.argmax(match, axis=1)], jnp.uint32(0))
+    hll = lax.bitcast_convert_type(rows[:, 1:1 + gm], jnp.int32)
+    hll = lax.bitcast_convert_type(lax.pmax(hll, axes), jnp.uint32)
+    sums = jnp.concatenate([rows[:, :1], rows[:, 1 + gm:counted]], axis=1)
+    lo = lax.psum(sums & jnp.uint32(0xFFFF), axes)
+    hi = lax.psum(sums >> jnp.uint32(16), axes)
+    cands = rows[:, counted:]
+    if cands.shape[1]:
+        cands = lax.all_gather(cands, axes, axis=1).reshape(slots, -1)
+    merged = jnp.concatenate([hll, lo, hi, cands], axis=1)
+    return merged, wins, jnp.sum(first).astype(jnp.int32)
+
+
+def _merged_planes(row: np.ndarray, cfg: SketchConfig) -> dict:
+    """A fetched row of `_merge_closed_blocks`: its window's `n_updates`
+    and planes in `WindowSketchBlock`'s types, registers int32 (a view),
+    counters int64 (the low halves' sum + the high halves' sum << 16:
+    the host merge's int64 sum)."""
+    g, gm = cfg.num_groups, cfg.num_groups * cfg.hll_m
+    dw = cfg.cms_depth * cfg.cms_width
+    n = 1 + dw + g * cfg.hist.bins
+    sums = np.left_shift(row[gm + n:gm + 2 * n], 16, dtype=np.int64)
+    sums += row[gm:gm + n]
+    return {
+        "n_updates": int(sums[0]),
+        "hll": row[:gm].view(np.int32).reshape(g, cfg.hll_m),
+        "cms": sums[1:1 + dw].reshape(cfg.cms_depth, cfg.cms_width),
+        "hist": sums[1 + dw:].reshape(g, cfg.hist.bins),
+    }
+
+
+def _merged_candidates(row: np.ndarray, cfg: SketchConfig, n_devices: int) -> dict:
+    """The same row's candidate union: devices 0..D-1, each one's lanes
+    with votes > 0, in the order `WindowSketchBlock.merge` concatenates
+    them."""
+    k = cfg.topk_rows * cfg.topk_cols
+    tk = row[row.shape[0] - 5 * n_devices * k:].reshape(n_devices, 5, k)
+    votes = tk[:, 0].astype(np.int32).astype(np.int64)
+    keep = votes > 0
+    hi, lo, ida, idb = (tk[:, i][keep].astype(np.uint32) for i in range(1, 5))
+    return {"tk_hi": hi, "tk_lo": lo, "tk_ida": ida, "tk_idb": idb,
+            "tk_votes": votes[keep]}
 
 
 @jax.tree_util.register_dataclass
@@ -236,6 +332,8 @@ class ShardedPipeline:
         self._close = self._build_window_close()
         self._flush = self._build_flush()
         self._flush_range = self._build_flush_range()
+        # windows a drain merges on the devices; 0 = the host merges
+        self.merged_block_slots = _merged_block_slots(config, self.n_devices)
         self._sketch_drain = self._build_sketch_drain()
         self._snapshot = self._build_snapshot()
         # per-ratio tier-fold kernels (ISSUE 9), built on first use —
@@ -492,13 +590,23 @@ class ShardedPipeline:
         """Per-device pending-drain (+ forced close below a bound) —
         the sketch twin of _build_flush_range: one device call, outputs
         fetched by the manager bundled into the flush drain's existing
-        transfers."""
+        transfers. Where `merged_block_slots` is over 0 (the pool off)
+        the devices' closed blocks merge by window across the mesh in
+        the same call (`_merge_closed_blocks`), and what comes back in
+        place of each device's own pend is the merged blocks, the same
+        on every device."""
+        axes, slots = self.axes, self.merged_block_slots
+        sk_cfg = self.config.sketch_config()
 
         def sketch_drain_sharded(sk, close_w):
             sk1 = jax.tree.map(lambda x: x[0], sk)
             new_sk, pend, pend_win, n, wide_rows, wide_wins = (
                 _sketch_drain_impl(sk1, close_w)
             )
+            if slots:
+                pend, pend_win, n = _merge_closed_blocks(
+                    pend, pend_win, n, sk_cfg, axes, slots
+                )
             expand = lambda x: x[None]
             return (
                 jax.tree.map(expand, new_sk),
@@ -521,7 +629,10 @@ class ShardedPipeline:
         pend_win [D, P], pend_n [D], wide_rows [D, Pw, WIDE],
         wide_wins [D, Pw]). The wide arrays are zero-size in slab mode;
         in pool mode they carry each wide pool slot's in-place drained
-        block (win == SENTINEL_WIN rows are dead — host filters)."""
+        block (win == SENTINEL_WIN rows are dead — host filters). With
+        `merged_block_slots` over 0 the three pend outputs are the
+        merged blocks instead: [D, slots, W] rows, their windows
+        ascending, and their count, each device's copy the same."""
         return self._sketch_drain(sketches, jnp.uint32(close_below))
 
     # -- live read plane (ISSUE 10) --------------------------------------
@@ -809,8 +920,12 @@ class _DevicePages:
     the shards into a fresh host array first, a copy (on a TPU a
     transpose) of every row that `join_rows` then copies again."""
 
-    def __init__(self, x, counts, page_rows: int | None = None):
+    def __init__(self, x, counts, page_rows: int | None = None,
+                 views: bool = False):
         self.counts = [int(c) for c in counts]
+        # `join` hands each device's rows on as a list of views of the
+        # fetched pages, joined into nothing
+        self.views = views
         paged = window_mod._PagedRows(
             x, max(self.counts, default=0), axis=1, page_rows=page_rows
         )
@@ -863,13 +978,16 @@ class _DevicePages:
         self.joined_bytes += out.nbytes
         return self._copy(cut, out)
 
-    def join(self, fetched: list) -> list[np.ndarray]:
+    def join(self, fetched: list) -> list:
         """Each device's rows as one host array (a view of the fetched
-        shard where one page held them)."""
+        shard where one page held them), or with `views` as a list of
+        its rows, each a view of a fetched shard."""
         out = []
         self.joined_bytes = self.copied_bytes = self.pooled_bytes = 0
         for cut in self._cuts(fetched):
-            if not cut:
+            if self.views:
+                out.append([row for c in cut for row in c])
+            elif not cut:
                 out.append(np.zeros(*self._no_rows))
             elif len(cut) == 1:
                 out.append(cut[0])
@@ -987,14 +1105,16 @@ class ShardedWindowManager:
         # merged sketch views of the last closed window (None until one closes)
         self.global_view = None
         self.pod_1m = None
-        # per-window sketch tier (ISSUE 8): closed blocks host-merged
-        # across devices, in window order. BOUNDED drop-oldest-counted
+        # per-window sketch tier (ISSUE 8): closed blocks merged across
+        # devices, in window order. BOUNDED drop-oldest-counted
         # (like the device pending buffer) so an undrained consumer
         # cannot leak a block per window forever.
         self.closed_sketches: list = []
         self.max_held_sketches = 512
         self.sketch_blocks_closed = 0
         self.sketch_blocks_dropped = 0
+        # of the closed blocks, those that came off the devices merged
+        self.sketch_blocks_device_merged = 0
         # pooled sketch memory (ISSUE 20): summed-over-devices spill/
         # promotion/occupancy mirrors, updated at advance drains via the
         # bundled scalar fetch (zero when the pool is off)
@@ -1184,6 +1304,7 @@ class ShardedWindowManager:
             # the drop-oldest overflow count (non-zero = nobody drains
             # pop_closed_sketches)
             "sketch_blocks_closed": self.sketch_blocks_closed,
+            "sketch_blocks_device_merged": self.sketch_blocks_device_merged,
             "sketch_blocks_held": len(self.closed_sketches),
             "sketch_blocks_dropped": self.sketch_blocks_dropped,
             # pooled sketch memory (ISSUE 20): cumulative spill +
@@ -1212,7 +1333,7 @@ class ShardedWindowManager:
         self.lineage = tracker
 
     def pop_closed_sketches(self) -> list:
-        """Drain the host-merged closed WindowSketchBlocks (window
+        """Drain the merged closed WindowSketchBlocks (window
         order). The sketch twin of the DocBatches `ingest` returns."""
         out, self.closed_sketches = self.closed_sketches, []
         return out
@@ -1371,9 +1492,10 @@ class ShardedWindowManager:
     def _drain_range(self, lo: int, hi: int):
         """Flush [lo, hi) from every device stash in one fused call and
         regroup the packed rows into per-window DocBatches; the sketch
-        tier's closed blocks (ISSUE 8) drain in the SAME two transfers
-        and are host-merged across devices by window into
-        `closed_sketches`.
+        tier's closed blocks (ISSUE 8) drain in the SAME two transfers,
+        merged across devices by window (one merged block a window off
+        device 0 where the drain merged them, else each device's, merged
+        here) into `closed_sketches`.
 
         Host pays: ONE scalar vector (`flush.wait`, the manager's one
         counter sync: `stats.fetch`) + ONE list of fixed-size pages
@@ -1453,6 +1575,9 @@ class ShardedWindowManager:
             self.fold_blocks_run_sum += int(next(lane).sum())
             self.fold_blocks_total_sum += self._fold_blocks_total
             pend_np = next(lane)
+            if self.pipe.merged_block_slots:
+                # the merged blocks, the same on every device: device 0's
+                pend_np[1:] = 0
             self.stash_live_rows_sum += int(next(lane).sum())
             self.stash_capacity_rows_sum += d * packed.shape[1]
             self.stash_evictions = int(next(lane).sum())
@@ -1484,8 +1609,10 @@ class ShardedWindowManager:
         with self.tracer.span(SPAN_FLUSH_ROWS):
             exact = _DevicePages(packed, totals_np)
             # a page of ONE block: a device that holds one closed block
-            # sends that block, not all of `pend`
-            blocks = _DevicePages(pend, pend_np, page_rows=1)
+            # sends that block, not all of `pend`; merged blocks are
+            # read where they were fetched
+            blocks = _DevicePages(pend, pend_np, page_rows=1,
+                                  views=bool(self.pipe.merged_block_slots))
             sk_parts = [(blocks, int(pend_np.sum()))]
             parts = [exact, blocks, _DevicePages(pend_win, pend_np)]
             if n_wide:
@@ -1519,26 +1646,10 @@ class ShardedWindowManager:
             tier_blocks = list(got)
         with self.tracer.span(SPAN_FLUSH_SPLIT):
             with self.tracer.span(SPAN_FLUSH_SKETCH):
-                per_dev = [
-                    unpack_drained(pend_rows[dev], pend_wins[dev], self._sk_cfg)
-                    for dev in range(d)
-                ]
-                if wide is not None:
-                    # drained wide pool slots (ISSUE 20) merge into the
-                    # same per-window dict — a window promoted on one
-                    # device and compact on another unifies here by the
-                    # r12 algebra
-                    for dev in range(d):
-                        keep = wide[1][dev] != np.uint32(SENTINEL_WIN)
-                        per_dev.append(unpack_drained(
-                            wide[0][dev][keep], wide[1][dev][keep], self._sk_cfg
-                        ))
-                merged: dict[int, object] = {}
-                with self.tracer.span(SPAN_FLUSH_SKETCH_MERGE):
-                    for blk in (b for blocks_ in per_dev for b in blocks_):
-                        have = merged.get(blk.window)
-                        merged[blk.window] = blk if have is None else have.merge(blk)
-                ordered = [merged[w] for w in sorted(merged)]
+                if self.pipe.merged_block_slots:
+                    ordered = self._device_merged_blocks(pend_rows[0], pend_wins[0])
+                else:
+                    ordered = self._host_merged_blocks(pend_rows, pend_wins, wide)
                 self.sketch_blocks_closed += len(ordered)
                 self.sketch_blocks_dropped += hold_blocks(
                     self.closed_sketches, ordered, self.max_held_sketches
@@ -1564,6 +1675,49 @@ class ShardedWindowManager:
                  for db in flushed]
             )
         return flushed
+
+    def _device_merged_blocks(self, rows, wins) -> list:
+        """The drain's blocks as the devices merged them, one a window
+        in window order, each unpacked once; the candidate union over
+        the devices' gathered top-K lanes is the merge's host work left
+        (`flush.sketch_merge`). A window no device updated is dropped,
+        as `unpack_drained` drops such a block."""
+        cfg, d = self._sk_cfg, self.pipe.n_devices
+        planes = [_merged_planes(row, cfg) for row in rows]
+        blocks = []
+        with self.tracer.span(SPAN_FLUSH_SKETCH_MERGE):
+            for i, plane in enumerate(planes):
+                cands = _merged_candidates(rows[i], cfg, d)
+                if plane["n_updates"] or len(cands["tk_hi"]):
+                    blocks.append(WindowSketchBlock(
+                        window=int(wins[i]), config=cfg, **plane, **cands))
+        self.sketch_blocks_device_merged += len(blocks)
+        return blocks
+
+    def _host_merged_blocks(self, pend_rows, pend_wins, wide) -> list:
+        """Each device's drained blocks unpacked and merged by window on
+        the host (the pool on: `_merged_block_slots`), in window order."""
+        d = self.pipe.n_devices
+        per_dev = [
+            unpack_drained(pend_rows[dev], pend_wins[dev], self._sk_cfg)
+            for dev in range(d)
+        ]
+        if wide is not None:
+            # drained wide pool slots (ISSUE 20) merge into the
+            # same per-window dict — a window promoted on one
+            # device and compact on another unifies here by the
+            # r12 algebra
+            for dev in range(d):
+                keep = wide[1][dev] != np.uint32(SENTINEL_WIN)
+                per_dev.append(unpack_drained(
+                    wide[0][dev][keep], wide[1][dev][keep], self._sk_cfg
+                ))
+        merged: dict[int, object] = {}
+        with self.tracer.span(SPAN_FLUSH_SKETCH_MERGE):
+            for blk in (b for blocks_ in per_dev for b in blocks_):
+                have = merged.get(blk.window)
+                merged[blk.window] = blk if have is None else have.merge(blk)
+        return [merged[w] for w in sorted(merged)]
 
     def _cascade_on_drain(self, packed, totals, hi: int) -> list:
         """Rollup cascade (ISSUE 9): fold this drain's packed flush rows

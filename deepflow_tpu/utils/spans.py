@@ -161,9 +161,11 @@ SPAN_FLUSH_RESERVE = "flush.reserve"
 # NumPy only, so it cannot compile and stays out of FLUSH_SPAN_NAMES;
 # absent from a manager without the plane.
 SPAN_FLUSH_SKETCH = "flush.sketch"
-# what only the sharded close does (PR 36): the host's
-# `WindowSketchBlock.merge` of the D devices' blocks into one a window,
-# a child of flush.sketch (host NumPy only); and the dispatch of the
+# what only the sharded close does (PR 36): the host's share of the
+# D devices' blocks' merge into one a window, a child of flush.sketch
+# (host NumPy only: since PR 39 with the pool off the candidate union
+# over the devices' gathered top-K lanes, the rest merges on the
+# devices; with it on `WindowSketchBlock.merge`); and the dispatch of the
 # collective that merges the open sketch ring across the mesh on every
 # advance (`ShardedPipeline.window_close`: lax.pmax / lax.psum). Neither
 # is in PIPELINE_SPAN_NAMES: a one-chip manager has no such work.

@@ -3,8 +3,9 @@ on the served path, Receiver -> queues -> FeederRuntime -> ShardedFeedSink
 -> ShardedWindowManager on four forced host devices, against the plain
 references the benchmark holds it to: the partial rows merged by key are
 the NumPy rollup and the one-device deployment's documents; the merged
-sketch block is the reference sketch bit for bit; each device's rows and
-blocks alone, merged, give the whole; closes of many document counts
+sketch block is the reference sketch bit for bit, and (PR 39) the block
+the devices merged is the one the host merges from their blocks; each
+device's rows and blocks alone, merged, give the whole; closes of many document counts
 compile the close's programs once; the close's spans add up and its
 counters count; a forced retrace is counted; and a bfloat16 control is not
 within the SUM limit."""
@@ -203,13 +204,29 @@ def capture_device_blocks(kept: list):
 def run(m):
     """The sharded deployment over RECORDS with the page cut small, so
     that a close is several pages and the reserve is used."""
-    page, unpack, device_blocks = window_mod.PAGE_ROWS, sharded.unpack_drained, []
+    page = window_mod.PAGE_ROWS
     window_mod.PAGE_ROWS = 256
+    try:
+        out = served_run(m, sharded_config(m), RECORDS)
+    finally:
+        window_mod.PAGE_ROWS = page
+    assert out["guarantees_broken"] == set()
+    return out
+
+
+@pytest.fixture(scope="module")
+def host_merged_run(m):
+    """The same run with the closed blocks merged on the host, as before
+    PR 39 and as the pool still does (`_merged_block_slots` 0), keeping
+    every device's unpacked blocks."""
+    slots, unpack, device_blocks = (sharded._merged_block_slots,
+                                    sharded.unpack_drained, [])
+    sharded._merged_block_slots = lambda config, n_devices: 0
     try:
         out = served_run(m, sharded_config(m), RECORDS,
                          capture_device_blocks(device_blocks))
     finally:
-        window_mod.PAGE_ROWS, sharded.unpack_drained = page, unpack
+        sharded._merged_block_slots, sharded.unpack_drained = slots, unpack
     assert out["guarantees_broken"] == set()
     out["device_blocks"] = device_blocks
     return out
@@ -322,9 +339,9 @@ def test_merged_block_is_the_reference_sketch_bit_for_bit(run, m):
     assert c["pipeline.sketch_bytes_fetched"] == c["pipeline.sketch_bytes_live"] > 0
 
 
-def test_each_devices_blocks_alone_merged_give_the_whole(run, m):
+def test_each_devices_blocks_alone_merged_give_the_whole(run, host_merged_run):
     by_window: dict = {}
-    for blocks in run["device_blocks"]:
+    for blocks in host_merged_run["device_blocks"]:
         for blk in blocks:
             by_window.setdefault(int(blk.window), []).append(blk)
     kept = {int(b.window): b for b in run["ctx"]["side_outputs"]["sketch_blocks"]}
@@ -342,6 +359,28 @@ def test_each_devices_blocks_alone_merged_give_the_whole(run, m):
         # no device's block is the whole: every one lacks another's rows
         if len(parts) > 1:
             assert all(int(p.n_updates) < int(whole.n_updates) for p in parts)
+
+
+def test_blocks_merged_on_the_devices_are_the_hosts_merge_bit_for_bit(
+        run, host_merged_run):
+    """PR 39: every window's block as the devices merged it is the one
+    the host merged from the devices' blocks, candidates in order too;
+    every closed block came off the devices merged, and what the drains
+    fetched of them is one block a window."""
+    got = run["ctx"]["side_outputs"]["sketch_blocks"]
+    want = host_merged_run["ctx"]["side_outputs"]["sketch_blocks"]
+    assert [int(b.window) for b in got] == [int(b.window) for b in want]
+    for a, b in zip(got, want):
+        assert a.n_updates == b.n_updates
+        for f in ("hll", "cms", "hist", "tk_hi", "tk_lo", "tk_ida", "tk_idb",
+                  "tk_votes"):
+            np.testing.assert_array_equal(getattr(a, f), getattr(b, f), err_msg=f)
+    c, c_host = run["counters"], host_merged_run["counters"]
+    assert c["pipeline.sketch_blocks_device_merged"] \
+        == c["pipeline.sketch_blocks_closed"] == len(RECORDS)
+    assert c_host["pipeline.sketch_blocks_device_merged"] == 0
+    # each window's block once, where the host fetched each device's
+    assert 0 < c["pipeline.sketch_bytes_fetched"] < c_host["pipeline.sketch_bytes_fetched"]
 
 
 # ---------------------------------------------------------------------------

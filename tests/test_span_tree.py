@@ -85,6 +85,9 @@ HOST_TIME_LAYERS = (
     "feeder.queue_depth_frames", "receiver.busy_ms_per_mrec",
     "receiver.cpu_ms_per_mrec",
 )
+# PR 39, the four-chip cell's again: the share of closed blocks that came
+# off the devices merged
+DEVICE_MERGE_LAYERS = ("close.device_merged_block_share",)
 # 0.0 is a reading: no acquire of a tiny run need block (one chip: the
 # per-batch stats.fetch syncs first; the CPU's sharded step may have run by
 # the time its buffer comes round), and its pumps need not find the queues
@@ -674,7 +677,7 @@ _TRACE = {"trace": {"slice_records": 30_000, "busy_s": 0.5,
           "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}}
 
 
-@pytest.mark.parametrize("name", SHARDED_LAYERS)
+@pytest.mark.parametrize("name", SHARDED_LAYERS + DEVICE_MERGE_LAYERS)
 def test_sharded_layer_file_reads_a_number_from_a_tiny_sharded_run(
         name, tiny_run, tiny_sharded_run, chipbench_modules):
     layers = chipbench_modules["layers"]
@@ -687,6 +690,8 @@ def test_sharded_layer_file_reads_a_number_from_a_tiny_sharded_run(
     assert isinstance(value, float) and value > 0.0
     if name.endswith("roofline"):
         assert value < 100.0
+    if name in DEVICE_MERGE_LAYERS:
+        assert value == 100.0  # the pool is off: every block merges on the devices
     # the one-chip program has no such span, counter or module
     plain = {**tiny_run["planes"], **_TRACE,
              "trace": {**_TRACE["trace"], "module_s": {}}}
@@ -730,7 +735,7 @@ def test_layer_files_and_benchmark_entries_pair_up():
     files = {os.path.basename(p)[:-5]
              for p in glob.glob(os.path.join(CHIPBENCH, "layers", "*.json"))}
     new = (list(NEW_LAYERS) + list(SHARDED_LAYERS) + list(POOLED_LAYERS)
-           + list(HOST_TIME_LAYERS))
+           + list(HOST_TIME_LAYERS) + list(DEVICE_MERGE_LAYERS))
     assert set(names) == files and names[-len(new):] == new
 
 

@@ -295,8 +295,8 @@ def test_live_block_share_layer_file_reads_the_two_sums():
     # 23 when this one came; PR 31 appended one, PR 33 the sketch cell's
     # three, PR 34 the close's host half's three, PR 36 the four-chip
     # cell's five, PR 37 the close's host passes' two, PR 38 the host's
-    # time by owner's nine
-    assert len(bench["per_layer"]) == 46
+    # time by owner's nine, PR 39 the four-chip cell's device-merged share
+    assert len(bench["per_layer"]) == 47
     entry = bench["per_layer"][22]
     layer = layers.load_layer("fold.live_block_share")
     assert {k: layer[k] for k in entry} == entry and "workloads" not in entry
